@@ -478,8 +478,9 @@ def bench_matching(rows, repeats=2):
     Single-instance sizes vs the host Hopcroft-Karp oracle (the device
     path must match its cardinality exactly — asserted, not just timed),
     then the batched masked/compacted drivers, then the Pallas frontier
-    backend end-to-end (interpret on CPU: correctness-scale timing)."""
+    backend end-to-end (interpreted off-TPU: correctness-scale timing)."""
     from repro.core.matching import match_bipartite, match_bipartite_batch
+    from repro.kernels import interpret_mode
     from repro.core.matching.ref import hopcroft_karp, random_bipartite
     rng = np.random.default_rng(0)
     for n in (64, 128, 256):
@@ -508,8 +509,8 @@ def bench_matching(rows, repeats=2):
     adj32 = jnp.asarray(random_bipartite(rng, 32, 32, p=0.15))
     us_x = _time(match_bipartite, adj32, reps=repeats)
     us_p = _time(match_bipartite, adj32, backend="pallas", reps=repeats)
-    rows.append(("matching_pallas_interp_32x32", us_p,
-                 f"xla_us={us_x:.0f};interpret-mode frontier kernel"))
+    mode = "_interp" if interpret_mode() else ""
+    rows.append((f"matching_pallas{mode}_32x32", us_p, f"xla_us={us_x:.0f}"))
 
 
 @bench("warmstart")
@@ -644,7 +645,9 @@ def bench_routing(rows, repeats=2):
 
 @bench("kernels")
 def bench_kernels(rows, repeats=2):
-    """Bidding kernel tile sweep (interpret on CPU: correctness-scale)."""
+    """Bidding kernel tile sweep (compiled on a TPU, interpreted
+    elsewhere: correctness-scale)."""
+    from repro.kernels import interpret_mode
     from repro.kernels.bidding.kernel import bidding
     from repro.kernels.bidding.ref import bidding_ref
     rng = np.random.default_rng(0)
@@ -652,19 +655,23 @@ def bench_kernels(rows, repeats=2):
     c = jnp.asarray(rng.integers(-1000, 1000, (n, n)), jnp.int32)
     p = jnp.asarray(rng.integers(-500, 500, (n,)), jnp.int32)
     m = jnp.asarray(rng.random((n, n)) < 0.3)
+    interp = interpret_mode()
+    mode = "_interp" if interp else ""
     us_ref = _time(bidding_ref, c, p, m, reps=repeats)
     rows.append((f"bidding_ref_xla_n{n}", us_ref, "oracle"))
     for br, bc in ((128, 128), (256, 256), (256, 512)):
         vmem_kib = (br * bc * 5 + bc * 4 + br * 12) / 1024
         us = _time(bidding, c, p, m, block_rows=br, block_cols=bc,
-                   interpret=True, reps=repeats)
-        rows.append((f"bidding_kernel_{br}x{bc}_interp", us,
+                   interpret=interp, reps=repeats)
+        rows.append((f"bidding_kernel_{br}x{bc}{mode}", us,
                      f"vmem_per_step_KiB={vmem_kib:.0f}"))
 
 
 @bench("flash")
 def bench_flash_kernel(rows, repeats=2):
-    """Flash-attention Pallas kernel vs jnp flash path (interpret on CPU)."""
+    """Flash-attention Pallas kernel vs jnp flash path (compiled on a TPU,
+    interpreted elsewhere)."""
+    from repro.kernels import interpret_mode
     from repro.kernels.flash_attention.kernel import flash_attention_fwd
     from repro.kernels.flash_attention.ref import flash_attention_ref
     import numpy as np
@@ -674,11 +681,13 @@ def bench_flash_kernel(rows, repeats=2):
     q = jnp.asarray(rng.normal(size=(B, S, H, dh)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(B, S, KV, dh)).astype(np.float32))
     v = jnp.asarray(rng.normal(size=(B, S, KV, dh)).astype(np.float32))
+    interp = interpret_mode()
+    mode = "_interp" if interp else ""
     us_ref = _time(flash_attention_ref, q, k, v, reps=repeats)
     rows.append((f"flash_ref_xla_S{S}", us_ref, "dense oracle"))
     for bq, bk in ((128, 128), (256, 512)):
         vmem = (bq * dh + 2 * bk * dh + bq * bk + bq * (dh + 2)) * 4 / 1024
         us = _time(flash_attention_fwd, q, k, v, block_q=bq, block_k=bk,
-                   interpret=True, reps=repeats)
-        rows.append((f"flash_kernel_{bq}x{bk}_interp", us,
+                   interpret=interp, reps=repeats)
+        rows.append((f"flash_kernel_{bq}x{bk}{mode}", us,
                      f"vmem_per_step_KiB={vmem:.0f}"))

@@ -30,6 +30,10 @@ cross-checked against ``repro.core.kinds.registered_kinds()`` — registering
 a new solver kind without a benchmark makes every ``benchmarks.run``
 invocation fail loudly instead of silently shipping the kind unmeasured.
 
+Run as a script, it keeps JAX's persistent compilation cache in
+``JAX_COMPILATION_CACHE_DIR`` when that is set, else in the checkout's
+``.jax_cache`` (``repro.launch.compile_cache``).
+
 Unknown bench names are rejected with the list of available benches
 (previously they silently printed an empty CSV). ``--csv PATH`` writes the
 same CSV to a file so callers (CI's artifact step) don't have to depend on
@@ -175,4 +179,7 @@ def main(argv: list[str] | None = None) -> None:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache(pathlib.Path(__file__).resolve().parents[1]
+                      / ".jax_cache")
     main()

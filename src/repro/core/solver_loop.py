@@ -512,11 +512,12 @@ def run_compacted(spec: LoopSpec, state, n_instances: int, *, lanes=None,
             _admit_free(spec, refill, lanes, lane_states, rounds,
                         slot_token, live_idx, free_idx)
 
-    # Reassemble in input order (lanes are contiguous, ordered slices).
+    # Reassemble in input order (lanes are contiguous, ordered slices) on
+    # the first lane's device, so a scheduler lane's sub-mesh keeps its
+    # results instead of shipping them to another lane's chip.
     if len(lane_states) > 1:
-        home = jax.devices()[0]
-        parts = [jax.device_put(s, home) if dev is not None else s
-                 for (_, _, dev), s in zip(lanes, lane_states)]
+        home = lanes[0][2]
+        parts = [jax.device_put(s, home) for s in lane_states]
         lead = _lead(spec, 1)
         state = jax.tree.map(
             lambda *xs: jnp.concatenate(xs, axis=lead(xs[0]) if lead else 0),

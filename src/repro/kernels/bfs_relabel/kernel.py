@@ -27,9 +27,12 @@ early would leak not-yet-converged ``ds`` values into the sink labeling.
 Blocks are whole (H, W) planes with a batch grid dimension — wavefronts
 cross the entire grid, so tiling would reintroduce a halo fixpoint per
 sweep. VMEM per step: 4 cap planes + 2 seed planes + 2 in + 2 out
-wavefront planes = 10 planes of H·W·4B; 256² ⇒ ~2.6 MB, comfortably
-within VMEM. Grids beyond ~512² need a tiled variant (not needed here:
-the solvers top out at vision-scale 256² instances).
+wavefront planes = 10 planes of H·W·4B, double-buffered across the batch
+grid, plus the sweep's temporaries: the compiler asks for ~33 planes in
+all, 32.97 MiB at 512², over Mosaic's 16 MiB default. ``vmem_limit_bytes``
+raises the kernel's limit from its shape, up to ``VMEM_CAP``; that admits
+the paper's 512² grid and square grids up to ~800², and a larger grid
+raises ``ValueError`` before dispatch (it needs a tiled variant).
 """
 from __future__ import annotations
 
@@ -38,6 +41,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 INF_H = 2 ** 30  # python int: jnp scalars would be captured consts in pallas
 
@@ -46,6 +50,24 @@ INF_H = 2 ** 30  # python int: jnp scalars would be captured consts in pallas
 # of this. 8 amortizes the HBM round trip without inflating the tail
 # (converged planes re-relax as no-ops).
 SWEEPS = 8
+
+# VMEM the compiled kernel needs, in (H, W) int32 planes: 10 block planes,
+# double-buffered across the batch grid, plus ~13 planes of sweep
+# temporaries (the compiler asked for 32.97 MiB at 512², i.e. 33 planes).
+VMEM_PLANES = 36
+# Mosaic's default scoped-VMEM limit, and what this kernel may raise it to
+# (a TPU v5e core has 128 MiB of VMEM; the rest stays for the compiler).
+VMEM_DEFAULT = 16 * 2 ** 20
+VMEM_CAP = 100 * 2 ** 20
+
+
+def vmem_limit_bytes(H: int, W: int) -> int:
+    """Scoped-VMEM limit for one ``(H, W)`` instance per grid step.
+
+    A plane occupies whole (8, 128) tiles of 4-byte words in VMEM.
+    """
+    plane = -(-H // 8) * 8 * (-(-W // 128) * 128) * 4
+    return max(VMEM_DEFAULT, VMEM_PLANES * plane)
 
 
 def _shift_min(a, d):
@@ -92,7 +114,7 @@ def _bfs_relabel_kernel(cap_ref, seed_t_ref, seed_s_ref, dt_ref, ds_ref,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bfs_relabel_sweeps(cap, seed_t, seed_s, dt, ds, *,
-                       interpret: bool = True):
+                       interpret: bool = False):
     """``SWEEPS`` joint relaxation sweeps of both wavefront planes.
 
     Args:
@@ -106,6 +128,13 @@ def bfs_relabel_sweeps(cap, seed_t, seed_s, dt, ds, *,
     the batch dimension ``maxflow_grid_batch`` dispatches over.
     """
     B, H, W = dt.shape
+    vmem = vmem_limit_bytes(H, W)
+    if not interpret and vmem > VMEM_CAP:
+        raise ValueError(
+            f"bfs_relabel_sweeps: a {H}x{W} grid needs ~{vmem >> 20} MiB of "
+            f"VMEM for whole-plane blocks, over the {VMEM_CAP >> 20} MiB "
+            f"this kernel may take on a TPU; grids this large need a tiled "
+            f"relabel (use backend='xla' or 'pallas')")
     spec2d = pl.BlockSpec((1, H, W), lambda b: (b, 0, 0))
     spec4 = pl.BlockSpec((4, 1, H, W), lambda b: (0, b, 0, 0))
     dt, ds = pl.pallas_call(
@@ -116,5 +145,6 @@ def bfs_relabel_sweeps(cap, seed_t, seed_s, dt, ds, *,
         out_shape=[jax.ShapeDtypeStruct((B, H, W), jnp.int32),
                    jax.ShapeDtypeStruct((B, H, W), jnp.int32)],
         interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
     )(cap, seed_t, seed_s, dt, ds)
     return dt, ds

@@ -25,6 +25,7 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import interpret_mode
 from repro.kernels.bfs_relabel.kernel import SWEEPS, bfs_relabel_sweeps
 
 # python int, not jnp.int32: this module is imported lazily, possibly
@@ -49,7 +50,7 @@ def bfs_relabel_heights(cap, cap_src, cap_sink, h_prev, n_nodes,
     where the source is, else ``max(h_prev, N)``.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     *batch, H, W = h_prev.shape
     B = 1
     for s in batch:

@@ -66,11 +66,10 @@ def _bidding_kernel(c_ref, p_ref, m_ref, min1_ref, arg1_ref, min2_ref, *,
                                              "interpret"))
 def bidding(c: jax.Array, p_y: jax.Array, mask: jax.Array,
             *, block_rows: int = 256, block_cols: int = 512,
-            interpret: bool = True):
+            interpret: bool = False):
     """Row-wise (min1, arg1, min2) of ``where(mask, INF, c - p_y)``.
 
-    interpret=True executes the kernel body on CPU (validation mode); on a
-    real TPU pass interpret=False.
+    interpret=True executes the kernel body on the host (tests off-TPU).
     """
     n_r, n_c = c.shape
     br, bc = min(block_rows, n_r), min(block_cols, n_c)

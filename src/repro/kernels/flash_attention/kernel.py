@@ -80,7 +80,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     "causal", "scale", "block_q", "block_k", "interpret"))
 def flash_attention_fwd(q, k, v, *, causal: bool = True,
                         scale: float | None = None, block_q: int = 256,
-                        block_k: int = 512, interpret: bool = True):
+                        block_k: int = 512, interpret: bool = False):
     """q: (B, Sq, H, dh); k/v: (B, Sk, KV, dh/dv). Returns (B, Sq, H, dv).
 
     VMEM tiling per the module docstring; interpret=True validates on CPU.

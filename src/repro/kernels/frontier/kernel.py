@@ -70,14 +70,13 @@ def _frontier_kernel(a_ref, r_ref, m_ref, root_ref, claim_ref, *,
                                              "interpret"))
 def frontier(adj: jax.Array, root_row: jax.Array, match_row: jax.Array,
              *, block_rows: int = 256, block_cols: int = 512,
-             interpret: bool = True):
+             interpret: bool = False):
     """Per-column ``(min_root, claim_row)`` over labeled candidate rows.
 
     ``adj`` is ``(n_r, n_c)`` bool; ``root_row``/``match_row`` are
     ``(n_r,)`` int32 (root INF = unlabeled, match -1 = free).  Columns with
     no candidate return ``(INF, 0)`` — callers gate on ``min_root < INF``.
-    interpret=True executes the kernel body on CPU (validation mode); on a
-    real TPU pass interpret=False.
+    interpret=True executes the kernel body on the host (tests off-TPU).
     """
     n_r, n_c = adj.shape
     br, bc = min(block_rows, n_r), min(block_cols, n_c)

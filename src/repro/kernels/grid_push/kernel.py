@@ -11,8 +11,12 @@ cross-tile flow deposition (shift-adds) is pure elementwise data movement and
 stays in XLA (ops.py) where it fuses with the surrounding ops; the VMEM-
 resident argmin/push math — the part the paper hand-optimizes — lives here.
 
-VMEM per step: 12 input planes + 7 output planes of BH·BW·4B.
-BH=BW=256 ⇒ 19·256·256·4B ≈ 5 MB — fits VMEM with double buffering.
+VMEM per step: 12 input planes + 7 output planes of BH·BW·4B, double-
+buffered. The dense kernel's 256×256 blocks ⇒ 2·19·256 KiB ≈ 9.5 MiB, and
+the scheduled kernel's 64×128 blocks ⇒ 2·19·32 KiB ≈ 1.2 MiB, both under
+Mosaic's 16 MiB default scoped limit (compiled for v5e in
+tests/test_tpu_compile.py at 8×512²). Block widths are multiples of 128
+lanes or the full width (``tile_dims``).
 The halo exchange (neighbour heights) is precomputed by ops.py as 4 shifted
 height planes, which on real hardware XLA lays out as cheap HBM slices.
 """
@@ -31,46 +35,44 @@ INF_H = 2 ** 30  # python int: jnp scalars would be captured consts in pallas
 def _decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes):
     """The per-node decision math shared by both kernels (concrete values).
 
+    ``cap`` / ``nbr_h`` index by direction (refs or arrays, 4 planes).
     Candidate order matches grid.jacobi_round:
-    [sink, source, UP, DOWN, LEFT, RIGHT].
+    [sink, source, UP, DOWN, LEFT, RIGHT]; the push goes to the FIRST
+    candidate attaining the minimum height (``jnp.argmin``'s tie rule),
+    written as a min and a select chain over the six planes because
+    Mosaic lowers neither an int32 argmin nor ``take_along_axis``.
+    Returns ``(h_new, deltas)`` with ``deltas`` a list of six planes.
     """
     active = e > 0
-    cand = jnp.concatenate([
-        jnp.where(cap_sink > 0, 0, INF_H)[None],
-        jnp.where(cap_src > 0, n_nodes, INF_H)[None],
-        jnp.where(cap > 0, nbr_h, INF_H),
-    ], axis=0)                      # (6, BH, BW)
-    h_min = jnp.min(cand, axis=0)
-    choice = jnp.argmin(cand, axis=0)
+    caps = [cap_sink, cap_src] + [cap[d] for d in range(4)]
+    cand = [jnp.where(cap_sink > 0, 0, INF_H),
+            jnp.where(cap_src > 0, n_nodes, INF_H)]
+    cand += [jnp.where(caps[2 + d] > 0, nbr_h[d], INF_H) for d in range(4)]
+    h_min = functools.reduce(jnp.minimum, cand)
 
     do_push = active & (h > h_min)
     do_relabel = active & (h <= h_min) & (h_min < INF_H)
-
-    cap_all = jnp.concatenate([cap_sink[None], cap_src[None], cap], axis=0)
-    chosen_cap = jnp.take_along_axis(cap_all, choice[None], axis=0)[0]
-    delta = jnp.where(do_push, jnp.minimum(e, chosen_cap), 0.0)
-
-    planes = jax.lax.broadcasted_iota(jnp.int32, cand.shape, 0)
     h_new = jnp.where(do_relabel, h_min + 1, h)
-    return h_new, jnp.where(planes == choice[None], delta[None], 0.0)
+
+    deltas, done = [], ~do_push
+    for c, k in zip(cand, caps):
+        pick = ~done & (c == h_min)
+        deltas.append(jnp.where(pick, jnp.minimum(e, k), 0.0))
+        done = done | pick
+    return h_new, deltas
 
 
-def _grid_push_kernel(nnodes_ref, e_ref, h_ref, cap_ref, nbrh_ref, csrc_ref,
+def _grid_push_kernel(nn_ref, e_ref, h_ref, cap_ref, nbrh_ref, csrc_ref,
                       csink_ref, hnew_ref, delta_ref):
-    # Blocks are (BH, BW) planes; in batched mode each carries a leading
-    # singleton batch axis (one grid step per instance) that we squeeze here.
-    bh, bw = e_ref.shape[-2:]
-    e = e_ref[...].reshape(bh, bw)            # f32
-    h = h_ref[...].reshape(bh, bw)            # i32
-    cap = cap_ref[...].reshape(4, bh, bw)     # f32 residual neighbour caps
-    nbr_h = nbrh_ref[...].reshape(4, bh, bw)  # i32 neighbour heights (halo)
-    cap_src = csrc_ref[...].reshape(bh, bw)   # f32
-    cap_sink = csink_ref[...].reshape(bh, bw)  # f32
-    n_nodes = nnodes_ref[0]
-
-    h_new, delta = _decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes)
-    hnew_ref[...] = h_new.reshape(hnew_ref.shape)
-    delta_ref[...] = delta.reshape(delta_ref.shape)
+    # Blocks are (BH, BW) planes; in batched mode the leading batch axis is
+    # squeezed out by the BlockSpecs (one grid step per instance and tile).
+    cap = [cap_ref[d] for d in range(4)]        # f32 residual neighbour caps
+    nbr_h = [nbrh_ref[d] for d in range(4)]     # i32 neighbour heights (halo)
+    h_new, deltas = _decide(e_ref[...], h_ref[...], cap, nbr_h,
+                            csrc_ref[...], csink_ref[...], nn_ref[0])
+    hnew_ref[...] = h_new
+    for p, d in enumerate(deltas):
+        delta_ref[p] = d
 
 
 def _grid_push_sched_kernel(sched_ref, nact_ref, nnodes_ref, e_ref, h_ref,
@@ -84,26 +86,17 @@ def _grid_push_sched_kernel(sched_ref, nact_ref, nnodes_ref, e_ref, h_ref,
     program's blocks are tile ``sched[b, i]`` (index maps below). Schedule
     positions past ``nact[b]`` carry tiles with NO active vertex — for
     them one Jacobi round is the identity (no node pushes or relabels), so
-    the kernel skips the whole candidate/argmin/push stage and writes the
+    the kernel skips the whole candidate/min/push stage and writes the
     identity outputs directly. The permutation covers every tile exactly
     once, so every output block is written exactly once.
     """
     b = pl.program_id(0)
     i = pl.program_id(1)
-    bh, bw = e_ref.shape[-2:]
 
     @pl.when(i < nact_ref[b])
     def _active_tile():
-        e = e_ref[...].reshape(bh, bw)
-        h = h_ref[...].reshape(bh, bw)
-        cap = cap_ref[...].reshape(4, bh, bw)
-        nbr_h = nbrh_ref[...].reshape(4, bh, bw)
-        cap_src = csrc_ref[...].reshape(bh, bw)
-        cap_sink = csink_ref[...].reshape(bh, bw)
-        h_new, delta = _decide(e, h, cap, nbr_h, cap_src, cap_sink,
-                               nnodes_ref[0])
-        hnew_ref[...] = h_new.reshape(hnew_ref.shape)
-        delta_ref[...] = delta.reshape(delta_ref.shape)
+        _grid_push_kernel(nnodes_ref, e_ref, h_ref, cap_ref, nbrh_ref,
+                          csrc_ref, csink_ref, hnew_ref, delta_ref)
 
     @pl.when(i >= nact_ref[b])
     def _inactive_tile():  # identity: no active node -> no push, no relabel
@@ -111,11 +104,25 @@ def _grid_push_sched_kernel(sched_ref, nact_ref, nnodes_ref, e_ref, h_ref,
         delta_ref[...] = jnp.zeros_like(delta_ref)
 
 
+def tile_dims(H: int, W: int, block_h: int, block_w: int):
+    """The ``(bh, bw)`` block both kernels use for an ``(H, W)`` plane.
+
+    Each requested block dim is kept when it divides the plane and meets
+    the TPU's (8 sublane, 128 lane) tiling; otherwise the block spans the
+    full dim, which Mosaic always accepts. Results never depend on the
+    block shape, only which grid steps do the work.
+    """
+    def fit(block, dim, align):
+        b = min(block, dim)
+        return b if dim % b == 0 and (b == dim or b % align == 0) else dim
+    return fit(block_h, H, 8), fit(block_w, W, 128)
+
+
 @functools.partial(jax.jit, static_argnames=("block_h", "block_w",
                                              "interpret"))
 def grid_push_decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes,
                      *, block_h: int = 256, block_w: int = 256,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """Per-node push/relabel decision for one Jacobi round.
 
     Returns (h_new, delta) where delta[p] is the flow pushed toward plane
@@ -125,48 +132,47 @@ def grid_push_decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes,
     (with ``cap``/``nbr_h`` ``(4, B, H, W)``). In batched mode the pallas
     grid gains a leading batch dimension — grid ``(B, H//bh, W//bw)`` — so
     every instance's tiles are independent kernel steps of ONE launch,
-    amortizing the dispatch over the whole batch.
+    amortizing the dispatch over the whole batch. ``n_nodes`` rides scalar
+    prefetch (SMEM). ``interpret=True`` runs the kernel body on the host
+    (tests off-TPU).
     """
     *batch, H, W = e.shape
-    bh, bw = min(block_h, H), min(block_w, W)
-    assert H % bh == 0 and W % bw == 0, (H, W, bh, bw)
-    args = (jnp.asarray([n_nodes], jnp.int32), e, h, cap, nbr_h, cap_src,
-            cap_sink)
+    bh, bw = tile_dims(H, W, block_h, block_w)
 
     if not batch:
         grid = (H // bh, W // bw)
-        spec2d = pl.BlockSpec((bh, bw), lambda i, j: (i, j))
-        spec4 = pl.BlockSpec((4, bh, bw), lambda i, j: (0, i, j))
-        spec6 = pl.BlockSpec((6, bh, bw), lambda i, j: (0, i, j))
-        nnodes_spec = pl.BlockSpec((1,), lambda i, j: (0,))
-        out_shape = [jax.ShapeDtypeStruct((H, W), jnp.int32),
-                     jax.ShapeDtypeStruct((6, H, W), jnp.float32)]
+        spec2d = pl.BlockSpec((bh, bw), lambda i, j, nn: (i, j))
+        spec4 = pl.BlockSpec((4, bh, bw), lambda i, j, nn: (0, i, j))
+        spec6 = pl.BlockSpec((6, bh, bw), lambda i, j, nn: (0, i, j))
     else:
         (B,) = batch
         grid = (B, H // bh, W // bw)
-        spec2d = pl.BlockSpec((1, bh, bw), lambda b, i, j: (b, i, j))
-        spec4 = pl.BlockSpec((4, 1, bh, bw), lambda b, i, j: (0, b, i, j))
-        spec6 = pl.BlockSpec((6, 1, bh, bw), lambda b, i, j: (0, b, i, j))
-        nnodes_spec = pl.BlockSpec((1,), lambda b, i, j: (0,))
-        out_shape = [jax.ShapeDtypeStruct((B, H, W), jnp.int32),
-                     jax.ShapeDtypeStruct((6, B, H, W), jnp.float32)]
+        spec2d = pl.BlockSpec((None, bh, bw), lambda b, i, j, nn: (b, i, j))
+        spec4 = pl.BlockSpec((4, None, bh, bw),
+                             lambda b, i, j, nn: (0, b, i, j))
+        spec6 = pl.BlockSpec((6, None, bh, bw),
+                             lambda b, i, j, nn: (0, b, i, j))
 
-    h_new, delta = pl.pallas_call(
-        _grid_push_kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,   # n_nodes
         grid=grid,
-        in_specs=[nnodes_spec, spec2d, spec2d, spec4, spec4, spec2d, spec2d],
+        in_specs=[spec2d, spec2d, spec4, spec4, spec2d, spec2d],
         out_specs=[spec2d, spec6],
-        out_shape=out_shape,
+    )
+    return pl.pallas_call(
+        _grid_push_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(e.shape, jnp.int32),
+                   jax.ShapeDtypeStruct((6,) + e.shape, jnp.float32)],
         interpret=interpret,
-    )(*args)
-    return h_new, delta
+    )(jnp.asarray([n_nodes], jnp.int32), e, h, cap, nbr_h, cap_src, cap_sink)
 
 
 @functools.partial(jax.jit, static_argnames=("block_h", "block_w",
                                              "interpret"))
 def grid_push_decide_sched(e, h, cap, nbr_h, cap_src, cap_sink, sched,
                            n_active, n_nodes, *, block_h: int = 64,
-                           block_w: int = 64, interpret: bool = True):
+                           block_w: int = 128, interpret: bool = False):
     """Active-tile-scheduled push/relabel decision (balanced backend).
 
     Same outputs as ``grid_push_decide`` — ``(h_new, delta)`` with
@@ -178,9 +184,10 @@ def grid_push_decide_sched(e, h, cap, nbr_h, cap_src, cap_sink, sched,
       e / h / cap_src / cap_sink: ``(B, H, W)`` state planes.
       cap / nbr_h: ``(4, B, H, W)``.
       sched: ``(B, T)`` int32 — per instance, a PERMUTATION of the tile
-        ids ``0..T-1`` (``T = (H//block_h) * (W//block_w)``, row-major)
-        with every tile containing an active vertex compacted to the
-        front (``repro.kernels.grid_push.ops.tile_schedule``).
+        ids ``0..T-1`` (``T = (H//bh) * (W//bw)`` for the ``tile_dims``
+        block, row-major) with every tile containing an active vertex
+        compacted to the front (``repro.kernels.grid_push.ops.
+        tile_schedule``).
       n_active: ``(B,)`` int32 — how many leading schedule entries are
         active; programs past it take the identity fast path.
       n_nodes: scalar int32 (the paper's N).
@@ -194,11 +201,7 @@ def grid_push_decide_sched(e, h, cap, nbr_h, cap_src, cap_sink, sched,
     on the full grid (asserted in tests/test_balanced.py).
     """
     B, H, W = e.shape
-    bh, bw = min(block_h, H), min(block_w, W)
-    if H % bh:
-        bh = H
-    if W % bw:
-        bw = W
+    bh, bw = tile_dims(H, W, block_h, block_w)
     ntw = W // bw
     T = (H // bh) * ntw
     assert sched.shape == (B, T), (sched.shape, B, T)
@@ -207,27 +210,19 @@ def grid_push_decide_sched(e, h, cap, nbr_h, cap_src, cap_sink, sched,
         t = sched[b, i]
         return (b, t // ntw, t % ntw)
 
-    def tile4(b, i, sched, nact, nn):
+    def tile_planes(b, i, sched, nact, nn):
         t = sched[b, i]
         return (0, b, t // ntw, t % ntw)
 
-    def tile6(b, i, sched, nact, nn):
-        t = sched[b, i]
-        return (0, b, t // ntw, t % ntw)
-
+    spec2d = pl.BlockSpec((None, bh, bw), tile2d)
+    spec4 = pl.BlockSpec((4, None, bh, bw), tile_planes)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,   # sched, n_active, n_nodes
         grid=(B, T),
-        in_specs=[pl.BlockSpec((1, bh, bw), tile2d),
-                  pl.BlockSpec((1, bh, bw), tile2d),
-                  pl.BlockSpec((4, 1, bh, bw), tile4),
-                  pl.BlockSpec((4, 1, bh, bw), tile4),
-                  pl.BlockSpec((1, bh, bw), tile2d),
-                  pl.BlockSpec((1, bh, bw), tile2d)],
-        out_specs=[pl.BlockSpec((1, bh, bw), tile2d),
-                   pl.BlockSpec((6, 1, bh, bw), tile6)],
+        in_specs=[spec2d, spec2d, spec4, spec4, spec2d, spec2d],
+        out_specs=[spec2d, pl.BlockSpec((6, None, bh, bw), tile_planes)],
     )
-    h_new, delta = pl.pallas_call(
+    return pl.pallas_call(
         _grid_push_sched_kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, H, W), jnp.int32),
@@ -236,4 +231,3 @@ def grid_push_decide_sched(e, h, cap, nbr_h, cap_src, cap_sink, sched,
     )(sched.astype(jnp.int32), n_active.astype(jnp.int32),
       jnp.asarray([n_nodes], jnp.int32), e, h, cap, nbr_h, cap_src,
       cap_sink)
-    return h_new, delta

@@ -22,13 +22,13 @@ progress.
 """
 from __future__ import annotations
 
-import jax
 import jax.numpy as jnp
 
 from repro.core.maxflow.grid import (GridFlowState, _OPP, _gsum, _move,
                                      _nbr_h)
+from repro.kernels import interpret_mode
 from repro.kernels.grid_push.kernel import (grid_push_decide,
-                                            grid_push_decide_sched)
+                                            grid_push_decide_sched, tile_dims)
 from repro.kernels.grid_push.ref import grid_push_decide_ref
 
 
@@ -53,8 +53,7 @@ def jacobi_round_pallas(state: GridFlowState, n_nodes,
                         *, block_h: int = 256, block_w: int = 256,
                         interpret: bool | None = None) -> GridFlowState:
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-
+        interpret = interpret_mode()
     nbr_h = jnp.stack([_nbr_h(state.h, d) for d in range(4)], axis=0)
     h_new, delta = grid_push_decide(
         state.e, state.h, state.cap, nbr_h, state.cap_src, state.cap_sink,
@@ -67,7 +66,7 @@ def tile_schedule(active, block_h: int, block_w: int):
 
     Args:
       active: ``(B, H, W)`` bool — which nodes hold excess this round.
-      block_h / block_w: the kernel tile shape (must divide H, W).
+      block_h / block_w: the kernel tile shape (``kernel.tile_dims``).
 
     Returns ``(sched, n_active)``: ``sched`` is ``(B, T)`` int32, per
     instance a permutation of the row-major tile ids with every tile
@@ -85,7 +84,7 @@ def tile_schedule(active, block_h: int, block_w: int):
 
 
 def jacobi_round_scheduled(state: GridFlowState, n_nodes,
-                           *, block_h: int = 64, block_w: int = 64,
+                           *, block_h: int = 64, block_w: int = 128,
                            interpret: bool | None = None):
     """One Jacobi round dispatched over active tiles only.
 
@@ -98,13 +97,9 @@ def jacobi_round_scheduled(state: GridFlowState, n_nodes,
     Shape-polymorphic over leading batch axes.
     """
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = interpret_mode()
     *batch, H, W = state.e.shape
-    bh, bw = min(block_h, H), min(block_w, W)
-    if H % bh:
-        bh = H
-    if W % bw:
-        bw = W
+    bh, bw = tile_dims(H, W, block_h, block_w)
     B = 1
     for s in batch:
         B *= s

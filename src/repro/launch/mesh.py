@@ -31,14 +31,15 @@ from typing import Callable
 
 import jax
 import numpy as np
-from jax.sharding import PartitionSpec
+from jax import shard_map
+from jax.sharding import AxisType, PartitionSpec
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; multi-pod prepends a 2-pod axis."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model_parallel: int = 1):
@@ -46,7 +47,8 @@ def make_host_mesh(model_parallel: int = 1):
     n = len(jax.devices())
     assert n % model_parallel == 0
     return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+                         ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
 
 
 def make_solver_mesh(n_devices: int | None = None, *, axis: str = "batch"):
@@ -177,14 +179,9 @@ def shard_batched(fn: Callable, mesh, mesh_axis: str | None = None):
     entry points raise a ``ValueError`` otherwise and the pad-and-bucket
     front end pads with inert instances instead.
     """
-    try:  # stable namespace (newer jax); experimental alias as fallback
-        from jax import shard_map
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
-
     spec = batch_spec(mesh, mesh_axis)
     return jax.jit(shard_map(fn, mesh=mesh, in_specs=spec, out_specs=spec,
-                             check_rep=False))
+                             check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)

@@ -69,7 +69,9 @@ def _r_flushes(w: _Writer, v: dict) -> None:
 
 def _r_dispatches(w: _Writer, v: dict) -> None:
     n = w.family("dispatches_total", "counter",
-                 "Bucket dispatches by solver kind and loop driver.")
+                 "Bucket dispatches by solver kind and loop driver "
+                 "(masked/compacted; isolated = solo re-solve after a "
+                 "failed batched dispatch).")
     for key, count in sorted(v.items()):
         kind, _, driver = key.partition(":")
         w.sample(n, count, {"kind": kind, "driver": driver})

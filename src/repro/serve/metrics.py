@@ -143,7 +143,8 @@ class SchedulerMetrics:
     Counters: submitted / completed / failed / cancelled tickets; flushes
     by trigger (``size`` | ``deadline`` | ``manual`` | ``drain``);
     dispatches by ``(kind, driver)`` where driver is ``masked`` or
-    ``compacted``. Gauges: current queue depth. Distributions: ticket
+    ``compacted``, or ``isolated`` for each solo re-solve after a batched
+    dispatch failed. Gauges: current queue depth. Distributions: ticket
     latency (submit -> future resolution) percentiles, batch-occupancy
     EWMA (real instances / max_batch), convergence-spread EWMA, per-kind
     solver-rounds and heuristic-invocation EWMAs (``rounds_ewma`` /
@@ -203,6 +204,11 @@ class SchedulerMetrics:
             self._dispatches[(kind, "compacted" if compact else "masked")] += 1
         self.convergence.observe(kind, spread=spread, occupancy=occupancy,
                                  rounds=rounds, heuristics=heuristics)
+
+    def record_isolated(self, kind: str) -> None:
+        """One solo re-solve after a failed batched ``kind`` dispatch."""
+        with self._lock:
+            self._dispatches[(kind, "isolated")] += 1
 
     def record_done(self, latency_ms: float, *, ok: bool = True) -> None:
         with self._lock:

@@ -45,7 +45,11 @@ exact code path.
 Failure semantics: requests are validated BEFORE a future exists (same
 contract as the sync engine); if a batched dispatch still fails, the lane
 falls back to solving that batch's requests one at a time so a poisoned
-request fails ONLY its own future. ``close(drain=True)`` (also the
+request fails ONLY its own future. That fallback logs the batch's
+exception and counts every solo re-solve as a dispatch with driver
+``isolated`` (``SchedulerMetrics.dispatch_count(kind, "isolated")``), so a
+batched path that fails is never hidden behind correct answers.
+``close(drain=True)`` (also the
 context-manager exit) solves everything pending before returning;
 ``close(drain=False)`` cancels queued futures (``Future.cancelled()``)
 and only finishes batches already in flight. Neither path can hang on a
@@ -55,6 +59,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import logging
 import queue
 import threading
 import time
@@ -73,6 +78,7 @@ from repro.serve.engine import SolverEngine, _merge_deprecated_kw
 from repro.serve.metrics import SchedulerMetrics
 
 _SENTINEL = object()
+_log = logging.getLogger(__name__)
 
 
 @dataclass
@@ -507,6 +513,9 @@ class AsyncSolverEngine:
                 else:
                     self._solve_batch(lane, kind, reqs, extra)
             except Exception:
+                _log.exception(
+                    "batched %s dispatch of %d requests failed; re-solving "
+                    "each request alone", kind, len(reqs))
                 try:
                     self._isolate_failures(lane, kind, reqs)
                 except Exception as e:
@@ -740,6 +749,7 @@ class AsyncSolverEngine:
         for r in reqs:
             if r.future.done():          # already resolved before the raise
                 continue
+            self.metrics.record_isolated(kind)
             t0 = time.monotonic()
             try:
                 [res] = lane.engine.solve_requests(

@@ -6,6 +6,7 @@ bfs_relabel_sweeps_ref``), and the ops-level fixpoint driver reproduces
 the eager bidirectional fixpoint + combine (``ref.
 bfs_relabel_heights_ref``) bit-exactly — single and batched.
 """
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -122,3 +123,23 @@ def test_bidirectional_labels_disconnected_pocket():
     # (0,1) has no residual out-edges at all (cap stores OUT capacities),
     # so neither wavefront reaches it either:
     assert h[0, 1] == n
+
+
+def test_vmem_limit_admits_paper_grid_and_refuses_oversize():
+    """The compiled kernel's VMEM limit comes from its shape: the paper's
+    512² grid gets the ~33 planes the chip's compiler asks for, and a grid
+    past ``VMEM_CAP`` raises before dispatch instead of inside Mosaic."""
+    from repro.kernels.bfs_relabel.kernel import (VMEM_CAP, VMEM_DEFAULT,
+                                                  vmem_limit_bytes)
+    assert vmem_limit_bytes(64, 64) == VMEM_DEFAULT
+    assert 33 * 2 ** 20 <= vmem_limit_bytes(512, 512) <= VMEM_CAP
+    # a (480, 640) plane pads its lanes to 5 whole 128-lane tiles
+    assert vmem_limit_bytes(480, 640) == vmem_limit_bytes(480, 640 - 127)
+    big = jax.ShapeDtypeStruct((1, 1024, 1024), jnp.int32)
+    cap = jax.ShapeDtypeStruct((4, 1, 1024, 1024), jnp.float32)
+    with pytest.raises(ValueError, match="tiled relabel"):
+        jax.eval_shape(lambda *a: bfs_relabel_sweeps(*a, interpret=False),
+                       cap, big, big, big, big)
+    # interpret mode has no VMEM: the same shape traces
+    jax.eval_shape(lambda *a: bfs_relabel_sweeps(*a, interpret=True),
+                   cap, big, big, big, big)

@@ -111,3 +111,17 @@ def test_grid_push_round_bit_identical():
             np.testing.assert_allclose(np.asarray(fa), np.asarray(fb),
                                        err_msg=nm)
         st = a
+
+
+@pytest.mark.parametrize("H,W,blocks,want", [
+    (512, 512, (64, 128), (64, 128)),     # aligned: kept
+    (512, 512, (256, 256), (256, 256)),
+    (512, 512, (64, 64), (64, 512)),      # 64 lanes < 128: full width
+    (16, 16, (64, 128), (16, 16)),        # larger than the plane: full dims
+    (100, 640, (64, 128), (100, 128)),    # 64 does not divide 100
+    (480, 640, (12, 128), (480, 128)),    # 12 rows break the 8-row tiling
+])
+def test_tile_dims_are_tpu_aligned(H, W, blocks, want):
+    """Kernel blocks meet the TPU tiling or span the full dim."""
+    from repro.kernels.grid_push.kernel import tile_dims
+    assert tile_dims(H, W, *blocks) == want

@@ -2,6 +2,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import AxisType
 
 from repro.roofline_hlo import analyze, multipliers, parse_computations
 from repro.roofline import Roofline, cost_analysis_dict, model_flops_for
@@ -45,7 +46,7 @@ def test_nested_scan_multipliers():
 
 
 def test_collective_parse():
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = jax.make_mesh((1,), ("d",), axis_types=(AxisType.Auto,))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     def f(x):

@@ -290,6 +290,42 @@ def test_poisoned_request_fails_only_its_own_future(monkeypatch):
     assert snap["tickets"]["completed"] == 2
 
 
+def test_failed_batch_is_counted_isolated_and_logged(monkeypatch, caplog):
+    """A batched dispatch that raises is logged with its exception, and
+    every solo re-solve it falls back to counts as an ``isolated``
+    dispatch in the metrics and in the Prometheus exposition."""
+    from repro.obs import prometheus_text
+
+    real = kinds_mod.get_kind("assignment")
+    calls = []
+
+    def fail_first_batch(prep, **kw):
+        calls.append(len(prep.originals))
+        if len(calls) == 1:
+            raise RuntimeError("batched dispatch refused")
+        return real.solve_prepared(prep, **kw)
+
+    monkeypatch.setitem(kinds_mod._REGISTRY, "assignment",
+                        real._replace(solve_prepared=fail_first_batch))
+    rng = np.random.default_rng(11)
+    ws = [rng.integers(0, 50, (5, 5)) for _ in range(3)]
+    with caplog.at_level("ERROR", logger="repro.serve.scheduler"):
+        with AsyncSolverEngine(max_batch=3,
+                               max_delay_ms=LONG_DEADLINE_MS) as eng:
+            futs = [eng.submit("assignment", w) for w in ws]
+            for f, w in zip(futs, ws):
+                got = f.result(timeout=WAIT_S)
+                assert int(got.weight) == int(
+                    solve_assignment(jnp.asarray(w)).weight)
+    assert calls[0] == 3
+    assert eng.metrics.dispatch_count("assignment", "isolated") == 3
+    assert eng.metrics.dispatch_count("assignment", "masked") == 0
+    assert ('repro_dispatches_total{kind="assignment",driver="isolated"} 3'
+            in prometheus_text(eng.metrics))
+    [rec] = [r for r in caplog.records if "re-solving" in r.getMessage()]
+    assert "batched dispatch refused" in rec.exc_text
+
+
 # ----------------------------------------------------- adaptive dispatch
 
 def test_adaptive_dispatch_chooses_compaction_on_ragged_stream():

@@ -4,6 +4,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs.base import get_config, smoke_variant
 from repro.data.pipeline import DataConfig, host_batch
@@ -98,7 +99,8 @@ def test_dryrun_cell_on_host_mesh():
     import dataclasses
     from repro.configs import base as cb
     from repro.launch.specs import build_cell
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Auto, AxisType.Auto))
     cfg = get_config("smollm-135m")
     tiny = dataclasses.replace(smoke_variant(cfg), name=cfg.name + "-tiny")
     cb._REGISTRY[tiny.name] = tiny
