@@ -45,6 +45,13 @@ device stage of batch *k* and feeds the stats into its adaptive
 masked-vs-compacted dispatch policy; the blocking front ends below expose
 the same stats through ``stats_out=``.
 
+Both stages are spanned (``repro.obs.span``: the ambient tracer, and a
+running ``jax.profiler`` capture): ``batch/stage`` around each kind's host
+stage, and, in order, ``solve/dispatch`` (the jitted call returning),
+``solve/wait`` (the stats read, which blocks until the device is done)
+and ``solve/crop`` (one jitted crop per instance, on the idle device)
+inside its device stage.
+
 This module also REGISTERS the paper's two kinds (``"maxflow"`` and
 ``"assignment"``) with the solver-kind registry at the bottom of the file;
 the third kind, ``"matching"``, registers itself in
@@ -53,6 +60,7 @@ adding a kind.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, Iterable, NamedTuple, Sequence
 
 import jax
@@ -65,6 +73,7 @@ from repro.core.kinds import SolverKind, get_kind, register_kind
 from repro.core.maxflow.grid import (GridFlowResult, GridProblem,
                                      maxflow_grid_batch)
 from repro.core.refill import RefillRuntime
+from repro.obs.trace import span
 
 __all__ = [
     "pad_grid_problem", "stack_grid_problems", "pad_cost_matrix",
@@ -387,17 +396,19 @@ def prepare_maxflow_buckets(
     Returns one ``PreparedBucket`` per distinct bucket shape, each already
     padded with inert instances to the mesh's shard count (if any).
     """
-    problems = [GridProblem(*(jnp.asarray(a) for a in p)) for p in problems]
-    shapes = [tuple(p.cap_src.shape) for p in problems]
+    with span("batch/stage"):
+        problems = [GridProblem(*(jnp.asarray(a) for a in p))
+                    for p in problems]
+        shapes = [tuple(p.cap_src.shape) for p in problems]
 
-    def build(bshape, idxs, n_pad):
-        H, W = bshape
-        padded = [pad_grid_problem(problems[i], H, W) for i in idxs]
-        padded += [inert_grid_problem(H, W)] * n_pad
-        return stack_grid_problems(padded), None
+        def build(bshape, idxs, n_pad):
+            H, W = bshape
+            padded = [pad_grid_problem(problems[i], H, W) for i in idxs]
+            padded += [inert_grid_problem(H, W)] * n_pad
+            return stack_grid_problems(padded), None
 
-    return _make_buckets("maxflow", shapes, bucket=bucket, mesh=mesh,
-                         mesh_axis=mesh_axis, build=build)
+        return _make_buckets("maxflow", shapes, bucket=bucket, mesh=mesh,
+                             mesh_axis=mesh_axis, build=build)
 
 
 def solve_prepared_maxflow(
@@ -415,28 +426,39 @@ def solve_prepared_maxflow(
     cropped back to each request's original (H, W), exactly as
     ``solve_maxflow_batch`` returns them.
     """
-    res = maxflow_grid_batch(prep.stacked, backend=backend, compact=compact,
-                             mesh=mesh, mesh_axis=mesh_axis, **solver_kw)
-    out: dict[int, GridFlowResult] = {}
-    for b, i in enumerate(prep.idxs):
-        h, w = prep.shapes[b]
-        st = res.state
-        out[i] = GridFlowResult(
-            flow=res.flow[b],
-            cut=res.cut[b, :h, :w],
-            state=st._replace(
-                e=st.e[b, :h, :w], h=st.h[b, :h, :w],
-                cap=st.cap[b, :, :h, :w],
-                cap_src=st.cap_src[b, :h, :w],
-                cap_sink=st.cap_sink[b, :h, :w],
-                sink_flow=st.sink_flow[b], src_flow=st.src_flow[b],
-                heur=None if st.heur is None else st.heur[b]),
-            rounds=res.rounds[b],
-            converged=res.converged[b],
-            heuristics=None if res.heuristics is None else res.heuristics[b],
-        )
-    return out, _stats("maxflow", prep, res.rounds, res.converged, compact,
+    with span("solve/dispatch"):
+        res = maxflow_grid_batch(prep.stacked, backend=backend,
+                                 compact=compact, mesh=mesh,
+                                 mesh_axis=mesh_axis, **solver_kw)
+    with span("solve/wait"):
+        stats = _stats("maxflow", prep, res.rounds, res.converged, compact,
                        heuristics=res.heuristics)
+    with span("solve/crop"):
+        out = {i: _crop_grid(res, b, h=prep.shapes[b][0], w=prep.shapes[b][1])
+               for b, i in enumerate(prep.idxs)}
+    return out, stats
+
+
+@functools.partial(jax.jit, static_argnames=("h", "w"))
+def _crop_grid(res: GridFlowResult, b, *, h: int, w: int) -> GridFlowResult:
+    """Instance ``b`` of a bucket's result, cropped to its (h, w), in one
+    dispatch: the device stage crops after the solve, on an idle device,
+    where eager slicing would take a dispatch per field."""
+    st = res.state
+    return GridFlowResult(
+        flow=res.flow[b],
+        cut=res.cut[b, :h, :w],
+        state=st._replace(
+            e=st.e[b, :h, :w], h=st.h[b, :h, :w],
+            cap=st.cap[b, :, :h, :w],
+            cap_src=st.cap_src[b, :h, :w],
+            cap_sink=st.cap_sink[b, :h, :w],
+            sink_flow=st.sink_flow[b], src_flow=st.src_flow[b],
+            heur=None if st.heur is None else st.heur[b]),
+        rounds=res.rounds[b],
+        converged=res.converged[b],
+        heuristics=None if res.heuristics is None else res.heuristics[b],
+    )
 
 
 def solve_maxflow_batch(
@@ -521,17 +543,18 @@ def prepare_assignment_buckets(
     matrices so the device stage can recompute matching weights on the REAL
     costs (the padded solve runs on bonus-shifted values).
     """
-    costs = [np.asarray(w) for w in costs]
-    shapes = [(w.shape[-1],) for w in costs]
+    with span("batch/stage"):
+        costs = [np.asarray(w) for w in costs]
+        shapes = [(w.shape[-1],) for w in costs]
 
-    def build(bshape, idxs, n_pad):
-        (m,) = bshape
-        mats = [pad_cost_matrix(costs[i], m)[0] for i in idxs]
-        mats += [inert_cost_matrix(m)] * n_pad
-        return jnp.stack(mats), tuple(costs[i] for i in idxs)
+        def build(bshape, idxs, n_pad):
+            (m,) = bshape
+            mats = [pad_cost_matrix(costs[i], m)[0] for i in idxs]
+            mats += [inert_cost_matrix(m)] * n_pad
+            return jnp.stack(mats), tuple(costs[i] for i in idxs)
 
-    return _make_buckets("assignment", shapes, bucket=bucket, mesh=mesh,
-                         mesh_axis=mesh_axis, build=build)
+        return _make_buckets("assignment", shapes, bucket=bucket, mesh=mesh,
+                             mesh_axis=mesh_axis, build=build)
 
 
 def solve_prepared_assignment(
@@ -548,26 +571,36 @@ def solve_prepared_assignment(
     recomputed on the ORIGINAL (unpadded) costs, exactly as
     ``solve_assignment_batch`` returns them.
     """
-    res = solve_assignment(prep.stacked, compact=compact, mesh=mesh,
-                           mesh_axis=mesh_axis, **solver_kw)
-    out: dict[int, AssignmentResult] = {}
-    for b, i in enumerate(prep.idxs):
-        (n,) = prep.shapes[b]
-        col = res.col_of_row[b, :n]
-        valid = col < n          # unconverged rows may hold dummy cols
-        picked = jnp.take_along_axis(
-            jnp.asarray(prep.originals[b], jnp.int32),
-            jnp.minimum(col, n - 1)[:, None], axis=1)[:, 0]
-        weight = jnp.sum(jnp.where(valid, picked, 0))
-        out[i] = AssignmentResult(
-            col_of_row=col, weight=weight,
-            p_x=res.p_x[b, :n], p_y=res.p_y[b, :n],
-            rounds=res.rounds[b], pushes=res.pushes[b],
-            relabels=res.relabels[b], converged=res.converged[b],
-        )
-    return out, _stats("assignment", prep, res.rounds, res.converged,
+    with span("solve/dispatch"):
+        res = solve_assignment(prep.stacked, compact=compact, mesh=mesh,
+                               mesh_axis=mesh_axis, **solver_kw)
+    with span("solve/wait"):
+        stats = _stats("assignment", prep, res.rounds, res.converged,
                        compact)
+    with span("solve/crop"):
+        out = {i: _crop_assignment(
+                   res, b, jnp.asarray(prep.originals[b], jnp.int32),
+                   n=prep.shapes[b][0])
+               for b, i in enumerate(prep.idxs)}
+    return out, stats
 
+
+@functools.partial(jax.jit, static_argnames=("n",))
+def _crop_assignment(res: AssignmentResult, b, original,
+                     *, n: int) -> AssignmentResult:
+    """Instance ``b`` of a bucket's result, cropped to its n, with the
+    weight recomputed on its ``original`` costs, in one dispatch (see
+    ``_crop_grid``)."""
+    col = res.col_of_row[b, :n]
+    valid = col < n              # unconverged rows may hold dummy cols
+    picked = jnp.take_along_axis(
+        original, jnp.minimum(col, n - 1)[:, None], axis=1)[:, 0]
+    return AssignmentResult(
+        col_of_row=col, weight=jnp.sum(jnp.where(valid, picked, 0)),
+        p_x=res.p_x[b, :n], p_y=res.p_y[b, :n],
+        rounds=res.rounds[b], pushes=res.pushes[b],
+        relabels=res.relabels[b], converged=res.converged[b],
+    )
 
 def solve_assignment_batch(
     costs: Sequence,

@@ -24,8 +24,10 @@ Payload forms accepted by the validator (both canonicalize to a dense
 """
 from __future__ import annotations
 
+import functools
 from typing import Iterable, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -36,6 +38,7 @@ from repro.core.refill import RefillRuntime
 from repro.core.matching.bfs import (MatchingResult, _matching_spec,
                                      match_bipartite, match_bipartite_batch)
 from repro.core.matching.ref import hopcroft_karp
+from repro.obs.trace import span
 
 __all__ = [
     "MatchingResult", "match_bipartite", "match_bipartite_batch",
@@ -134,17 +137,18 @@ def prepare_matching_buckets(
     ``(edges, (nl, nr))`` edge-list forms work here exactly as they do at
     engine submit time.
     """
-    adjs = [validate_matching_problem(p) for p in payloads]
-    shapes = [a.shape for a in adjs]
+    with span("batch/stage"):
+        adjs = [validate_matching_problem(p) for p in payloads]
+        shapes = [a.shape for a in adjs]
 
-    def build(bshape, idxs, n_pad):
-        NL, NR = bshape
-        mats = [pad_matching_problem(adjs[i], NL, NR) for i in idxs]
-        mats += [inert_matching_problem(NL, NR)] * n_pad
-        return jnp.asarray(np.stack(mats)), None
+        def build(bshape, idxs, n_pad):
+            NL, NR = bshape
+            mats = [pad_matching_problem(adjs[i], NL, NR) for i in idxs]
+            mats += [inert_matching_problem(NL, NR)] * n_pad
+            return jnp.asarray(np.stack(mats)), None
 
-    return _make_buckets("matching", shapes, bucket=bucket, mesh=mesh,
-                         mesh_axis=mesh_axis, build=build)
+        return _make_buckets("matching", shapes, bucket=bucket, mesh=mesh,
+                             mesh_axis=mesh_axis, build=build)
 
 
 def solve_prepared_matching(
@@ -162,19 +166,30 @@ def solve_prepared_matching(
     (padded vertices are isolated, so the crop discards only ``-1``s and
     the cardinality is unchanged).
     """
-    res = match_bipartite_batch(prep.stacked, compact=compact, mesh=mesh,
-                                mesh_axis=mesh_axis, **solver_kw)
-    out: dict[int, MatchingResult] = {}
-    for b, i in enumerate(prep.idxs):
-        nl, nr = prep.shapes[b]
-        out[i] = MatchingResult(
-            match_row=res.match_row[b, :nl],
-            match_col=res.match_col[b, :nr],
-            cardinality=res.cardinality[b],
-            rounds=res.rounds[b],
-            converged=res.converged[b],
-        )
-    return out, _stats("matching", prep, res.rounds, res.converged, compact)
+    with span("solve/dispatch"):
+        res = match_bipartite_batch(prep.stacked, compact=compact, mesh=mesh,
+                                    mesh_axis=mesh_axis, **solver_kw)
+    with span("solve/wait"):
+        stats = _stats("matching", prep, res.rounds, res.converged, compact)
+    with span("solve/crop"):
+        out = {i: _crop_matching(res, b, nl=prep.shapes[b][0],
+                                 nr=prep.shapes[b][1])
+               for b, i in enumerate(prep.idxs)}
+    return out, stats
+
+
+@functools.partial(jax.jit, static_argnames=("nl", "nr"))
+def _crop_matching(res: MatchingResult, b, *, nl: int,
+                   nr: int) -> MatchingResult:
+    """Instance ``b`` of a bucket's result, cropped to its (nl, nr), in one
+    dispatch (see ``repro.core.batch._crop_grid``)."""
+    return MatchingResult(
+        match_row=res.match_row[b, :nl],
+        match_col=res.match_col[b, :nr],
+        cardinality=res.cardinality[b],
+        rounds=res.rounds[b],
+        converged=res.converged[b],
+    )
 
 
 def _matching_inert(shape: tuple) -> np.ndarray:

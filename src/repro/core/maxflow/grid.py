@@ -235,8 +235,6 @@ def bfs_heights(cap: jax.Array, cap_sink: jax.Array, h_prev: jax.Array,
     climbing toward the source (up to 2N-1) are never reset — resetting would
     let stranded excess oscillate between heuristic invocations.
     """
-    h0 = jnp.where(cap_sink > 0, jnp.int32(1), INF_H)
-
     def body(carry):
         h, _, it = carry
         relaxed = h
@@ -251,8 +249,12 @@ def bfs_heights(cap: jax.Array, cap_sink: jax.Array, h_prev: jax.Array,
         _, changed, it = carry
         return changed & (it < max_iters)
 
-    h, _, _ = jax.lax.while_loop(cond, body, (h0, jnp.bool_(True), jnp.int32(0)))
-    return jnp.where(h >= INF_H, jnp.maximum(h_prev, n_nodes), h)  # gap relabel
+    with jax.named_scope("maxflow/relabel"):
+        h0 = jnp.where(cap_sink > 0, jnp.int32(1), INF_H)
+        h, _, _ = jax.lax.while_loop(cond, body,
+                                     (h0, jnp.bool_(True), jnp.int32(0)))
+        # gap relabel
+        return jnp.where(h >= INF_H, jnp.maximum(h_prev, n_nodes), h)
 
 
 def check_no_violations(state: GridFlowState) -> jax.Array:
@@ -344,7 +346,8 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
             def inner(_, carry):
                 s, ewma = carry
                 remaining = jnp.maximum(_gsum(s.e), 1.0)
-                s, retired = jacobi_round_scheduled(s, n_nodes)
+                with jax.named_scope("maxflow/push"):
+                    s, retired = jacobi_round_scheduled(s, n_nodes)
                 # EWMA of per-round progress: excess RETIRED at a terminal
                 # this round as a fraction of the excess still in flight
                 # (inter-node moves don't count — height-plateau ping-pong
@@ -361,16 +364,18 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
                        & (ewma < stall_threshold))
 
             def relabel(s: GridFlowState) -> jax.Array:
-                h_bfs = bfs_relabel_heights(s.cap, s.cap_src, s.cap_sink,
-                                            s.h, n_nodes, iters)
-                return jnp.where(stalled[..., None, None], h_bfs, s.h)
+                with jax.named_scope("maxflow/relabel"):
+                    h_bfs = bfs_relabel_heights(s.cap, s.cap_src, s.cap_sink,
+                                                s.h, n_nodes, iters)
+                    return jnp.where(stalled[..., None, None], h_bfs, s.h)
 
             h_new = jax.lax.cond(jnp.any(stalled), relabel,
                                  lambda s: s.h, new)
             return _count_heur(new._replace(h=h_new), stalled)
 
         def inner(_, s):
-            return round_fn(s, n_nodes)
+            with jax.named_scope("maxflow/push"):
+                return round_fn(s, n_nodes)
 
         new = jax.lax.fori_loop(0, rounds_per_heuristic, inner, state)
         new = new._replace(
@@ -401,19 +406,20 @@ def _grid_init(cap0, cs0, ct0, *, bfs_max_iters: int) -> GridFlowState:
     bshape = tuple(b)
     n_nodes = jnp.int32(H * W + 2)
     bfs_iters = bfs_max_iters or (H * W + 2)
-    state = GridFlowState(
-        e=cs0.astype(jnp.float32),
-        h=jnp.zeros(bshape + (H, W), jnp.int32),
-        cap=cap0.astype(jnp.float32),
-        cap_src=cs0.astype(jnp.float32),   # residual x -> s after saturation
-        cap_sink=ct0.astype(jnp.float32),
-        sink_flow=jnp.zeros(bshape, jnp.float32),
-        src_flow=jnp.zeros(bshape, jnp.float32),
-        heur=jnp.zeros(bshape, jnp.int32),  # init BFS below not counted
-    )
-    # Start from BFS-consistent heights (global relabel at round 0).
-    return state._replace(
-        h=bfs_heights(state.cap, state.cap_sink, state.h, n_nodes, bfs_iters))
+    with jax.named_scope("maxflow/init"):
+        state = GridFlowState(
+            e=cs0.astype(jnp.float32),
+            h=jnp.zeros(bshape + (H, W), jnp.int32),
+            cap=cap0.astype(jnp.float32),
+            cap_src=cs0.astype(jnp.float32),   # residual x -> s (saturated)
+            cap_sink=ct0.astype(jnp.float32),
+            sink_flow=jnp.zeros(bshape, jnp.float32),
+            src_flow=jnp.zeros(bshape, jnp.float32),
+            heur=jnp.zeros(bshape, jnp.int32),  # init BFS below not counted
+        )
+        # Start from BFS-consistent heights (global relabel at round 0).
+        return state._replace(h=bfs_heights(state.cap, state.cap_sink,
+                                            state.h, n_nodes, bfs_iters))
 
 
 def _grid_finalize(state: GridFlowState, rounds, *,
@@ -425,15 +431,17 @@ def _grid_finalize(state: GridFlowState, rounds, *,
     H, W = state.e.shape[-2:]
     n_nodes = jnp.int32(H * W + 2)
     bfs_iters = bfs_max_iters or (H * W + 2)
-    h_bfs = bfs_heights(state.cap, state.cap_sink, state.h, n_nodes, bfs_iters)
-    return GridFlowResult(
-        flow=state.sink_flow,
-        cut=h_bfs < n_nodes,
-        state=state,
-        rounds=rounds,
-        converged=~jnp.any(state.e > 0, axis=(-2, -1)),
-        heuristics=state.heur,
-    )
+    with jax.named_scope("maxflow/finalize"):
+        h_bfs = bfs_heights(state.cap, state.cap_sink, state.h, n_nodes,
+                            bfs_iters)
+        return GridFlowResult(
+            flow=state.sink_flow,
+            cut=h_bfs < n_nodes,
+            state=state,
+            rounds=rounds,
+            converged=~jnp.any(state.e > 0, axis=(-2, -1)),
+            heuristics=state.heur,
+        )
 
 
 def _solve_grid(cap0, cs0, ct0, *, rounds_per_heuristic, max_rounds,

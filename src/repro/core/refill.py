@@ -31,7 +31,6 @@ closed-batch path (same padding shape).  The serving layer
 """
 from __future__ import annotations
 
-import contextlib
 import time
 from typing import Any, Callable, NamedTuple
 
@@ -41,6 +40,7 @@ import numpy as np
 
 from repro.core.kinds import get_kind
 from repro.core.solver_loop import LoopSpec, run_compacted
+from repro.obs.trace import span, use_tracer
 
 __all__ = ["RefillRuntime", "refill_runtime", "RefillSolver"]
 
@@ -111,7 +111,8 @@ class RefillSolver:
       tracer: optional ``repro.obs.Tracer`` — the session records a
         ``device-solve`` span around its run and ``bucket/pad`` spans
         around each payload intake (the serving engines thread their
-        tracer through here). ``None`` records nothing.
+        tracer through here). ``None`` records nothing; ``device-solve``
+        still reaches a running ``jax.profiler`` capture.
       **solver_kw: the kind's static solver knobs (``backend=``,
         ``max_rounds=``, ...), forwarded to the refill runtime factory.
     """
@@ -320,11 +321,9 @@ class RefillSolver:
                 if on_result is not None:
                     on_result(idx, res)
 
-        span = (contextlib.nullcontext() if self.tracer is None else
-                self.tracer.span("device-solve", kind=self.kind.name,
-                                 bucket=list(shape), capacity=cap,
-                                 driver="refill"))
-        with span:
+        with use_tracer(self.tracer), \
+                span("device-solve", kind=self.kind.name, bucket=list(shape),
+                     capacity=cap, driver="refill"):
             run_compacted(rt.spec, state, cap, lanes=session._lanes,
                           refill=_Hook())
         return results

@@ -140,6 +140,7 @@ def bfs_relabel_sweeps(cap, seed_t, seed_s, dt, ds, *,
     dt, ds = pl.pallas_call(
         _bfs_relabel_kernel,
         grid=(B,),
+        name="bfs_relabel_sweeps",
         in_specs=[spec4, spec2d, spec2d, spec2d, spec2d],
         out_specs=[spec2d, spec2d],
         out_shape=[jax.ShapeDtypeStruct((B, H, W), jnp.int32),

@@ -81,6 +81,7 @@ def bidding(c: jax.Array, p_y: jax.Array, mask: jax.Array,
     min1, arg1, min2 = pl.pallas_call(
         functools.partial(_bidding_kernel, block_cols=bc),
         grid=grid,
+        name="bidding",
         in_specs=[
             pl.BlockSpec((br, bc), lambda i, j: (i, j)),
             pl.BlockSpec((1, bc), lambda i, j: (0, j)),

@@ -89,6 +89,7 @@ def frontier(adj: jax.Array, root_row: jax.Array, match_row: jax.Array,
     min_root, claim_row = pl.pallas_call(
         functools.partial(_frontier_kernel, block_rows=br, block_cols=bc),
         grid=grid,
+        name="frontier",
         in_specs=[
             pl.BlockSpec((br, bc), lambda j, i: (i, j)),
             col_spec,

@@ -162,6 +162,7 @@ def grid_push_decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes,
     return pl.pallas_call(
         _grid_push_kernel,
         grid_spec=grid_spec,
+        name="grid_push_decide",
         out_shape=[jax.ShapeDtypeStruct(e.shape, jnp.int32),
                    jax.ShapeDtypeStruct((6,) + e.shape, jnp.float32)],
         interpret=interpret,
@@ -225,6 +226,7 @@ def grid_push_decide_sched(e, h, cap, nbr_h, cap_src, cap_sink, sched,
     return pl.pallas_call(
         _grid_push_sched_kernel,
         grid_spec=grid_spec,
+        name="grid_push_decide_sched",
         out_shape=[jax.ShapeDtypeStruct((B, H, W), jnp.int32),
                    jax.ShapeDtypeStruct((6, B, H, W), jnp.float32)],
         interpret=interpret,

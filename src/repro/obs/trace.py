@@ -5,30 +5,42 @@ any number of threads at once and exports them as a plain event list or
 as Chrome-trace JSON (the format Perfetto / ``chrome://tracing`` load
 directly).  The serving stack emits one span chain per ticket::
 
-    submit -> queue-wait -> bucket/pad -> device-solve -> resolve
+    submit -> queue-wait -> solve -> cache/put -> resolve
 
-plus ``refill-admission`` spans at continuous-batching cycle boundaries,
-every span tagged with ``ticket`` / ``kind`` / bucket-shape attributes so
-a trace reconstructs each request's full lifecycle (tests/test_obs.py).
+with the batch-level ``bucket/pad`` (holding ``batch/stage``) and
+``device-solve`` (holding ``solve/dispatch``, ``solve/crop`` and
+``solve/wait``) spans beside it, plus ``refill-admission`` spans at
+continuous-batching cycle boundaries; every per-ticket span is tagged with
+``ticket`` / ``kind`` so a trace reconstructs each request's full
+lifecycle (tests/test_obs.py, docs/observability.md).
 
-Design constraints (the ISSUE's "lock-free in the hot path"):
+``span(name, **attrs)`` is the one call instrumented code makes.  It has
+two halves, each live only when its consumer is:
+
+* the TRACER half records a ``Span`` into the ambient tracer
+  (``use_tracer`` / ``current_tracer``): code that holds a tracer runs its
+  stages under ``use_tracer(tracer)``, and everything they call sees it;
+* the PROFILER half opens a ``jax.profiler.TraceAnnotation`` while a
+  profiler capture is running, so the span lands on the capture's host
+  plane, on the device trace's own clock (``step_annotation``).
+
+Design constraints ("lock-free in the hot path"):
 
 * RECORDING takes no lock: finished spans are appended to a
   ``collections.deque`` (append is atomic under the GIL) and span nesting
   lives in per-thread stacks (``threading.local``), so submit paths, the
   scheduler thread, and lane threads never contend.
-* DISABLED tracing costs one ``None`` check: instrumented code guards
-  every span with ``if tracer is not None`` and the ambient tracer is a
-  ``contextvars.ContextVar`` (``current_tracer()``), so the untraced hot
-  path does no clock reads, no allocation, no dict building.
+* DISABLED tracing is cheap: with no tracer and no capture, ``span`` costs
+  one contextvar read and one C call (the profiler's ``is_enabled``) and
+  returns a shared no-op context — no clock reads, no span records; an
+  engine's ``use_tracer(None)`` around a stage adds one contextvar read.
 * Timestamps come from ``time.monotonic()`` — the same clock the
   scheduler's deadlines and latency metrics use, so retroactive spans
   (``record``) built from scheduler timestamps land on one axis.
 
-Nothing here imports jax: the module stays importable (and the tracer
-testable) without touching device state.  The device-timeline hook
-(``step_annotation``) imports ``jax.profiler`` lazily and only when
-annotating.
+Nothing here imports jax at module import: the module stays importable
+(and the tracer testable) without touching device state.  The profiler
+half and the compile listener (``watch_compiles``) import jax lazily.
 """
 from __future__ import annotations
 
@@ -169,6 +181,7 @@ def load_trace(path) -> list[dict]:
 
 _tracer_var: contextvars.ContextVar[Tracer | None] = \
     contextvars.ContextVar("repro_obs_tracer", default=None)
+_NOOP = contextlib.nullcontext()
 
 
 def current_tracer() -> Tracer | None:
@@ -181,9 +194,20 @@ def current_tracer() -> Tracer | None:
     return _tracer_var.get()
 
 
-@contextlib.contextmanager
 def use_tracer(tracer: Tracer | None):
-    """Install ``tracer`` as the ambient tracer for the ``with`` body."""
+    """Install ``tracer`` as the ambient tracer for the ``with`` body.
+
+    Installing ``None`` where none is installed changes nothing, so it
+    returns the shared no-op context after one contextvar read: an engine
+    without a tracer pays that per stage, not a set and a reset.
+    """
+    if tracer is None and _tracer_var.get() is None:
+        return _NOOP
+    return _installed(tracer)
+
+
+@contextlib.contextmanager
+def _installed(tracer: Tracer | None):
     token = _tracer_var.set(tracer)
     try:
         yield tracer
@@ -191,17 +215,53 @@ def use_tracer(tracer: Tracer | None):
         _tracer_var.reset(token)
 
 
-# ---- device-timeline hook ------------------------------------------------
+# ---- one span call, two halves -------------------------------------------
+_capture_on = None          # the profiler's ``is_enabled``, bound on first use
+
+
+def _profiling() -> bool:
+    """Is a ``jax.profiler`` capture running? One C call once bound."""
+    global _capture_on
+    if _capture_on is None:
+        try:
+            from jax._src.lib import _profiler
+            _capture_on = _profiler.TraceMe.is_enabled
+        except Exception:                              # pragma: no cover
+            _capture_on = lambda: False                # noqa: E731
+    return _capture_on()
+
+
+def span(name: str, **attrs: Any):
+    """Context manager: the span ``name`` around the ``with`` body.
+
+    The TRACER half records into the ambient tracer (``use_tracer`` /
+    ``current_tracer()``); the PROFILER half opens a ``TraceAnnotation``
+    while a ``jax.profiler`` capture runs, putting the span on the
+    capture's host plane on the device trace's clock.  With neither live
+    it returns a shared no-op context after one contextvar read and one C
+    call.  ``attrs`` go to the tracer half only.
+    """
+    tracer = _tracer_var.get()
+    capture = _profiling()
+    if tracer is None and not capture:
+        return _NOOP
+    return _both_halves(name, tracer, capture, attrs)
+
+
+@contextlib.contextmanager
+def _both_halves(name, tracer, capture, attrs):
+    with (tracer.span(name, **attrs) if tracer is not None else _NOOP), \
+            (step_annotation(name) if capture else _NOOP):
+        yield
+
 
 @contextlib.contextmanager
 def step_annotation(name: str, **attrs: Any):
-    """Annotate the jax-profiler device timeline for the ``with`` body.
+    """The profiler half of ``span``: annotate a running capture.
 
-    When a ``jax.profiler.trace`` capture is running, the annotation shows
-    up on the device timeline under ``name`` — lining device work up with
-    the host spans this module records.  A no-op (and jax-import-free)
-    when jax is unavailable; instrumented code additionally gates it on an
-    active tracer so the untraced hot path never touches the profiler.
+    While a ``jax.profiler`` capture runs, the ``with`` body shows up
+    under ``name`` on the capture's host plane, on the same clock as the
+    device ops.  A no-op without a capture (and without jax).
     """
     try:
         from jax.profiler import TraceAnnotation
@@ -210,3 +270,32 @@ def step_annotation(name: str, **attrs: Any):
         return
     with TraceAnnotation(name, **attrs):
         yield
+
+
+# ---- compile spans ---------------------------------------------------------
+
+COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "compile/cache",
+}
+
+
+def watch_compiles(tracer: Tracer):
+    """Record a span into ``tracer`` for every program jax compiles
+    (``compile``) or loads from its persistent cache (``compile/cache``).
+
+    Registers a ``jax.monitoring`` duration listener: each span ends when
+    jax reports the event, starts its reported duration earlier, and
+    lands on the compiling thread.  Returns ``unwatch()``, which removes
+    the listener; call it once.
+    """
+    import jax.monitoring
+
+    def listen(event: str, duration: float, **_: Any) -> None:
+        name = COMPILE_EVENTS.get(event)
+        if name is not None:
+            t1 = time.monotonic()
+            tracer.record(name, t1 - duration, t1, event=event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    return lambda: jax.monitoring.unregister_event_duration_listener(listen)

@@ -39,7 +39,7 @@ from repro.core.batch import (BucketStats, PreparedBucket,  # noqa: F401
                               validate_grid_problem)
 from repro.core.kinds import get_kind
 from repro.models.layers import Sharder
-from repro.obs.trace import current_tracer, step_annotation
+from repro.obs.trace import current_tracer, span, use_tracer
 from repro.models.model import apply_model, init_caches
 
 
@@ -134,12 +134,15 @@ class SolverEngine:
         ``solver_kw`` for the two original kinds; folded into
         ``solver_kw`` with a ``DeprecationWarning``.
       tracer: optional ``repro.obs.Tracer`` recording lifecycle spans
-        (``submit`` / ``bucket/pad`` / ``device-solve``) through this
-        engine. Defaults to the AMBIENT tracer at construction time
-        (``repro.obs.use_tracer`` — captured once, because contextvars do
-        not cross the threads a scheduler may drive this engine from);
-        ``None`` (no ambient tracer) records nothing and costs one
-        ``None`` check per stage.
+        (``submit`` / ``validate`` / ``bucket/pad`` / ``device-solve`` /
+        ``cache/put``) through this engine. Defaults to the AMBIENT tracer
+        at construction time (``repro.obs.use_tracer`` — captured once,
+        because contextvars do not cross the threads a scheduler may drive
+        this engine from). Every stage runs under ``use_tracer(tracer)``,
+        so the spans of ``repro.core.batch`` (``batch/stage``,
+        ``solve/dispatch`` / ``solve/crop`` / ``solve/wait``) land in it
+        too. ``None`` records nothing; spans still reach a running
+        ``jax.profiler`` capture.
       cache: optional ``repro.core.warm.SolutionCache`` backing the
         incremental re-solve path (``submit(..., base=, delta=)``, see
         docs/warmstart.md). Defaults to a private per-engine cache;
@@ -241,7 +244,8 @@ class SolverEngine:
             raise ValueError("submit(delta=...) needs base= to apply it to")
         elif payload is None:
             raise ValueError("submit() needs a payload (or base=/delta=)")
-        payload = get_kind(kind).validate(payload)
+        with use_tracer(self.tracer), span("validate", kind=kind):
+            payload = get_kind(kind).validate(payload)
         t = self._ticket()
         self._queues.setdefault(kind, []).append((t, payload))
         if ws is not None:
@@ -279,11 +283,8 @@ class SolverEngine:
         this engine's bucket/mesh config) — the stage the async scheduler
         overlaps with the previous batch's device solve.
         """
-        if self.tracer is None:
-            return get_kind(kind).prepare_buckets(
-                payloads, bucket=self.bucket, mesh=self.mesh,
-                mesh_axis=self.mesh_axis)
-        with self.tracer.span("bucket/pad", kind=kind, n=len(payloads)):
+        with use_tracer(self.tracer), \
+                span("bucket/pad", kind=kind, n=len(payloads)):
             return get_kind(kind).prepare_buckets(
                 payloads, bucket=self.bucket, mesh=self.mesh,
                 mesh_axis=self.mesh_axis)
@@ -298,17 +299,11 @@ class SolverEngine:
         Returns ``({payload_position: result}, BucketStats)``.
         """
         compact = self.compact if compact is None else compact
-        if self.tracer is None:
-            return get_kind(prep.kind).solve_prepared(
-                prep, compact=compact, mesh=self.mesh,
-                mesh_axis=self.mesh_axis,
-                **self.solver_kw.get(prep.kind, {}))
-        driver = "compacted" if compact else "masked"
-        with self.tracer.span("device-solve", kind=prep.kind,
-                              bucket=list(prep.shape),
-                              n_real=len(prep.idxs), driver=driver,
-                              init="cold"), \
-                step_annotation(f"solve:{prep.kind}"):
+        with use_tracer(self.tracer), \
+                span("device-solve", kind=prep.kind, bucket=list(prep.shape),
+                     n_real=len(prep.idxs),
+                     driver="compacted" if compact else "masked",
+                     init="cold"):
             return get_kind(prep.kind).solve_prepared(
                 prep, compact=compact, mesh=self.mesh,
                 mesh_axis=self.mesh_axis,
@@ -334,12 +329,9 @@ class SolverEngine:
             kw = dict(bucket=self.bucket, compact=compact, mesh=self.mesh,
                       mesh_axis=self.mesh_axis, stats_out=stats_out,
                       **self.solver_kw.get(kind, {}))
-            if self.tracer is None:
-                return solve_warm(kind, payloads, warm, **kw)
-            with self.tracer.span("device-solve", kind=kind,
-                                  n_real=len(payloads),
-                                  n_warm=len(warm), init="warm"), \
-                    step_annotation(f"solve:{kind}"):
+            with use_tracer(self.tracer), \
+                    span("device-solve", kind=kind, n_real=len(payloads),
+                         n_warm=len(warm), init="warm"):
                 return solve_warm(kind, payloads, warm, **kw)
         results = [None] * len(payloads)
         for prep in self.prepare(kind, payloads):
@@ -401,7 +393,9 @@ class SolverEngine:
             self._warm_of_ticket.pop(t, None)
             if r is None or k.solution_of is None:
                 continue
-            key = self.cache.put(kind, p, k.solution_of(r))
+            with use_tracer(self.tracer), \
+                    span("cache/put", ticket=t, kind=kind):
+                key = self.cache.put(kind, p, k.solution_of(r))
             self._key_of_ticket[t] = (kind, key)
         if self.metrics is None or not tickets:
             return

@@ -73,7 +73,8 @@ from repro.core.kinds import get_kind
 from repro.core.refill import refill_runtime
 from repro.core.solver_loop import trace_cycles
 from repro.launch.mesh import scheduler_lanes, shard_count
-from repro.obs.trace import current_tracer
+from repro.obs.trace import (current_tracer, span, use_tracer,
+                             watch_compiles)
 from repro.serve.engine import SolverEngine, _merge_deprecated_kw
 from repro.serve.metrics import SchedulerMetrics
 
@@ -178,13 +179,17 @@ class AsyncSolverEngine:
       metrics: optional ``SchedulerMetrics`` to record into (one is
         created otherwise; read it via ``.metrics.snapshot()``).
       tracer: optional ``repro.obs.Tracer`` recording per-ticket
-        lifecycle spans (``submit`` → ``queue-wait`` → ``bucket/pad`` →
-        ``device-solve`` → ``refill-admission`` → ``resolve``, every span
-        tagged ``ticket``/``kind``). Defaults to the AMBIENT tracer at
-        construction (``repro.obs.use_tracer``) — captured once here and
-        handed to the lane engines, because contextvars do not cross into
-        the scheduler/lane threads. ``None`` traces nothing; the hot path
-        then pays one ``None`` check per stage.
+        lifecycle spans (``submit`` (holding ``validate``) →
+        ``queue-wait`` → ``solve`` → ``cache/put`` → ``resolve``, tagged
+        ``ticket``/``kind``), the batch spans of the lane engines
+        (``bucket/pad``, ``device-solve`` and the ``repro.core.batch``
+        spans inside them), ``refill-admission``, and a ``compile`` span
+        for every program jax compiles while the engine is open
+        (``repro.obs.watch_compiles``, dropped at ``close``). Defaults to
+        the AMBIENT tracer at construction (``repro.obs.use_tracer``) —
+        captured once here and handed to the lane engines, because
+        contextvars do not cross into the scheduler/lane threads. ``None``
+        traces nothing.
 
     Results are bit-identical to ``SolverEngine.flush()`` of the same
     request stream chunked the same way — and, transitively, to a loop of
@@ -258,6 +263,8 @@ class AsyncSolverEngine:
                 target=self._lane_loop, args=(lane,),
                 name=f"solver-lane-{i}", daemon=True)
             lane.thread.start()
+        self._unwatch = (watch_compiles(self.tracer)
+                         if self.tracer is not None else None)
 
     # ---- submission ------------------------------------------------------
 
@@ -290,9 +297,31 @@ class AsyncSolverEngine:
         k = get_kind(kind)
         if res is None or k.solution_of is None:
             return
-        key = self._cache.put(kind, req.payload, k.solution_of(res))
+        with use_tracer(self.tracer), \
+                span("cache/put", ticket=req.ticket, kind=kind):
+            key = self._cache.put(kind, req.payload, k.solution_of(res))
         with self._lock:
             self._key_of_ticket[req.ticket] = (kind, key)
+
+    def _finish(self, kind: str, req: "_Request", res, t0: float,
+                t1: float, **solve_attrs) -> None:
+        """Resolve a solved request: its ``solve`` span ``[t0, t1]``, the
+        cache put (spanned ``cache/put``), its latency — taken after the
+        put, which the caller waits behind — then the future, spanned
+        ``resolve``. Metrics come BEFORE resolution: a caller waiting on
+        ``result()`` may read ``snapshot()`` the instant it resolves."""
+        if self.tracer is not None:
+            self.tracer.record("solve", t0, t1, ticket=req.ticket,
+                               kind=kind, **solve_attrs)
+        self._cache_result(kind, req, res)
+        self.metrics.record_done((time.monotonic() - req.submit_t) * 1e3)
+        if self.tracer is None:
+            req.future.set_result(res)
+        else:
+            tr0 = time.monotonic()
+            req.future.set_result(res)
+            self.tracer.record("resolve", tr0, time.monotonic(),
+                               ticket=req.ticket, kind=kind)
 
     def submit(self, kind: str, payload=None, *,
                deadline_ms: float | None = None,
@@ -332,7 +361,8 @@ class AsyncSolverEngine:
             raise ValueError("submit(delta=...) needs base= to apply it to")
         elif payload is None:
             raise ValueError("submit() needs a payload (or base=/delta=)")
-        payload = get_kind(kind).validate(payload)
+        with use_tracer(self.tracer), span("validate", kind=kind):
+            payload = get_kind(kind).validate(payload)
         now = time.monotonic()
         budget = self.max_delay_ms if deadline_ms is None else deadline_ms
         if budget <= 0:
@@ -529,6 +559,9 @@ class AsyncSolverEngine:
     def _solve_batch(self, lane: _Lane, kind: str, reqs: list[_Request],
                      preps: list) -> None:
         results: dict[int, Any] = {}
+        # per-ticket view of its bucket's dispatch (the engine also
+        # records the aggregate device-solve span): idx -> (t0, t1, attrs)
+        solve_of: dict[int, tuple] = {}
         for prep in preps:
             compact = choose_driver(
                 self.metrics.convergence.spread(kind),
@@ -538,16 +571,11 @@ class AsyncSolverEngine:
             with trace_cycles(self.metrics.record_live_trace):
                 out, stats = lane.engine.solve_prepared(
                     prep, compact=compact)
-            if self.tracer is not None:
-                # per-ticket view of the bucket dispatch (the engine also
-                # records the aggregate device-solve span)
-                t_end = time.monotonic()
-                for i in prep.idxs:
-                    self.tracer.record(
-                        "solve", t_disp, t_end, ticket=reqs[i].ticket,
-                        kind=kind, bucket=list(prep.shape),
-                        driver="compacted" if compact else "masked",
-                        init="cold")
+            t_end = time.monotonic()
+            attrs = dict(bucket=list(prep.shape), init="cold",
+                         driver="compacted" if compact else "masked")
+            for i in prep.idxs:
+                solve_of[i] = (t_disp, t_end, attrs)
             self.metrics.record_dispatch(
                 kind, compact=compact, spread=stats.spread,
                 occupancy=stats.n_real / self.max_batch,
@@ -555,19 +583,9 @@ class AsyncSolverEngine:
             results.update(out)
         # cold solves count into the warm-fraction denominator too
         self.metrics.record_warm(kind, 0, len(reqs))
-        now = time.monotonic()
         for i, r in enumerate(reqs):
-            self._cache_result(kind, r, results[i])
-            # metrics BEFORE resolution: a caller waiting on result() may
-            # read snapshot() the instant the future resolves
-            self.metrics.record_done((now - r.submit_t) * 1e3)
-            if self.tracer is None:
-                r.future.set_result(results[i])
-            else:
-                tr0 = time.monotonic()
-                r.future.set_result(results[i])
-                self.tracer.record("resolve", tr0, time.monotonic(),
-                                   ticket=r.ticket, kind=kind)
+            t0, t1, attrs = solve_of[i]
+            self._finish(kind, r, results[i], t0, t1, **attrs)
 
     def _solve_warm_batch(self, lane: _Lane, kind: str,
                           reqs: list[_Request]) -> None:
@@ -604,21 +622,10 @@ class AsyncSolverEngine:
                         if cold_ewma is not None and warm_rounds else None)
         self.metrics.record_warm(kind, len(warm), len(reqs) - len(warm),
                                  rounds_saved)
-        now = time.monotonic()
         for i, r in enumerate(reqs):
-            self._cache_result(kind, r, results[i])
-            self.metrics.record_done((now - r.submit_t) * 1e3)
-            if self.tracer is None:
-                r.future.set_result(results[i])
-            else:
-                self.tracer.record(
-                    "solve", t_disp, t_end, ticket=r.ticket, kind=kind,
-                    driver="compacted" if compact else "masked",
-                    init="warm" if i in warm else "cold")
-                tr0 = time.monotonic()
-                r.future.set_result(results[i])
-                self.tracer.record("resolve", tr0, time.monotonic(),
-                                   ticket=r.ticket, kind=kind)
+            self._finish(kind, r, results[i], t_disp, t_end,
+                         driver="compacted" if compact else "masked",
+                         init="warm" if i in warm else "cold")
 
     def _refill_rt(self, kind: str):
         """The kind's refill runtime, or ``None`` if it serves closed-batch
@@ -702,21 +709,10 @@ class AsyncSolverEngine:
 
         def on_result(idx: int, res) -> None:
             r = reqs[idx]
-            self._cache_result(kind, r, res)
-            now = time.monotonic()
-            self.metrics.record_done((now - r.submit_t) * 1e3)
-            if self.tracer is None:
-                r.future.set_result(res)
-            else:
-                self.tracer.record("solve", solve_t0.get(idx, t_session),
-                                   now, ticket=r.ticket, kind=kind,
-                                   bucket=list(bshape), driver="refill",
-                                   init="warm" if r.warm is not None
-                                   else "cold")
-                tr0 = time.monotonic()
-                r.future.set_result(res)
-                self.tracer.record("resolve", tr0, time.monotonic(),
-                                   ticket=r.ticket, kind=kind)
+            self._finish(kind, r, res, solve_t0.get(idx, t_session),
+                         time.monotonic(), bucket=list(bshape),
+                         driver="refill",
+                         init="warm" if r.warm is not None else "cold")
 
         def on_error(idx: int, e: Exception) -> None:
             r = reqs[idx]
@@ -759,20 +755,10 @@ class AsyncSolverEngine:
                 self.metrics.record_done(0.0, ok=False)
                 r.future.set_exception(e)
             else:
-                self._cache_result(kind, r, res)
                 self.metrics.record_warm(
                     kind, int(r.warm is not None), int(r.warm is None))
-                now = time.monotonic()
-                self.metrics.record_done((now - r.submit_t) * 1e3)
-                if self.tracer is None:
-                    r.future.set_result(res)
-                else:
-                    self.tracer.record("solve", t0, now, ticket=r.ticket,
-                                       kind=kind, driver="isolated")
-                    tr0 = time.monotonic()
-                    r.future.set_result(res)
-                    self.tracer.record("resolve", tr0, time.monotonic(),
-                                       ticket=r.ticket, kind=kind)
+                self._finish(kind, r, res, t0, time.monotonic(),
+                             driver="isolated")
 
     # ---- shutdown --------------------------------------------------------
 
@@ -803,6 +789,8 @@ class AsyncSolverEngine:
             lane.work.put(_SENTINEL)
         for lane in self._lanes:
             lane.thread.join()
+        if self._unwatch is not None:
+            self._unwatch()
 
     def __enter__(self) -> "AsyncSolverEngine":
         return self

@@ -33,6 +33,7 @@ import json
 import pathlib
 import sys
 import threading
+import time
 import warnings
 
 import jax
@@ -488,6 +489,278 @@ def test_instrumented_paths_deprecationwarning_free():
                 assert f.result(timeout=WAIT_S) is not None
         prometheus_text(eng.metrics)
         json.dumps(tr.to_chrome())
+
+
+# ------------------------------- host stages, the profiler, the device
+
+def _named(spans, name):
+    return [s for s in spans if s.name == name]
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("refill", [False, True])
+def test_cache_put_and_validate_spans_close_the_chain(refill):
+    """Every resolved ticket has one ``cache/put`` span between the end of
+    its ``solve`` and the start of its ``resolve``; a ``validate`` span
+    lies inside every ``submit`` on the caller's thread."""
+    tr = Tracer()
+    probs = _grid_problems(11, 5, 6, 6)
+    with AsyncSolverEngine(max_batch=3, max_delay_ms=30.0, refill=refill,
+                           tracer=tr) as eng:
+        futs = [eng.submit("maxflow", p) for p in probs]
+        for f in futs:
+            assert f.result(timeout=WAIT_S) is not None
+    spans = tr.spans()
+    chains = _ticket_chains(tr)
+    _check_lifecycle(chains, range(len(probs)))
+    validates = _named(spans, "validate")
+    assert len(validates) == len(probs)
+    for t in range(len(probs)):
+        (put,) = _named(chains[t], "cache/put")
+        (solve,) = _named(chains[t], "solve")
+        (resolve,) = _named(chains[t], "resolve")
+        assert solve.t1 <= put.t0 <= put.t1 <= resolve.t0
+        assert put.attrs == {"ticket": t, "kind": "maxflow"}
+        (sub,) = _named(chains[t], "submit")
+        assert any(v.tid == sub.tid and sub.t0 <= v.t0 <= v.t1 <= sub.t1
+                   and v.attrs == {"kind": "maxflow"} for v in validates)
+
+
+def test_blocking_engine_spans_its_host_stages():
+    """The blocking engine's stages put the ``repro.core.batch`` spans
+    inside its own: ``batch/stage`` in ``bucket/pad``, the three
+    ``solve/*`` spans in ``device-solve`` (dispatch, then the wait for
+    the device, then the crop), one ``cache/put`` per ticket."""
+    tr = Tracer()
+    eng = SolverEngine(tracer=tr)
+    tickets = [eng.submit("maxflow", p) for p in _grid_problems(12, 3, 6, 6)]
+    eng.flush()
+    spans = tr.spans()
+    by_id = {s.span_id: s for s in spans}
+    (pad,) = _named(spans, "bucket/pad")
+    (stage,) = _named(spans, "batch/stage")
+    assert stage.parent_id == pad.span_id
+    (solve,) = _named(spans, "device-solve")
+    for name in ("solve/dispatch", "solve/crop", "solve/wait"):
+        (s,) = _named(spans, name)
+        assert by_id[s.parent_id] is solve
+    d, c, w = (_named(spans, n)[0] for n in
+               ("solve/dispatch", "solve/crop", "solve/wait"))
+    assert d.t1 <= w.t0 and w.t1 <= c.t0
+    assert sorted(s.attrs["ticket"] for s in _named(spans, "cache/put")) \
+        == tickets
+    assert len(_named(spans, "validate")) == len(tickets)
+
+
+def _host_event_names(trace_dir) -> set:
+    from jax.profiler import ProfileData
+    (path,) = pathlib.Path(trace_dir).glob("plugins/profile/*/*.xplane.pb")
+    pd = ProfileData.from_file(str(path))
+    return {ev.name for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+@pytest.mark.parametrize("kind", ["maxflow", "assignment"])
+def test_batch_spans_reach_a_profiler_capture(kind, tmp_path, monkeypatch):
+    """With no tracer, one ``solve_batch`` under a ``jax.profiler`` capture
+    leaves the host-stage spans on the capture's host plane; with no
+    capture and no tracer, no tracer span is opened at all."""
+    from repro.core import solve_batch
+    rng = np.random.default_rng(13)
+    payloads = (_grid_problems(13, 2, 6, 6) if kind == "maxflow" else
+                [rng.integers(0, 9, (5, 5)) for _ in range(2)])
+    plain = solve_batch(kind, payloads)         # compiles outside the capture
+
+    def no_tracer_span(*a, **k):
+        raise AssertionError("a tracer span opened with no tracer installed")
+    monkeypatch.setattr(Tracer, "span", no_tracer_span)
+    monkeypatch.setattr(Tracer, "record", no_tracer_span)
+    assert current_tracer() is None
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        traced = solve_batch(kind, payloads)
+    names = _host_event_names(tmp_path)
+    assert {"batch/stage", "solve/dispatch", "solve/crop",
+            "solve/wait"} <= names
+    untraced = solve_batch(kind, payloads)      # no capture, no tracer
+    for a, b, c in zip(plain, traced, untraced):
+        _assert_trees_equal(a, b)
+        _assert_trees_equal(a, c)
+
+
+def test_span_without_tracer_or_capture_is_the_shared_noop():
+    from repro.obs import span
+    assert current_tracer() is None
+    assert span("a") is span("b", ticket=1)
+    assert use_tracer(None) is span("a")        # nothing to install
+    tr = Tracer()
+    with use_tracer(tr), span("c", ticket=2):
+        with span("d"):
+            pass
+        with use_tracer(None), span("e"):       # an engine with no tracer
+            assert current_tracer() is None
+    c, = _named(tr.spans(), "c")
+    d, = _named(tr.spans(), "d")
+    assert c.attrs == {"ticket": 2} and d.parent_id == c.span_id
+    assert not _named(tr.spans(), "e")
+    assert current_tracer() is None
+
+
+def test_refill_device_solve_reaches_a_profiler_capture(tmp_path):
+    """A refill session with no tracer still puts ``device-solve`` on a
+    running capture's host plane, like the engines' own stages."""
+    ws = [np.random.default_rng(15).integers(0, 50, (5, 5))
+          for _ in range(3)]
+    plain = RefillSolver("assignment", shape=(5,), capacity=3).run(ws)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        traced = RefillSolver("assignment", shape=(5,), capacity=3).run(ws)
+    assert "device-solve" in _host_event_names(tmp_path)
+    for i in plain:
+        _assert_trees_equal(plain[i], traced[i])
+
+
+@pytest.mark.serve
+@pytest.mark.parametrize("refill", [False, True])
+def test_latency_counts_the_cache_put_before_resolution(refill):
+    """A request's exported latency covers the cache puts it waits behind
+    before its future resolves (its own and its batch's earlier ones)."""
+    from repro.core.warm import SolutionCache
+    put_s = 0.3
+
+    class SlowCache(SolutionCache):
+        def put(self, *a, **k):
+            time.sleep(put_s)
+            return super().put(*a, **k)
+
+    probs = _grid_problems(16, 2, 6, 6)
+    for cache in (None, SlowCache()):           # the first run compiles
+        with AsyncSolverEngine(max_batch=2, max_delay_ms=LONG_DEADLINE_MS,
+                               refill=refill, cache=cache) as eng:
+            futs = [eng.submit("maxflow", p) for p in probs]
+            for f in futs:
+                assert f.result(timeout=WAIT_S) is not None
+            lat = eng.metrics.snapshot()["latency_ms"]
+    assert lat["p50"] >= put_s * 1e3 * 1.5      # one and two puts
+    assert lat["p99"] >= put_s * 1e3 * 1.98
+
+
+def _fresh_program():
+    """A jitted program no cache holds: its constant is new every call."""
+    c = float(time.monotonic_ns() % 1_000_003)
+    return jax.jit(lambda x: x * c + 1.0)(jnp.arange(7.0)).block_until_ready()
+
+
+@pytest.mark.serve
+def test_compile_spans_while_an_engine_holds_a_tracer():
+    """A fresh jit under an open traced engine leaves a ``compile`` span;
+    after ``close`` the listener is gone and compiles leave nothing."""
+    from repro.obs import trace as trace_mod
+    tr = Tracer()
+    with AsyncSolverEngine(max_batch=2, max_delay_ms=30.0, tracer=tr):
+        _fresh_program()
+    comp = _named(tr.spans(), "compile")
+    assert comp, "no compile span recorded"
+    assert all(0 <= s.t1 - s.t0 < 600 for s in comp)
+    assert all(s.attrs["event"] in trace_mod.COMPILE_EVENTS for s in comp)
+    n = len(tr.spans())
+    _fresh_program()
+    assert len(tr.spans()) == n
+
+
+def test_watch_compiles_records_until_unwatched():
+    from repro.obs import watch_compiles
+    tr = Tracer()
+    unwatch = watch_compiles(tr)
+    try:
+        _fresh_program()
+    finally:
+        unwatch()
+    (comp,) = _named(tr.spans(), "compile")
+    assert comp.tid == threading.get_ident() and comp.t0 <= comp.t1
+    _fresh_program()
+    assert len(_named(tr.spans(), "compile")) == 1
+
+
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+def test_grid_solve_hlo_names_its_stages(backend):
+    """The batched grid solve's HLO carries the solver's named scopes in
+    its ``op_name`` metadata."""
+    prob = _grid_batch(14, 2, 8, 128)
+    text = jax.jit(lambda p: maxflow_grid_batch(p, backend=backend)).lower(
+        prob).as_text(debug_info=True)
+    for scope in ("maxflow/init", "maxflow/push", "maxflow/relabel",
+                  "maxflow/finalize"):
+        assert scope in text, f"{scope} missing from the lowered HLO"
+
+
+def _pallas_names(jaxpr) -> list:
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            out.append(eqn.params["name"])
+        for v in eqn.params.values():
+            sub = getattr(v, "jaxpr", v)
+            if hasattr(sub, "eqns"):
+                out += _pallas_names(sub)
+    return out
+
+
+def _bench_match(kernel: str) -> str | None:
+    path = (pathlib.Path(__file__).resolve().parents[1] / "bench" / "kernels"
+            / f"{kernel}.py")
+    if not path.is_file():
+        return None
+    ns: dict = {}
+    exec(path.read_text(), ns)
+    return ns["MATCH"]
+
+
+def _kernel_jaxpr(which):
+    from repro.kernels.bfs_relabel.kernel import bfs_relabel_sweeps
+    from repro.kernels.bidding.kernel import bidding
+    from repro.kernels.frontier.kernel import frontier
+    from repro.kernels.grid_push.kernel import (grid_push_decide,
+                                                grid_push_decide_sched)
+    f32, i32 = jnp.float32, jnp.int32
+    S = jax.ShapeDtypeStruct
+    B, H, W = 1, 8, 128
+    plane, planes = S((B, H, W), f32), S((4, B, H, W), f32)
+    ih, ihs = S((B, H, W), i32), S((4, B, H, W), i32)
+    n = S((), i32)
+    if which == "grid_push_decide":
+        return jax.make_jaxpr(lambda *a: grid_push_decide(
+            *a, interpret=True))(plane, ih, planes, ihs, plane, plane, n)
+    if which == "grid_push_decide_sched":
+        return jax.make_jaxpr(lambda *a: grid_push_decide_sched(
+            *a, block_h=8, block_w=128, interpret=True))(
+            plane, ih, planes, ihs, plane, plane, S((B, 1), i32),
+            S((B,), i32), n)
+    if which == "bfs_relabel_sweeps":
+        return jax.make_jaxpr(lambda *a: bfs_relabel_sweeps(
+            *a, interpret=True))(planes, ih, ih, ih, ih)
+    if which == "frontier":
+        return jax.make_jaxpr(lambda *a: frontier(*a, interpret=True))(
+            S((8, 128), jnp.bool_), S((8,), i32), S((8,), i32))
+    return jax.make_jaxpr(lambda *a: bidding(*a, interpret=True))(
+        S((8, 128), i32), S((128,), i32), S((8, 128), jnp.bool_))
+
+
+@pytest.mark.parametrize("kernel,bench_kernel", [
+    ("grid_push_decide", "grid_push"),
+    ("grid_push_decide_sched", "grid_push"),
+    ("bfs_relabel_sweeps", None),
+    ("frontier", None),
+    ("bidding", "bidding"),
+])
+def test_pallas_calls_carry_their_names(kernel, bench_kernel):
+    """Every ``pallas_call`` is named explicitly, and the name holds the
+    substring the benchmark's trace reader matches it by."""
+    assert _pallas_names(_kernel_jaxpr(kernel).jaxpr) == [kernel]
+    if bench_kernel is not None:
+        assert _bench_match(bench_kernel) in kernel
 
 
 # ----------------------------------------------------- metrics hygiene
