@@ -326,6 +326,11 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
     contract survives (tests/test_balanced.py).
     """
     round_fn = _round_fn(backend)
+    # The fused Pallas round writes every plane of the loop carry while it
+    # still reads them, so a loop body of one round copies the whole carry
+    # before each call; two rounds an iteration alternate between the carry
+    # and temporaries, with no copy.
+    unroll = 2 if backend == "pallas" else 1
     if backend == "balanced":
         from repro.kernels.bfs_relabel.ops import bfs_relabel_heights
         from repro.kernels.grid_push.ops import jacobi_round_scheduled
@@ -377,7 +382,8 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
             with jax.named_scope("maxflow/push"):
                 return round_fn(s, n_nodes)
 
-        new = jax.lax.fori_loop(0, rounds_per_heuristic, inner, state)
+        new = jax.lax.fori_loop(0, rounds_per_heuristic, inner, state,
+                                unroll=unroll)
         new = new._replace(
             h=bfs_heights(new.cap, new.cap_sink, new.h, n_nodes, iters))
         return _count_heur(new, jnp.ones(state.e.shape[:-2], jnp.bool_))

@@ -7,6 +7,11 @@ ops wrappers ask ``interpret_mode`` whether to interpret instead.
 """
 import jax
 
+# Mosaic's default scoped-VMEM limit, and what a kernel may raise it to (a
+# TPU v5e core has 128 MiB of VMEM; the rest stays for the compiler).
+VMEM_DEFAULT = 16 * 2 ** 20
+VMEM_CAP = 100 * 2 ** 20
+
 
 def interpret_mode() -> bool:
     """Whether the ops wrappers run their kernels in Pallas interpret mode.

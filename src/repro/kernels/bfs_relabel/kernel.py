@@ -43,6 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import VMEM_CAP, VMEM_DEFAULT
+
 INF_H = 2 ** 30  # python int: jnp scalars would be captured consts in pallas
 
 # Relaxation sweeps per kernel invocation. Each extra sweep is pure VMEM
@@ -55,10 +57,6 @@ SWEEPS = 8
 # double-buffered across the batch grid, plus ~13 planes of sweep
 # temporaries (the compiler asked for 32.97 MiB at 512², i.e. 33 planes).
 VMEM_PLANES = 36
-# Mosaic's default scoped-VMEM limit, and what this kernel may raise it to
-# (a TPU v5e core has 128 MiB of VMEM; the rest stays for the compiler).
-VMEM_DEFAULT = 16 * 2 ** 20
-VMEM_CAP = 100 * 2 ** 20
 
 
 def vmem_limit_bytes(H: int, W: int) -> int:

@@ -1,24 +1,33 @@
-"""Pallas TPU kernel: the per-node decision of one push-relabel Jacobi round.
+"""Pallas TPU kernels: push-relabel Jacobi rounds on a grid.
 
 The paper's push kernel (§4.6) is the hot spot of the max-flow computation:
 each node scans its residual edges, finds the lowest neighbour, and either
 pushes or relabels. The CUDA version keeps heights in shared memory
-(Vineet & Narayanan) — the TPU analogue is VMEM tiles chosen by BlockSpec.
+(Vineet & Narayanan) — the TPU analogue is VMEM blocks chosen by BlockSpec.
 
-The kernel computes, per grid tile: the chosen target (sink / source / one of
-four neighbours), the pushed amount per target plane, and the new height. The
-cross-tile flow deposition (shift-adds) is pure elementwise data movement and
-stays in XLA (ops.py) where it fuses with the surrounding ops; the VMEM-
-resident argmin/push math — the part the paper hand-optimizes — lives here.
+``grid_push_round`` is one whole Jacobi round in one call, over row strips
+of full width: each grid step reads its strip of the eight state planes
+(``e``, ``h``, four ``cap``, ``cap_src``, ``cap_sink``) and the ``HALO``
+rows above and below it, gathers the neighbour heights, decides, deposits
+the pushed flow and writes its strip of the next state. A round reads and
+writes each state plane once (8 + 8 plane passes, plus 2·``HALO``/bh of a
+plane of halo rows); no neighbour-height or per-target delta plane reaches
+HBM. VMEM per step: the 16 block planes and the halos double-buffered, plus
+the body's temporaries over ``bh + 2·HALO`` rows: the compiler asked for
+~37–42 such planes at 8×512² (6.0 MiB at bh 64, 10.4 MiB at 128, 21.5 MiB
+at 256, 43 MiB at 512), so ``round_vmem_limit_bytes`` sets the limit from the shape
+(``ROUND_VMEM_PLANES``), over Mosaic's 16 MiB default.
 
-VMEM per step: 12 input planes + 7 output planes of BH·BW·4B, double-
-buffered. The dense kernel's 256×256 blocks ⇒ 2·19·256 KiB ≈ 9.5 MiB, and
+``grid_push_decide`` (the decision alone over ``(bh, bw)`` tiles, with the
+four neighbour-height planes built by XLA and the deposit left to XLA) is
+the round for grids whose height is not a multiple of ``HALO``, and
+``grid_push_decide_sched`` is the balanced backend's tile-scheduled
+decision. Their VMEM per step: 12 input planes + 7 output planes of
+BH·BW·4B, double-buffered: the 256×256 blocks ⇒ 2·19·256 KiB ≈ 9.5 MiB,
 the scheduled kernel's 64×128 blocks ⇒ 2·19·32 KiB ≈ 1.2 MiB, both under
-Mosaic's 16 MiB default scoped limit (compiled for v5e in
-tests/test_tpu_compile.py at 8×512²). Block widths are multiples of 128
-lanes or the full width (``tile_dims``).
-The halo exchange (neighbour heights) is precomputed by ops.py as 4 shifted
-height planes, which on real hardware XLA lays out as cheap HBM slices.
+the default limit. Block widths are multiples of 128 lanes or the full
+width (``tile_dims``). All three compile for v5e in
+tests/test_tpu_compile.py at 8×512².
 """
 from __future__ import annotations
 
@@ -29,11 +38,14 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.core.maxflow.grid import _OPP
+from repro.kernels import VMEM_CAP, VMEM_DEFAULT
+
 INF_H = 2 ** 30  # python int: jnp scalars would be captured consts in pallas
 
 
 def _decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes):
-    """The per-node decision math shared by both kernels (concrete values).
+    """The per-node decision math shared by the kernels (concrete values).
 
     ``cap`` / ``nbr_h`` index by direction (refs or arrays, 4 planes).
     Candidate order matches grid.jacobi_round:
@@ -167,6 +179,176 @@ def grid_push_decide(e, h, cap, nbr_h, cap_src, cap_sink, n_nodes,
                    jax.ShapeDtypeStruct((6,) + e.shape, jnp.float32)],
         interpret=interpret,
     )(jnp.asarray([n_nodes], jnp.int32), e, h, cap, nbr_h, cap_src, cap_sink)
+
+
+# Strip height the solver's round asks for (tuned on a v5e at 1–8 × 512²:
+# within 5% of the fastest of 64–512 rows at each batch, and the quickest
+# to compile, since Mosaic unrolls the body over the strip's vregs).
+STRIP_ROWS = 64
+# Rows of the halo blocks above and below a strip: one (8, 128) tile high.
+# A strip's boundary rows decide with their neighbours' decisions, which
+# read heights two rows out, so 8 rows hold more than the round needs.
+HALO = 8
+# VMEM the fused round needs, in 4-byte planes of a strip and its halos
+# ((bh + 2·HALO) × W): 16 block planes (8 in, 8 out) double-buffered, plus
+# the body's temporaries. The compiler asked for 37–42 at 8×512².
+ROUND_VMEM_PLANES = 48
+# Elementwise operations the round's body spends on one node (the
+# decision ~56, neighbour heights ~6, the deposit ~20): the cost estimate's
+# flops, for XLA's scheduler.
+ROUND_OPS_PER_NODE = 82
+
+
+def round_vmem_limit_bytes(bh: int, W: int) -> int:
+    """Scoped-VMEM limit for one ``(bh, W)`` strip per grid step."""
+    plane = (bh + 2 * HALO) * (-(-W // 128) * 128) * 4
+    return max(VMEM_DEFAULT, ROUND_VMEM_PLANES * plane)
+
+
+def strip_rows(H: int, W: int, block_h: int) -> int | None:
+    """The strip height the fused round takes for an ``(H, W)`` grid.
+
+    The largest multiple of ``HALO`` rows that divides ``H``, is at most
+    ``block_h`` (or ``HALO``) and fits ``VMEM_CAP``; None when there is
+    none (``H`` not a multiple of ``HALO``, or a row too wide), and the
+    round then takes the tiled decide-then-deposit path.
+    """
+    if H % HALO:
+        return None
+    for bh in range(max(block_h, HALO) // HALO * HALO, 0, -HALO):
+        if H % bh == 0 and round_vmem_limit_bytes(bh, W) <= VMEM_CAP:
+            return bh
+    return None
+
+
+def _grid_push_round_kernel(nn_ref, e_ref, h_ref, cap_ref, csrc_ref,
+                            csink_ref, *refs):
+    """One whole Jacobi round of one row strip, halo rows included.
+
+    The strip's planes are stacked between the ``HALO`` rows above and
+    below it into ``R = bh + 2·HALO`` rows; halo rows outside the grid
+    become "no node" (height ``INF_H``, no capacity, no excess), which
+    reproduces ``_nbr_h``'s INF border and ``_move``'s zero fill. Rows
+    ``[HALO-1, HALO+bh+1)`` decide exactly (their neighbours' heights lie
+    inside the ``R`` rows), the deposit into the strip's rows reads only
+    those, and the rolls' wrap-around touches only the outermost rows,
+    which are never written out. The column wrap-around is masked for
+    the neighbour heights; a deposit's wrapped column is always zero,
+    since nothing pushes off the grid.
+    """
+    top, bot = refs[0:5], refs[5:10]
+    e_out, h_out, cap_out, csrc_out, csink_out, flow_out = refs[10:]
+    i = pl.program_id(1)
+    has_top, has_bot = i > 0, i < pl.num_programs(1) - 1
+
+    def rows(strip, above, below, fill):
+        return jnp.concatenate([jnp.where(has_top, above, fill), strip,
+                                jnp.where(has_bot, below, fill)], axis=0)
+
+    e = rows(e_ref[...], top[0][...], bot[0][...], 0.0)
+    h = rows(h_ref[...], top[1][...], bot[1][...], INF_H)
+    cap = [rows(cap_ref[d], top[2][d], bot[2][d], 0.0) for d in range(4)]
+    cap_src = rows(csrc_ref[...], top[3][...], bot[3][...], 0.0)
+    cap_sink = rows(csink_ref[...], top[4][...], bot[4][...], 0.0)
+    R, W = h.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, (R, W), 1)
+    # neighbour heights (grid._nbr_h): UP = h[r-1], DOWN = h[r+1], ...
+    nbr_h = [pltpu.roll(h, 1, 0), pltpu.roll(h, R - 1, 0),
+             jnp.where(col == 0, INF_H, pltpu.roll(h, 1, 1)),
+             jnp.where(col == W - 1, INF_H, pltpu.roll(h, W - 1, 1))]
+    h_new, deltas = _decide(e, h, cap, nbr_h, cap_src, cap_sink, nn_ref[0])
+    d_sink, d_src, d_nbr = deltas[0], deltas[1], deltas[2:]
+
+    def move(a, d):  # grid._move: deposit a[x] at x's neighbour in dir d
+        return pltpu.roll(a, (R - 1, 1, W - 1, 1)[d], 0 if d < 2 else 1)
+
+    # the deposit, in grid.jacobi_round's order of operations
+    out = d_sink + d_src + sum(d_nbr)
+    inflow = sum(move(d_nbr[d], d) for d in range(4))
+    lo, hi = HALO, R - HALO
+    e_out[...] = (e - out + inflow)[lo:hi]
+    h_out[...] = h_new[lo:hi]
+    for d in range(4):
+        cap_out[d] = (cap[d] - d_nbr[d]
+                      + move(d_nbr[_OPP[d]], _OPP[d]))[lo:hi]
+    csrc_out[...] = (cap_src - d_src)[lo:hi]
+    csink_out[...] = (cap_sink - d_sink)[lo:hi]
+    flow_out[0:1, :] = jnp.sum(d_sink[lo:hi], axis=0, keepdims=True)
+    flow_out[1:2, :] = jnp.sum(d_src[lo:hi], axis=0, keepdims=True)
+
+
+@functools.partial(jax.jit, static_argnames=("bh", "interpret"))
+def grid_push_round(e, h, cap, cap_src, cap_sink, n_nodes, *, bh: int,
+                    interpret: bool = False):
+    """One whole push-relabel Jacobi round over row strips (Pallas).
+
+    Args:
+      e / h / cap_src / cap_sink: ``(B, H, W)`` state planes (``h`` int32).
+      cap: ``(4, B, H, W)`` residual neighbour capacities.
+      n_nodes: scalar int32 (the paper's N), on scalar prefetch.
+      bh: strip height, a multiple of ``HALO`` that divides ``H``
+        (``strip_rows``).
+
+    Returns ``(e, h, cap, cap_src, cap_sink, flows)``: the next state,
+    bit-identical to ``grid.jacobi_round``'s, and ``flows`` ``(B, H//bh,
+    2, W)``, the column sums of each strip's flow into the sink (``[..., 0,
+    :]``) and back to the source (``[..., 1, :]``). The grid is ``(B,
+    H//bh)``; each step reads its strip and the ``HALO`` rows above and
+    below it (extra BlockSpecs on the same arrays, clamped at the grid's
+    edges) and writes its strip of the next state, so the round reads and
+    writes each state plane once. No output aliases an input: a strip's
+    halo rows are its neighbour's interior.
+    """
+    B, H, W = e.shape
+    if H % bh or bh % HALO:
+        raise ValueError(f"grid_push_round: strips of {bh} rows do not tile "
+                         f"{H} rows in blocks of {HALO} (see strip_rows)")
+    n_strips, hb = H // bh, bh // HALO
+    last = H // HALO - 1
+
+    def strip(b, i, nn):
+        return (b, i, 0)
+
+    def top(b, i, nn):
+        return (b, jnp.maximum(i * hb - 1, 0), 0)
+
+    def bot(b, i, nn):
+        return (b, jnp.minimum((i + 1) * hb, last), 0)
+
+    def spec(rows, index, planes=False):
+        if planes:
+            return pl.BlockSpec((4, None, rows, W),
+                                lambda b, i, nn: (0,) + index(b, i, nn))
+        return pl.BlockSpec((None, rows, W), index)
+
+    state_specs = [spec(bh, strip), spec(bh, strip), spec(bh, strip, True),
+                   spec(bh, strip), spec(bh, strip)]
+    halo_specs = [spec(HALO, index, k == 2) for index in (top, bot)
+                  for k in range(5)]
+    planes = (e, h, cap, cap_src, cap_sink)
+    steps, row = B * n_strips, W * 4
+    cost = pl.CostEstimate(   # XLA sees the blocks' traffic, not 0 bytes
+        flops=ROUND_OPS_PER_NODE * steps * (bh + 2 * HALO) * W,
+        transcendentals=0,
+        bytes_accessed=steps * row * (8 * (bh + 2 * HALO) + 8 * bh + 2))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,   # n_nodes
+        grid=(B, n_strips),
+        in_specs=state_specs + halo_specs,
+        out_specs=state_specs + [
+            pl.BlockSpec((None, None, 2, W), lambda b, i, nn: (b, i, 0, 0))],
+    )
+    return pl.pallas_call(
+        _grid_push_round_kernel,
+        grid_spec=grid_spec,
+        name="grid_push_round",
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype) for a in planes]
+        + [jax.ShapeDtypeStruct((B, n_strips, 2, W), jnp.float32)],
+        interpret=interpret,
+        cost_estimate=cost,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=round_vmem_limit_bytes(bh, W)),
+    )(jnp.asarray([n_nodes], jnp.int32), *planes, *planes, *planes)
 
 
 @functools.partial(jax.jit, static_argnames=("block_h", "block_w",

@@ -1,11 +1,13 @@
-"""Jit'd wrappers: full Jacobi rounds with the Pallas decision kernels.
+"""Jit'd wrappers: full Jacobi rounds with the Pallas grid_push kernels.
 
 ``jacobi_round_pallas`` produces bit-identical state transitions to
-``repro.core.maxflow.grid.jacobi_round`` (asserted in tests); the wrapper
-adds the halo gather before the kernel and the shift-add flow deposition
-after it. Like the XLA round it is shape-polymorphic over a leading batch
-axis (``e``: ``(..., H, W)``, ``cap``: ``(4, ..., H, W)``) — the kernel
-grid then gains a batch dimension.
+``repro.core.maxflow.grid.jacobi_round`` (asserted in tests): one fused
+``grid_push_round`` call over row strips where the grid's height allows
+(``kernel.strip_rows``), else the decision kernel with the halo gather
+before it and the shift-add flow deposition after it. Like the XLA round
+it is shape-polymorphic over leading batch axes (``e``: ``(..., H, W)``,
+``cap``: ``(4, ..., H, W)``) — the kernel grid then gains a batch
+dimension.
 
 ``jacobi_round_scheduled`` is the workload-balanced variant: it builds a
 per-instance ACTIVE-TILE SCHEDULE (tiles holding at least one node with
@@ -22,18 +24,22 @@ progress.
 """
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 from repro.core.maxflow.grid import (GridFlowState, _OPP, _gsum, _move,
                                      _nbr_h)
 from repro.kernels import interpret_mode
 from repro.kernels.grid_push.kernel import (grid_push_decide,
-                                            grid_push_decide_sched, tile_dims)
+                                            grid_push_decide_sched,
+                                            grid_push_round, STRIP_ROWS,
+                                            strip_rows, tile_dims)
 from repro.kernels.grid_push.ref import grid_push_decide_ref
 
 
 def _deposit(state: GridFlowState, h_new, delta) -> GridFlowState:
-    """Shift-add flow deposition shared by both round wrappers."""
+    """Shift-add flow deposition after a decision kernel."""
     d_sink, d_src = delta[0], delta[1]
     d_nbr = [delta[2 + d] for d in range(4)]
     out = d_sink + d_src + sum(d_nbr)
@@ -50,10 +56,39 @@ def _deposit(state: GridFlowState, h_new, delta) -> GridFlowState:
 
 
 def jacobi_round_pallas(state: GridFlowState, n_nodes,
-                        *, block_h: int = 256, block_w: int = 256,
+                        *, block_h: int = STRIP_ROWS, block_w: int = 256,
                         interpret: bool | None = None) -> GridFlowState:
+    """One Jacobi round on the Pallas kernels, bit-identical to
+    ``jacobi_round``.
+
+    Where the grid can be cut into row strips (``kernel.strip_rows``:
+    ``H`` a multiple of 8 rows), the whole round is one ``grid_push_round``
+    call over strips of at most ``block_h`` rows. Otherwise the decision
+    kernel runs over ``(block_h, block_w)`` tiles and XLA gathers the
+    neighbour heights and deposits the flow. The fused round adds the
+    terminal flows up strip by strip, in another order than
+    ``jacobi_round``'s sum: the same bits wherever the sums are exact, as
+    with integer capacities.
+    """
     if interpret is None:
         interpret = interpret_mode()
+    *batch, H, W = state.e.shape
+    bh = strip_rows(H, W, block_h)
+    if bh is not None:
+        B = math.prod(batch)
+        e, h, cap, cap_src, cap_sink, flows = grid_push_round(
+            state.e.reshape(B, H, W), state.h.reshape(B, H, W),
+            state.cap.reshape(4, B, H, W), state.cap_src.reshape(B, H, W),
+            state.cap_sink.reshape(B, H, W), n_nodes, bh=bh,
+            interpret=interpret)
+        flows = jnp.sum(flows, axis=(1, 3)).reshape(tuple(batch) + (2,))
+        return state._replace(
+            e=e.reshape(state.e.shape), h=h.reshape(state.h.shape),
+            cap=cap.reshape(state.cap.shape),
+            cap_src=cap_src.reshape(state.cap_src.shape),
+            cap_sink=cap_sink.reshape(state.cap_sink.shape),
+            sink_flow=state.sink_flow + flows[..., 0],
+            src_flow=state.src_flow + flows[..., 1])
     nbr_h = jnp.stack([_nbr_h(state.h, d) for d in range(4)], axis=0)
     h_new, delta = grid_push_decide(
         state.e, state.h, state.cap, nbr_h, state.cap_src, state.cap_sink,

@@ -723,7 +723,8 @@ def _kernel_jaxpr(which):
     from repro.kernels.bidding.kernel import bidding
     from repro.kernels.frontier.kernel import frontier
     from repro.kernels.grid_push.kernel import (grid_push_decide,
-                                                grid_push_decide_sched)
+                                                grid_push_decide_sched,
+                                                grid_push_round)
     f32, i32 = jnp.float32, jnp.int32
     S = jax.ShapeDtypeStruct
     B, H, W = 1, 8, 128
@@ -738,6 +739,9 @@ def _kernel_jaxpr(which):
             *a, block_h=8, block_w=128, interpret=True))(
             plane, ih, planes, ihs, plane, plane, S((B, 1), i32),
             S((B,), i32), n)
+    if which == "grid_push_round":
+        return jax.make_jaxpr(lambda *a: grid_push_round(
+            *a, bh=8, interpret=True))(plane, ih, planes, plane, plane, n)
     if which == "bfs_relabel_sweeps":
         return jax.make_jaxpr(lambda *a: bfs_relabel_sweeps(
             *a, interpret=True))(planes, ih, ih, ih, ih)
@@ -751,6 +755,7 @@ def _kernel_jaxpr(which):
 @pytest.mark.parametrize("kernel,bench_kernel", [
     ("grid_push_decide", "grid_push"),
     ("grid_push_decide_sched", "grid_push"),
+    ("grid_push_round", "grid_push"),
     ("bfs_relabel_sweeps", None),
     ("frontier", None),
     ("bidding", "bidding"),

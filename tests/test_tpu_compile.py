@@ -5,8 +5,8 @@ the TPU compiler is installed with jaxlib's TPU support) with
 ``interpret=False``, and asserts the program holds a Mosaic kernel
 (``tpu_custom_call``). This is what interpret-mode tests cannot see: the
 chip's compiler refuses unaligned tiles, unsupported ops and kernels that
-overrun VMEM. Sizes: the paper's 8×512² grid batch, 4×1024² dense
-assignment/matching.
+overrun VMEM. Sizes: the paper's 8×512² grid batch (and one 512² grid,
+a served batch), 4×1024² dense assignment/matching.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test runner's workers all
@@ -22,7 +22,8 @@ from repro.kernels.bidding.kernel import bidding
 from repro.kernels.frontier.kernel import frontier
 from repro.kernels.grid_push.kernel import (grid_push_decide,
                                             grid_push_decide_sched,
-                                            tile_dims)
+                                            grid_push_round, STRIP_ROWS,
+                                            strip_rows, tile_dims)
 
 B, H, W = 8, 512, 512          # chip_smoke.py's maxflow batch
 NB, N = 4, 1024                # chip_smoke.py's assignment / matching batch
@@ -70,6 +71,18 @@ def test_grid_push_decide_compiles(sds):
     text = _compiled_text(
         lambda *a: grid_push_decide(*a, interpret=False),
         *_grid_args(sds), sds((), i32))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [B, 1])   # the batch cell, one served grid
+def test_grid_push_round_compiles(sds, batch):
+    bh = strip_rows(H, W, STRIP_ROWS)
+    assert bh is not None and H % bh == 0
+    plane = sds((batch, H, W), f32)
+    text = _compiled_text(
+        lambda *a: grid_push_round(*a, bh=bh, interpret=False),
+        plane, sds((batch, H, W), i32), sds((4, batch, H, W), f32), plane,
+        plane, sds((), i32))
     assert "tpu_custom_call" in text
 
 
