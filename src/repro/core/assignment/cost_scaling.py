@@ -230,9 +230,12 @@ def price_update(c, eps, st: _RefineState, max_sweeps: int) -> _RefineState:
     deficit_y = jnp.sum(F, axis=-2) == 0
     l_y0 = jnp.where(deficit_y, 0, INF_D)
 
-    cp_xy = _masked(c + p_x[..., :, None] - p_y[..., None, :], st.fixed)
+    # Fixed arcs stay in the distance graph: the refine never pushes on
+    # them, but the final prices must be ε-optimal over every arc, and a
+    # fixed arc left out lets the drop ε·l(v) carry prices across it.
+    cp_xy = c + p_x[..., :, None] - p_y[..., None, :]
     len_xy = jnp.minimum(jnp.maximum(0, cp_xy // e2 + 1), INF_D)  # arc X->Y
-    len_xy = jnp.where((F == 0) & (cp_xy < INF), len_xy, INF_D)
+    len_xy = jnp.where(F == 0, len_xy, INF_D)
     cp_yx = -c + p_y[..., None, :] - p_x[..., :, None]
     len_yx = jnp.where(F == 1, jnp.minimum(
         jnp.maximum(0, cp_yx // e2 + 1), INF_D), INF_D)
@@ -298,21 +301,22 @@ def _scale_init(w, *, alpha: int) -> _ScaleState:
     w_i = jnp.asarray(w, jnp.int32)
     n = w_i.shape[-1]
     batch = w_i.shape[:-2]
-    c = -(n + 1) * w_i                                   # minimization form
-    C = jnp.maximum(jnp.max(jnp.abs(c), axis=(-2, -1)), 1)   # (...,) per inst
-    eps0 = jnp.maximum(1, -(-C // alpha))                # eps <- ceil(C/alpha)
-    st = _RefineState(
-        F=jnp.zeros(batch + (n, n), jnp.int32),
-        p_x=jnp.zeros(batch + (n,), jnp.int32),
-        p_y=jnp.zeros(batch + (n,), jnp.int32),
-        fixed=jnp.zeros(batch + (n, n), jnp.bool_),
-        rounds=jnp.zeros(batch, jnp.int32),
-        pushes=jnp.zeros(batch, jnp.int32),
-        relabels=jnp.zeros(batch, jnp.int32),
-    )
-    return _ScaleState(c=c, eps=eps0, k=jnp.zeros(batch, jnp.int32),
-                       alive=jnp.ones(batch, jnp.bool_),
-                       st=_refine_init(c, eps0, st))
+    with jax.named_scope("assignment/init"):
+        c = -(n + 1) * w_i                               # minimization form
+        C = jnp.maximum(jnp.max(jnp.abs(c), axis=(-2, -1)), 1)  # per inst
+        eps0 = jnp.maximum(1, -(-C // alpha))            # eps <- ceil(C/alpha)
+        st = _RefineState(
+            F=jnp.zeros(batch + (n, n), jnp.int32),
+            p_x=jnp.zeros(batch + (n,), jnp.int32),
+            p_y=jnp.zeros(batch + (n,), jnp.int32),
+            fixed=jnp.zeros(batch + (n, n), jnp.bool_),
+            rounds=jnp.zeros(batch, jnp.int32),
+            pushes=jnp.zeros(batch, jnp.int32),
+            relabels=jnp.zeros(batch, jnp.int32),
+        )
+        return _ScaleState(c=c, eps=eps0, k=jnp.zeros(batch, jnp.int32),
+                           alive=jnp.ones(batch, jnp.bool_),
+                           st=_refine_init(c, eps0, st))
 
 
 def _scale_warm(w, p_y, dmax, *, alpha: int) -> _ScaleState:
@@ -333,22 +337,23 @@ def _scale_warm(w, p_y, dmax, *, alpha: int) -> _ScaleState:
     w_i = jnp.asarray(w, jnp.int32)
     n = w_i.shape[-1]
     batch = w_i.shape[:-2]
-    c = -(n + 1) * w_i
-    C = jnp.maximum(jnp.max(jnp.abs(c), axis=(-2, -1)), 1)
-    eps_cold = jnp.maximum(1, -(-C // alpha))
-    eps0 = jnp.clip(1 + jnp.asarray(dmax, jnp.int32), 1, eps_cold)
-    st = _RefineState(
-        F=jnp.zeros(batch + (n, n), jnp.int32),
-        p_x=jnp.zeros(batch + (n,), jnp.int32),
-        p_y=jnp.asarray(p_y, jnp.int32),
-        fixed=jnp.zeros(batch + (n, n), jnp.bool_),
-        rounds=jnp.zeros(batch, jnp.int32),
-        pushes=jnp.zeros(batch, jnp.int32),
-        relabels=jnp.zeros(batch, jnp.int32),
-    )
-    return _ScaleState(c=c, eps=eps0, k=jnp.zeros(batch, jnp.int32),
-                       alive=jnp.ones(batch, jnp.bool_),
-                       st=_refine_init(c, eps0, st))
+    with jax.named_scope("assignment/init"):
+        c = -(n + 1) * w_i
+        C = jnp.maximum(jnp.max(jnp.abs(c), axis=(-2, -1)), 1)
+        eps_cold = jnp.maximum(1, -(-C // alpha))
+        eps0 = jnp.clip(1 + jnp.asarray(dmax, jnp.int32), 1, eps_cold)
+        st = _RefineState(
+            F=jnp.zeros(batch + (n, n), jnp.int32),
+            p_x=jnp.zeros(batch + (n,), jnp.int32),
+            p_y=jnp.asarray(p_y, jnp.int32),
+            fixed=jnp.zeros(batch + (n, n), jnp.bool_),
+            rounds=jnp.zeros(batch, jnp.int32),
+            pushes=jnp.zeros(batch, jnp.int32),
+            relabels=jnp.zeros(batch, jnp.int32),
+        )
+        return _ScaleState(c=c, eps=eps0, k=jnp.zeros(batch, jnp.int32),
+                           alive=jnp.ones(batch, jnp.bool_),
+                           st=_refine_init(c, eps0, st))
 
 
 _scale_warm_jit = jax.jit(_scale_warm, static_argnames=("alpha",))
@@ -377,42 +382,47 @@ def _assignment_spec(method: str, alpha: int, max_rounds: int,
         n = c.shape[-1]
 
         def inner(_, t):
-            return round_fn(c, eps, t)
+            with jax.named_scope("assignment/refine"):
+                return round_fn(c, eps, t)
 
         new = jax.lax.fori_loop(0, rounds_per_heuristic, inner, st)
         if use_price_update:
-            perf = _is_perfect(new.F)
-            if perf.ndim == 0:  # single instance: genuinely skip the sweep
-                new = jax.lax.cond(
-                    perf, lambda t: t,
-                    lambda t: price_update(c, eps, t, max_sweeps=2 * n), new)
-            else:
-                new = _freeze(~perf,
-                              price_update(c, eps, new, max_sweeps=2 * n),
-                              new)
-        k = k + rounds_per_heuristic
-        done = _is_perfect(new.F) | (k >= max_rounds)
-        if use_arc_fixing:
-            # Arc fixing at refine exit (paper §5.2, Goldberg [8]): now that
-            # f is a genuine ε-optimal FLOW w.r.t. p, any unmatched arc with
-            # c_p > 2nε carries zero flow in every ε'-optimal flow with
-            # ε' <= ε — freeze it for all subsequent refines. (Matched arcs
-            # always satisfy |c_p| <= ε, so only F == 0 arcs can be fixed;
-            # the mask replaces the paper's adjacency-list deletion with
-            # flow = -10 sentinels.)
-            cp = c + new.p_x[..., :, None] - new.p_y[..., None, :]
-            fix = new.fixed | ((cp > 2 * n * _exp(eps, 2)) & (new.F == 0))
-            new = new._replace(
-                fixed=jnp.where(done[..., None, None], fix, new.fixed))
-        # ε schedule step for finished refines: divide down, or die after
-        # the ε = 1 pass (Goldberg–Kennedy: 1-optimal on scaled costs =
-        # exact optimum).
-        still = alive & ~(done & (eps <= 1))
-        eps_next = jnp.where(done & (eps > 1),
-                             jnp.maximum(1, -(-eps // alpha)), eps)
-        new = _freeze(done & still, _refine_init(c, eps_next, new), new)
-        return _ScaleState(c=c, eps=eps_next, k=jnp.where(done, 0, k),
-                           alive=still, st=new)
+            with jax.named_scope("assignment/price_update"):
+                perf = _is_perfect(new.F)
+                if perf.ndim == 0:  # single instance: genuinely skip it
+                    new = jax.lax.cond(
+                        perf, lambda t: t,
+                        lambda t: price_update(c, eps, t, max_sweeps=2 * n),
+                        new)
+                else:
+                    new = _freeze(
+                        ~perf, price_update(c, eps, new, max_sweeps=2 * n),
+                        new)
+        with jax.named_scope("assignment/rescale"):
+            k = k + rounds_per_heuristic
+            done = _is_perfect(new.F) | (k >= max_rounds)
+            if use_arc_fixing:
+                # Arc fixing at refine exit (paper §5.2, Goldberg [8]): now
+                # that f is a genuine ε-optimal FLOW w.r.t. p, any unmatched
+                # arc with c_p > 2nε carries zero flow in every ε'-optimal
+                # flow with ε' <= ε — freeze it for all subsequent refines.
+                # (Matched arcs always satisfy |c_p| <= ε, so only F == 0
+                # arcs can be fixed; the mask replaces the paper's
+                # adjacency-list deletion with flow = -10 sentinels.)
+                cp = c + new.p_x[..., :, None] - new.p_y[..., None, :]
+                fix = new.fixed | ((cp > 2 * n * _exp(eps, 2))
+                                   & (new.F == 0))
+                new = new._replace(
+                    fixed=jnp.where(done[..., None, None], fix, new.fixed))
+            # ε schedule step for finished refines: divide down, or die
+            # after the ε = 1 pass (Goldberg–Kennedy: 1-optimal on scaled
+            # costs = exact optimum).
+            still = alive & ~(done & (eps <= 1))
+            eps_next = jnp.where(done & (eps > 1),
+                                 jnp.maximum(1, -(-eps // alpha)), eps)
+            new = _freeze(done & still, _refine_init(c, eps_next, new), new)
+            return _ScaleState(c=c, eps=eps_next, k=jnp.where(done, 0, k),
+                               alive=still, st=new)
 
     def live(s: _ScaleState, rounds: jax.Array) -> jax.Array:
         return s.alive
@@ -430,16 +440,17 @@ def _assignment_finalize(w, st: _RefineState) -> AssignmentResult:
     """
     w_i = jnp.asarray(w, jnp.int32)
     n = w_i.shape[-1]
-    matched = jnp.sum(st.F, axis=-1) > 0
-    col = jnp.where(matched, jnp.argmax(st.F, axis=-1), n)
-    weight = jnp.sum(jnp.where(matched, jnp.take_along_axis(
-        w_i, jnp.minimum(col, n - 1)[..., :, None], axis=-1)[..., 0], 0),
-        axis=-1)
-    return AssignmentResult(
-        col_of_row=col, weight=weight, p_x=st.p_x, p_y=st.p_y,
-        rounds=st.rounds, pushes=st.pushes, relabels=st.relabels,
-        converged=_is_perfect(st.F),
-    )
+    with jax.named_scope("assignment/finalize"):
+        matched = jnp.sum(st.F, axis=-1) > 0
+        col = jnp.where(matched, jnp.argmax(st.F, axis=-1), n)
+        weight = jnp.sum(jnp.where(matched, jnp.take_along_axis(
+            w_i, jnp.minimum(col, n - 1)[..., :, None], axis=-1)[..., 0], 0),
+            axis=-1)
+        return AssignmentResult(
+            col_of_row=col, weight=weight, p_x=st.p_x, p_y=st.p_y,
+            rounds=st.rounds, pushes=st.pushes, relabels=st.relabels,
+            converged=_is_perfect(st.F),
+        )
 
 
 @functools.partial(jax.jit, static_argnames=(

@@ -696,6 +696,21 @@ def test_grid_solve_hlo_names_its_stages(backend):
         assert scope in text, f"{scope} missing from the lowered HLO"
 
 
+@pytest.mark.parametrize("backend", ["xla", "pallas"])
+@pytest.mark.parametrize("method", ["auction", "pushrelabel"])
+def test_assignment_solve_hlo_names_its_stages(method, backend):
+    """The batched assignment solve's HLO carries the solver's named scopes
+    in its ``op_name`` metadata."""
+    w = jnp.asarray(np.random.default_rng(15).integers(0, 101, (2, 8, 8)),
+                    jnp.int32)
+    text = jax.jit(lambda w: solve_assignment(
+        w, method=method, backend=backend)).lower(w).as_text(debug_info=True)
+    for scope in ("assignment/init", "assignment/refine",
+                  "assignment/price_update", "assignment/rescale",
+                  "assignment/finalize"):
+        assert scope in text, f"{scope} missing from the lowered HLO"
+
+
 def _pallas_names(jaxpr) -> list:
     out = []
     for eqn in jaxpr.eqns:
