@@ -195,9 +195,10 @@ def _round_auction(c, eps, st: _RefineState, *,
     # expressed in Goldberg price coordinates: p_y strictly decreases by >=ε).
     p_y = jnp.where(got_bid, p_y + best_bid, p_y)
     # the winner's own price moves as the later relabel would (ε-CS witness).
-    winner_at = jnp.take_along_axis(winner, arg1, axis=-1)
-    won = active_x & (winner_at == jnp.arange(n)) \
-        & jnp.take_along_axis(got_bid, arg1, axis=-1)
+    # x won iff its row of new_match holds a 1: a bid is only ever placed by
+    # an active x at its own arg1, so that row is 0 except, when x's bid won,
+    # at arg1[x]. A row reduction, not a gather at arg1 (serial on TPU).
+    won = jnp.any(new_match != 0, axis=-1)
     p_x = jnp.where(won, -(min2 + _exp(eps, 1)), p_x)
 
     n_push = jnp.sum(got_bid, axis=-1)
