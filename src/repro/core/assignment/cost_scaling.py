@@ -46,9 +46,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.solver_loop import (LoopSpec, masked_events_active,
-                                    run_compacted, run_masked)
+from repro.core.solver_loop import KindOps, LoopSpec, run_masked, solve
 
 INF = jnp.int32(2 ** 30)
 
@@ -454,89 +454,91 @@ def _assignment_finalize(w, st: _RefineState) -> AssignmentResult:
         )
 
 
-@functools.partial(jax.jit, static_argnames=(
-    "method", "alpha", "max_rounds", "rounds_per_heuristic",
-    "use_price_update", "use_arc_fixing", "backend"))
-def _solve_assignment_impl(
-    w: jax.Array,
-    *,
-    method: str,
-    alpha: int,
-    max_rounds: int,
-    rounds_per_heuristic: int,
-    use_price_update: bool,
-    use_arc_fixing: bool,
-    backend: str,
-) -> AssignmentResult:
-    """Jitted solver body, rank-polymorphic (shard_map-able on (B, n, n)).
+def assignment_ops(*, method: str = "auction", alpha: int = 10,
+                   max_rounds: int = 200_000, rounds_per_heuristic: int = 16,
+                   use_price_update: bool = True, use_arc_fixing: bool = True,
+                   backend: str = "xla") -> KindOps:
+    """The ``"assignment"`` kind's instance operations (``KindOps``) for
+    one set of ``solve_assignment`` knobs; the same object for equal
+    knobs."""
+    return _assignment_ops(method, alpha, max_rounds, rounds_per_heuristic,
+                           use_price_update, use_arc_fixing, backend)
 
-    Orchestration lives in ``repro.core.solver_loop.run_masked``: each
-    instance runs its own flattened ε-scaling schedule (``_ScaleState``) and
-    is frozen via selects once its schedule finishes, while the rest of the
-    batch keeps refining.
-    """
-    state = _scale_init(w, alpha=alpha)
+
+@functools.lru_cache(maxsize=None)
+def _assignment_ops(method: str, alpha: int, max_rounds: int,
+                    rounds_per_heuristic: int, use_price_update: bool,
+                    use_arc_fixing: bool, backend: str) -> KindOps:
     spec = _assignment_spec(method, alpha, max_rounds, rounds_per_heuristic,
                             use_price_update, use_arc_fixing, backend)
-    state, _ = run_masked(spec, state, state.eps.shape)
-    return _assignment_finalize(w, state.st)
+
+    def init(w) -> _ScaleState:
+        return _scale_init(w, alpha=alpha)
+
+    def finalize(w, state: _ScaleState, rounds) -> AssignmentResult:
+        # the solver counts its own Jacobi rounds in the state; the
+        # driver's per-cycle rounds are not the result's
+        return _assignment_finalize(w, state.st)
+
+    def solve(w) -> AssignmentResult:
+        # rank-polymorphic: (n, n) or (B, n, n). run_masked through this
+        # module's global: the name is a seam
+        state = init(w)
+        state, rounds = run_masked(spec, state, state.eps.shape)
+        return finalize(w, state, rounds)
+
+    return KindOps(spec=spec, init=jax.jit(init), finalize=jax.jit(finalize),
+                   solve=jax.jit(solve), pad_one=_pad_one,
+                   shape_of=lambda w: (int(np.shape(w)[-1]),), crop=_crop)
 
 
-_scale_init_jit = jax.jit(_scale_init, static_argnames=("alpha",))
-_assignment_finalize_jit = jax.jit(_assignment_finalize)
+def pad_cost_matrix(w, m: int):
+    """Pad an (n, n) integer weight matrix to (m, m), optimum-preserving.
 
+    The real block gets a uniform bonus ``1 - min(0, w.min())`` so every
+    real-real arc strictly beats the zero-weight dummy arcs: every optimal
+    perfect matching of the padded matrix matches real rows to real columns
+    (exchange argument — rerouting a real row from a dummy column to any
+    real column gains ``w + bonus >= 1``), and the real block's restriction
+    is exactly an optimal matching of the original. Padded weight =
+    original weight + n * bonus. Caller must keep
+    ``m * (m+1) * max|w + bonus|`` inside int32 (same contract as
+    ``solve_assignment``).
 
-def _solve_assignment_compact(
-    w: jax.Array,
-    *,
-    lanes=None,
-    method: str,
-    alpha: int,
-    max_rounds: int,
-    rounds_per_heuristic: int,
-    use_price_update: bool,
-    use_arc_fixing: bool,
-    backend: str,
-) -> AssignmentResult:
-    """Batched solve with early-exit compaction on the (B,) axis.
-
-    ``run_compacted`` drives the host loop: instances whose ε schedule
-    finished are dropped from the working set — still-live ones are
-    gathered into dense pow2-sized sub-batches between jitted cycle
-    segments — instead of being select-masked until the whole batch drains.
-    Results bit-match the masked path (tests/test_compact.py).
+    Returns ``(padded, bonus)``.
     """
-    state = _scale_init_jit(jnp.asarray(w, jnp.int32), alpha=alpha)
-    spec = _assignment_spec(method, alpha, max_rounds, rounds_per_heuristic,
-                            use_price_update, use_arc_fixing, backend)
-    state, _ = run_compacted(spec, state, w.shape[0], lanes=lanes)
-    return _assignment_finalize_jit(jnp.asarray(w, jnp.int32), state.st)
+    w = np.asarray(w)
+    n = w.shape[-1]
+    assert m >= n, (m, n)
+    assert np.issubdtype(w.dtype, np.integer), "integer weights only"
+    bonus = int(1 - min(0, int(w.min()))) if n else 1
+    out = np.zeros((m, m), np.int32)
+    out[:n, :n] = w + bonus
+    return jnp.asarray(out), bonus
 
 
-def _solve_assignment_stepped(
-    w: jax.Array,
-    *,
-    method: str,
-    alpha: int,
-    max_rounds: int,
-    rounds_per_heuristic: int,
-    use_price_update: bool,
-    use_arc_fixing: bool,
-    backend: str,
-) -> AssignmentResult:
-    """Eager masked solve for cycle telemetry (any batch rank).
+def _pad_one(w, shape):
+    (m,) = shape
+    return pad_cost_matrix(w, m)[0][None]
 
-    Same init/finalize jits as the compacted path around an eager
-    ``run_masked``, which host-steps the jitted cycle under the active
-    ``cycle_events(masked=True)`` hook that routed here.  Bit-matches
-    ``_solve_assignment_impl`` (tests/test_obs.py).
-    """
-    w_i = jnp.asarray(w, jnp.int32)
-    state = _scale_init_jit(w_i, alpha=alpha)
-    spec = _assignment_spec(method, alpha, max_rounds, rounds_per_heuristic,
-                            use_price_update, use_arc_fixing, backend)
-    state, _ = run_masked(spec, state, state.eps.shape)
-    return _assignment_finalize_jit(w_i, state.st)
+
+@functools.partial(jax.jit, static_argnames=("shape",))
+def _crop(res: AssignmentResult, b, shape, original) -> AssignmentResult:
+    """Instance ``b`` of a batched result, cropped to its n, with the
+    weight recomputed on its ``original`` (unpadded) costs, in one
+    dispatch."""
+    (n,) = shape
+    original = jnp.asarray(original, jnp.int32)
+    col = res.col_of_row[b, :n]
+    valid = col < n              # unconverged rows may hold dummy cols
+    picked = jnp.take_along_axis(
+        original, jnp.minimum(col, n - 1)[:, None], axis=1)[:, 0]
+    return AssignmentResult(
+        col_of_row=col, weight=jnp.sum(jnp.where(valid, picked, 0)),
+        p_x=res.p_x[b, :n], p_y=res.p_y[b, :n],
+        rounds=res.rounds[b], pushes=res.pushes[b],
+        relabels=res.relabels[b], converged=res.converged[b],
+    )
 
 
 def solve_assignment(
@@ -606,29 +608,18 @@ def solve_assignment(
     are frozen by liveness masks, so batched results bit-match a loop of
     single-instance solves (tests/test_batch.py).
     """
-    kw = dict(method=method, alpha=alpha, max_rounds=max_rounds,
-              rounds_per_heuristic=rounds_per_heuristic,
-              use_price_update=use_price_update,
-              use_arc_fixing=use_arc_fixing, backend=backend)
-    if compact:
-        if w.ndim != 3:
-            raise ValueError(
-                f"compact=True needs batched (B, n, n) weights, got shape "
-                f"{w.shape}; compaction drops converged instances from a "
-                f"batch axis")
-        lanes = None
-        if mesh is not None:
-            from repro.launch.mesh import compact_lanes
-            lanes = compact_lanes(mesh, mesh_axis, w.shape[0])
-        return _solve_assignment_compact(w, lanes=lanes, **kw)
-    if mesh is None:
-        if masked_events_active():
-            return _solve_assignment_stepped(w, **kw)
-        return _solve_assignment_impl(w, **kw)
-    if w.ndim != 3:
+    if compact and w.ndim != 3:
+        raise ValueError(
+            f"compact=True needs batched (B, n, n) weights, got shape "
+            f"{w.shape}; compaction drops converged instances from a "
+            f"batch axis")
+    if mesh is not None and w.ndim != 3:
         raise ValueError(
             f"mesh-sharded solve_assignment needs batched (B, n, n) weights, "
             f"got shape {w.shape}")
-    from repro.launch.mesh import dispatch_sharded
-    return dispatch_sharded(_solve_assignment_impl, (w,), w.shape[0],
-                            mesh, mesh_axis, **kw)
+    ops = assignment_ops(method=method, alpha=alpha, max_rounds=max_rounds,
+                         rounds_per_heuristic=rounds_per_heuristic,
+                         use_price_update=use_price_update,
+                         use_arc_fixing=use_arc_fixing, backend=backend)
+    return solve(ops, w, jnp.shape(w)[:-2], compact=compact, mesh=mesh,
+                 mesh_axis=mesh_axis)

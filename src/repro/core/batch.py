@@ -60,7 +60,6 @@ adding a kind.
 """
 from __future__ import annotations
 
-import functools
 from typing import Any, Callable, Iterable, NamedTuple, Sequence
 
 import jax
@@ -68,11 +67,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.assignment.cost_scaling import (AssignmentResult,
+                                               assignment_ops,
+                                               pad_cost_matrix,
                                                solve_assignment)
 from repro.core.kinds import SolverKind, get_kind, register_kind
-from repro.core.maxflow.grid import (GridFlowResult, GridProblem,
-                                     maxflow_grid_batch)
-from repro.core.refill import RefillRuntime
+from repro.core.maxflow.grid import (GridFlowResult, GridProblem, grid_ops,
+                                     maxflow_grid_batch, pad_grid_problem,
+                                     stack_grid_problems)
 from repro.obs.trace import span
 
 __all__ = [
@@ -340,34 +341,6 @@ def validate_grid_problem(problem) -> GridProblem:
     return GridProblem(cap, cs, ct)
 
 
-def pad_grid_problem(problem: GridProblem, H: int, W: int) -> GridProblem:
-    """Zero-capacity pad a grid-cut instance to (H, W).
-
-    Padded nodes carry no terminal or neighbour capacity, so they hold no
-    excess and never push or relabel usefully — they are inert, and the
-    max-flow value (and the cut restricted to the original window) of the
-    padded instance equals the original's.
-    """
-    cap, cs, ct = problem
-    h, w = cs.shape[-2:]
-    assert H >= h and W >= w, (H, W, h, w)
-    pad2 = ((0, H - h), (0, W - w))
-    return GridProblem(
-        cap_nbr=jnp.pad(cap, ((0, 0),) + pad2),
-        cap_src=jnp.pad(cs, pad2),
-        cap_sink=jnp.pad(ct, pad2),
-    )
-
-
-def stack_grid_problems(problems: Sequence[GridProblem]) -> GridProblem:
-    """Stack same-shape instances into the (B, 4, H, W) batched layout."""
-    return GridProblem(
-        cap_nbr=jnp.stack([jnp.asarray(p.cap_nbr) for p in problems]),
-        cap_src=jnp.stack([jnp.asarray(p.cap_src) for p in problems]),
-        cap_sink=jnp.stack([jnp.asarray(p.cap_sink) for p in problems]),
-    )
-
-
 def inert_grid_problem(H: int, W: int) -> GridProblem:
     """An all-zero-capacity instance: no excess, converges in 0 rounds.
 
@@ -434,31 +407,10 @@ def solve_prepared_maxflow(
         stats = _stats("maxflow", prep, res.rounds, res.converged, compact,
                        heuristics=res.heuristics)
     with span("solve/crop"):
-        out = {i: _crop_grid(res, b, h=prep.shapes[b][0], w=prep.shapes[b][1])
+        crop = grid_ops(backend=backend, **solver_kw).crop
+        out = {i: crop(res, b, prep.shapes[b])
                for b, i in enumerate(prep.idxs)}
     return out, stats
-
-
-@functools.partial(jax.jit, static_argnames=("h", "w"))
-def _crop_grid(res: GridFlowResult, b, *, h: int, w: int) -> GridFlowResult:
-    """Instance ``b`` of a bucket's result, cropped to its (h, w), in one
-    dispatch: the device stage crops after the solve, on an idle device,
-    where eager slicing would take a dispatch per field."""
-    st = res.state
-    return GridFlowResult(
-        flow=res.flow[b],
-        cut=res.cut[b, :h, :w],
-        state=st._replace(
-            e=st.e[b, :h, :w], h=st.h[b, :h, :w],
-            cap=st.cap[b, :, :h, :w],
-            cap_src=st.cap_src[b, :h, :w],
-            cap_sink=st.cap_sink[b, :h, :w],
-            sink_flow=st.sink_flow[b], src_flow=st.src_flow[b],
-            heur=None if st.heur is None else st.heur[b]),
-        rounds=res.rounds[b],
-        converged=res.converged[b],
-        heuristics=None if res.heuristics is None else res.heuristics[b],
-    )
 
 
 def solve_maxflow_batch(
@@ -496,31 +448,6 @@ def validate_assignment_matrix(w) -> np.ndarray:
             f"malformed assignment request: need a square integer "
             f"matrix, got shape {w.shape} dtype {w.dtype}")
     return w
-
-
-def pad_cost_matrix(w, m: int):
-    """Pad an (n, n) integer weight matrix to (m, m), optimum-preserving.
-
-    The real block gets a uniform bonus ``1 - min(0, w.min())`` so every
-    real-real arc strictly beats the zero-weight dummy arcs: every optimal
-    perfect matching of the padded matrix matches real rows to real columns
-    (exchange argument — rerouting a real row from a dummy column to any
-    real column gains ``w + bonus >= 1``), and the real block's restriction
-    is exactly an optimal matching of the original. Padded weight =
-    original weight + n * bonus. Caller must keep
-    ``m * (m+1) * max|w + bonus|`` inside int32 (same contract as
-    ``solve_assignment``).
-
-    Returns ``(padded, bonus)``.
-    """
-    w = np.asarray(w)
-    n = w.shape[-1]
-    assert m >= n, (m, n)
-    assert np.issubdtype(w.dtype, np.integer), "integer weights only"
-    bonus = int(1 - min(0, int(w.min()))) if n else 1
-    out = np.zeros((m, m), np.int32)
-    out[:n, :n] = w + bonus
-    return jnp.asarray(out), bonus
 
 
 def inert_cost_matrix(m: int) -> jax.Array:
@@ -578,29 +505,12 @@ def solve_prepared_assignment(
         stats = _stats("assignment", prep, res.rounds, res.converged,
                        compact)
     with span("solve/crop"):
-        out = {i: _crop_assignment(
-                   res, b, jnp.asarray(prep.originals[b], jnp.int32),
-                   n=prep.shapes[b][0])
+        crop = assignment_ops(**solver_kw).crop
+        out = {i: crop(res, b, prep.shapes[b],
+                       jnp.asarray(prep.originals[b], jnp.int32))
                for b, i in enumerate(prep.idxs)}
     return out, stats
 
-
-@functools.partial(jax.jit, static_argnames=("n",))
-def _crop_assignment(res: AssignmentResult, b, original,
-                     *, n: int) -> AssignmentResult:
-    """Instance ``b`` of a bucket's result, cropped to its n, with the
-    weight recomputed on its ``original`` costs, in one dispatch (see
-    ``_crop_grid``)."""
-    col = res.col_of_row[b, :n]
-    valid = col < n              # unconverged rows may hold dummy cols
-    picked = jnp.take_along_axis(
-        original, jnp.minimum(col, n - 1)[:, None], axis=1)[:, 0]
-    return AssignmentResult(
-        col_of_row=col, weight=jnp.sum(jnp.where(valid, picked, 0)),
-        p_x=res.p_x[b, :n], p_y=res.p_y[b, :n],
-        rounds=res.rounds[b], pushes=res.pushes[b],
-        relabels=res.relabels[b], converged=res.converged[b],
-    )
 
 def solve_assignment_batch(
     costs: Sequence,
@@ -640,134 +550,8 @@ def _maxflow_inert(shape: tuple) -> GridProblem:
     return inert_grid_problem(*shape)
 
 
-def _maxflow_loop_spec(*, rounds_per_heuristic: int = 32,
-                       max_rounds: int = 100_000, bfs_max_iters: int = 0,
-                       backend: str = "xla", stall_threshold: float = 0.05):
-    """The grid solver's cached ``LoopSpec`` factory (``maxflow_grid``
-    defaults); see ``repro.core.maxflow.grid``."""
-    from repro.core.maxflow.grid import _grid_spec
-    return _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
-                      backend, stall_threshold)
-
-
-def _maxflow_refill(*, rounds_per_heuristic: int = 32,
-                    max_rounds: int = 100_000, bfs_max_iters: int = 0,
-                    backend: str = "xla",
-                    stall_threshold: float = 0.05) -> RefillRuntime:
-    """The ``"maxflow"`` kind's continuous-batching runtime
-    (``repro.core.refill``): the same cached spec / jitted init+finalize
-    the compacted batch driver uses, so a refilled instance's trajectory
-    bit-matches its closed-batch solve.  Problems use the public
-    (B, 4, H, W) layout; init/finalize own the internal direction-axis
-    moveaxis exactly as ``_grid_batch_compact`` does."""
-    from repro.core.maxflow.grid import (_grid_finalize_jit, _grid_init_jit,
-                                         _grid_spec)
-    spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
-                      backend, stall_threshold)
-
-    def pad_one(problem: GridProblem, shape) -> GridProblem:
-        H, W = shape
-        return stack_grid_problems([pad_grid_problem(problem, H, W)])
-
-    def init(stacked: GridProblem):
-        return _grid_init_jit(
-            jnp.moveaxis(jnp.asarray(stacked.cap_nbr), 1, 0),
-            jnp.asarray(stacked.cap_src), jnp.asarray(stacked.cap_sink),
-            bfs_max_iters=bfs_max_iters)
-
-    def finalize(stacked, state, rounds) -> GridFlowResult:
-        res = _grid_finalize_jit(state, rounds,
-                                 bfs_max_iters=bfs_max_iters)
-        return res._replace(state=res.state._replace(
-            cap=jnp.moveaxis(res.state.cap, 0, 1)))
-
-    def crop(res: GridFlowResult, shape, original) -> GridFlowResult:
-        h, w = shape
-        st = res.state
-        return GridFlowResult(
-            flow=res.flow[0], cut=res.cut[0, :h, :w],
-            state=st._replace(
-                e=st.e[0, :h, :w], h=st.h[0, :h, :w],
-                cap=st.cap[0, :, :h, :w], cap_src=st.cap_src[0, :h, :w],
-                cap_sink=st.cap_sink[0, :h, :w],
-                sink_flow=st.sink_flow[0], src_flow=st.src_flow[0],
-                heur=None if st.heur is None else st.heur[0]),
-            rounds=res.rounds[0], converged=res.converged[0],
-            heuristics=None if res.heuristics is None else res.heuristics[0])
-
-    def shape_of(problem: GridProblem) -> tuple:
-        return tuple(np.asarray(jnp.asarray(problem.cap_src)).shape)
-
-    return RefillRuntime(spec=spec, pad_one=pad_one, init=init,
-                         finalize=finalize, crop=crop, shape_of=shape_of)
-
-
 def _assignment_inert(shape: tuple) -> jax.Array:
     return inert_cost_matrix(*shape)
-
-
-def _assignment_refill(*, method: str = "auction", alpha: int = 10,
-                       max_rounds: int = 200_000,
-                       rounds_per_heuristic: int = 16,
-                       use_price_update: bool = True,
-                       use_arc_fixing: bool = True,
-                       backend: str = "xla") -> RefillRuntime:
-    """The ``"assignment"`` kind's continuous-batching runtime: bonus-
-    shifted padding on the way in (``pad_cost_matrix``), weight recomputed
-    on the ORIGINAL costs on the way out — exactly the
-    ``solve_prepared_assignment`` crop, per instance."""
-    from repro.core.assignment.cost_scaling import (_assignment_finalize_jit,
-                                                    _assignment_spec,
-                                                    _scale_init_jit)
-    spec = _assignment_spec(method, alpha, max_rounds, rounds_per_heuristic,
-                            use_price_update, use_arc_fixing, backend)
-
-    def pad_one(w, shape):
-        (m,) = shape
-        return pad_cost_matrix(w, m)[0][None]
-
-    def init(stacked):
-        return _scale_init_jit(jnp.asarray(stacked, jnp.int32), alpha=alpha)
-
-    def finalize(stacked, state, rounds) -> AssignmentResult:
-        # the solver's own per-instance round/push counters live in the
-        # state; the driver-side rounds argument is unused (as in the
-        # closed-batch path)
-        return _assignment_finalize_jit(jnp.asarray(stacked, jnp.int32),
-                                        state.st)
-
-    def crop(res: AssignmentResult, shape, original) -> AssignmentResult:
-        (n,) = shape
-        col = res.col_of_row[0, :n]
-        valid = col < n          # unconverged rows may hold dummy cols
-        picked = jnp.take_along_axis(
-            jnp.asarray(original, jnp.int32),
-            jnp.minimum(col, n - 1)[:, None], axis=1)[:, 0]
-        weight = jnp.sum(jnp.where(valid, picked, 0))
-        return AssignmentResult(
-            col_of_row=col, weight=weight,
-            p_x=res.p_x[0, :n], p_y=res.p_y[0, :n],
-            rounds=res.rounds[0], pushes=res.pushes[0],
-            relabels=res.relabels[0], converged=res.converged[0])
-
-    def shape_of(w) -> tuple:
-        return (int(np.asarray(w).shape[-1]),)
-
-    return RefillRuntime(spec=spec, pad_one=pad_one, init=init,
-                         finalize=finalize, crop=crop, shape_of=shape_of)
-
-
-def _assignment_loop_spec(*, method: str = "auction", alpha: int = 10,
-                          max_rounds: int = 200_000,
-                          rounds_per_heuristic: int = 16,
-                          use_price_update: bool = True,
-                          use_arc_fixing: bool = True,
-                          backend: str = "xla"):
-    """The assignment solver's cached ``LoopSpec`` factory
-    (``solve_assignment`` defaults); see ``repro.core.assignment``."""
-    from repro.core.assignment.cost_scaling import _assignment_spec
-    return _assignment_spec(method, alpha, max_rounds, rounds_per_heuristic,
-                            use_price_update, use_arc_fixing, backend)
 
 
 # ------------------------------------------------------ warm-start hooks
@@ -783,12 +567,6 @@ def _pad_trailing(a, shape, fill=0):
     return jnp.pad(a, pads, constant_values=fill)
 
 
-def _maxflow_init_state(**solver_kw):
-    """Cold per-instance init for the ``"maxflow"`` kind — the refill
-    runtime's init, registered so warm/cold mixing shares one code path."""
-    return _maxflow_refill(**solver_kw).init
-
-
 def _maxflow_warm_state(*, rounds_per_heuristic: int = 32,
                         max_rounds: int = 100_000, bfs_max_iters: int = 0,
                         backend: str = "xla", stall_threshold: float = 0.05):
@@ -797,23 +575,21 @@ def _maxflow_warm_state(*, rounds_per_heuristic: int = 32,
     the heights (``repro.core.maxflow.grid._grid_warm``).  Without a base
     problem the prior flow is unrecoverable from residuals alone, so the
     hook degrades to the cold init."""
-    from repro.core.maxflow.grid import _grid_init_jit, _grid_warm_jit
+    from repro.core.maxflow.grid import _grid_warm_jit
+    cold = grid_ops(rounds_per_heuristic=rounds_per_heuristic,
+                    max_rounds=max_rounds, bfs_max_iters=bfs_max_iters,
+                    backend=backend, stall_threshold=stall_threshold).init
 
     def warm1(problem1: GridProblem, solution, *, base_problem1=None,
               delta_bound=None):
-        cap = jnp.moveaxis(jnp.asarray(problem1.cap_nbr), 1, 0)
-        cs = jnp.asarray(problem1.cap_src)
-        ct = jnp.asarray(problem1.cap_sink)
         if base_problem1 is None:
-            return _grid_init_jit(cap, cs, ct, bfs_max_iters=bfs_max_iters)
-        H, W = cs.shape[-2:]
-        bcap = jnp.moveaxis(jnp.asarray(base_problem1.cap_nbr), 1, 0)
-        bct = jnp.asarray(base_problem1.cap_sink)
+            return cold(problem1)
+        H, W = problem1.cap_src.shape[-2:]
         # cached solution arrays are at the ORIGINAL (h, w); inert padding
         # carries no flow, so zero-extending them to the bucket is exact
-        pcap = _pad_trailing(solution["cap"], (H, W))[:, None]
+        pcap = _pad_trailing(solution["cap"], (H, W))[None]
         pct = _pad_trailing(solution["cap_sink"], (H, W))[None]
-        return _grid_warm_jit(cap, cs, ct, bcap, bct, pcap, pct,
+        return _grid_warm_jit(problem1, base_problem1, pcap, pct,
                               bfs_max_iters=bfs_max_iters)
 
     return warm1
@@ -823,10 +599,6 @@ def _maxflow_solution_of(res: GridFlowResult):
     """Cacheable artifact: the residual capacities (grid + sink edges) —
     with the base problem they reconstruct the full prior flow."""
     return {"cap": res.state.cap, "cap_sink": res.state.cap_sink}
-
-
-def _assignment_init_state(**solver_kw):
-    return _assignment_refill(**solver_kw).init
 
 
 def _assignment_warm_state(*, method: str = "auction", alpha: int = 10,
@@ -872,9 +644,7 @@ register_kind(SolverKind(
     inert_problem=_maxflow_inert,
     prepare_buckets=prepare_maxflow_buckets,
     solve_prepared=solve_prepared_maxflow,
-    loop_spec=_maxflow_loop_spec,
-    refill=_maxflow_refill,
-    init_state=_maxflow_init_state,
+    ops=grid_ops,
     warm_state=_maxflow_warm_state,
     solution_of=_maxflow_solution_of,
 ))
@@ -885,9 +655,7 @@ register_kind(SolverKind(
     inert_problem=_assignment_inert,
     prepare_buckets=prepare_assignment_buckets,
     solve_prepared=solve_prepared_assignment,
-    loop_spec=_assignment_loop_spec,
-    refill=_assignment_refill,
-    init_state=_assignment_init_state,
+    ops=assignment_ops,
     warm_state=_assignment_warm_state,
     solution_of=_assignment_solution_of,
 ))

@@ -1,17 +1,16 @@
 """Solver-kind registry: the one seam every layer above the kernels shares.
 
 The stack above the solvers — the ragged pad-and-bucket front end
-(``repro.core.batch``), the serving engine (``repro.serve.engine``), the
-async scheduler (``repro.serve.scheduler``), and the benchmark runner
-(``benchmarks.run``) — used to hardcode the paper's two solvers as
-``"maxflow" | "assignment"`` string branches.  This module replaces every
-one of those if/elif ladders with a REGISTRY: a solver kind registers once,
-under a string name, the five capabilities the upper layers need, and
-every layer dispatches through ``get_kind``.  Adding a new kind (the
-ROADMAP's refactor-test) is then ~one ``LoopSpec`` + kernels + one
-``register_kind`` call — ``repro.core.matching`` (GPU bipartite
-maximum-cardinality matching, Deveci et al., arXiv:1303.1379) is the third
-kind and the proof of the seam; see docs/solvers.md for the walkthrough.
+(``repro.core.batch``), warm starts (``repro.core.warm``), continuous
+batching (``repro.core.refill``), the serving engine
+(``repro.serve.engine``) and the async scheduler
+(``repro.serve.scheduler``) — dispatches through this REGISTRY: a solver
+kind registers once, under a string name, the capabilities the upper
+layers need, and every layer looks it up with ``get_kind``.  Adding a kind
+is one solver module (a ``LoopSpec``, its instance operations and
+kernels) plus one ``register_kind`` call — ``repro.core.matching`` (GPU
+bipartite maximum-cardinality matching, Deveci et al., arXiv:1303.1379)
+is the third kind; see docs/solvers.md for the walkthrough.
 
 A ``SolverKind`` bundles:
 
@@ -26,25 +25,19 @@ A ``SolverKind`` bundles:
 * ``solve_prepared(prep, *, compact=, mesh=, mesh_axis=, **kw)`` — the
   DEVICE stage: one batched dispatch of a prepared bucket, returning
   ``({payload_position: result}, BucketStats)``.
-* ``loop_spec(**static_kw) -> LoopSpec`` — the kind's cached ``LoopSpec``
-  factory (``repro.core.solver_loop``); exposed so callers can drive the
-  loop runtime directly (and so the registry documents where the kind's
-  cycle actually lives).
-* ``refill(**static_kw) -> RefillRuntime`` — OPTIONAL (default ``None``):
-  the kind's continuous-batching runtime (``repro.core.refill``) — the
-  pad-one/init/finalize/crop pieces that let the serving layer admit new
-  instances of this kind into an in-flight compacted solve at cycle
-  boundaries.  Kinds without one still serve through the closed-batch
-  path everywhere.
+* ``ops(**static_kw) -> KindOps`` — OPTIONAL (default ``None``): the
+  kind's cached instance operations (``repro.core.solver_loop.KindOps``):
+  its ``LoopSpec``, jitted init / finalize / fused masked solve, pad-one,
+  shape-of and the jitted per-instance crop.  The kind's batched entry
+  point hands them to ``repro.core.solver_loop.solve``, the one driver
+  ladder (masked, host-stepped, compacted, sharded); refill sessions and
+  warm starts build and finish their states with the same pieces, so
+  every path bit-matches the closed batch.  Kinds without it serve
+  through the closed-batch path only.
 
-Three further OPTIONAL hooks form the warm-start seam (``repro.core.warm``
-drives them; all three builtin kinds register all three):
+Two further OPTIONAL hooks complete the warm-start seam
+(``repro.core.warm`` drives them; all three builtin kinds register both):
 
-* ``init_state(**static_kw) -> (problem1) -> state1`` — the kind's COLD
-  init, extracted from inside the solver and registered: builds the loop
-  state for one padded batch-1 stacked problem.  The same init the solver
-  uses internally, so per-instance cold init inside a mixed warm/cold
-  batch bit-matches the closed-batch path.
 * ``warm_state(**static_kw) -> (problem1, solution, base_problem1=,
   delta_bound=) -> state1`` — rebuild a VALID loop state for the (possibly
   delta-mutated) ``problem1`` from a previously cached ``solution``:
@@ -53,7 +46,8 @@ drives them; all three builtin kinds register all three):
   a delta-bounded rung with the prior prices (assignment); keep the still-
   valid matched pairs and re-run augmenting rounds (matching).  The warm
   state must drive the UNCHANGED loop to the same optimum a cold solve of
-  the mutated problem reaches.
+  the mutated problem reaches; cold instances of the same batch start
+  from ``ops.init``.
 * ``solution_of(result) -> solution`` — extract the cacheable artifact
   (the thing ``warm_state`` consumes) from one cropped per-instance
   result; ``repro.core.warm.SolutionCache`` stores and spills these.
@@ -81,15 +75,13 @@ class SolverKind(NamedTuple):
     inert_problem: Callable[..., Any]
     prepare_buckets: Callable[..., list]
     solve_prepared: Callable[..., tuple]
-    loop_spec: Callable[..., Any]
-    # optional: the kind's continuous-batching runtime factory
-    # (repro.core.refill.RefillRuntime); None = closed-batch only
-    refill: Callable[..., Any] | None = None
+    # optional: the cached KindOps factory over the kind's static solver
+    # knobs (repro.core.solver_loop); None = closed-batch only
+    ops: Callable[..., Any] | None = None
     # optional warm-start seam (repro.core.warm); None = cold-only kind.
-    # init_state / warm_state are factories over the kind's static solver
-    # knobs returning per-instance (batch-1) state builders; solution_of
-    # maps one cropped result to its cacheable artifact.
-    init_state: Callable[..., Any] | None = None
+    # warm_state is a factory over the static knobs returning a
+    # per-instance (batch-1) state builder; solution_of maps one cropped
+    # result to its cacheable artifact.
     warm_state: Callable[..., Any] | None = None
     solution_of: Callable[[Any], Any] | None = None
 

@@ -24,19 +24,17 @@ Payload forms accepted by the validator (both canonicalize to a dense
 """
 from __future__ import annotations
 
-import functools
-from typing import Iterable, Sequence
+from typing import Iterable
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.batch import (BucketStats, PreparedBucket, _make_buckets,
                               _stats)
 from repro.core.kinds import SolverKind, register_kind
-from repro.core.refill import RefillRuntime
-from repro.core.matching.bfs import (MatchingResult, _matching_spec,
-                                     match_bipartite, match_bipartite_batch)
+from repro.core.matching.bfs import (MatchingResult, match_bipartite,
+                                     match_bipartite_batch, matching_ops,
+                                     pad_matching_problem)
 from repro.core.matching.ref import hopcroft_karp
 from repro.obs.trace import span
 
@@ -108,15 +106,6 @@ def validate_matching_problem(payload) -> np.ndarray:
     return a.astype(bool)
 
 
-def pad_matching_problem(adj, NL: int, NR: int) -> np.ndarray:
-    """Pad an adjacency with edge-less vertices to (NL, NR) —
-    value-preserving: isolated vertices join no matching."""
-    adj = np.asarray(adj, bool)
-    nl, nr = adj.shape
-    assert NL >= nl and NR >= nr, (NL, NR, nl, nr)
-    return np.pad(adj, ((0, NL - nl), (0, NR - nr)))
-
-
 def inert_matching_problem(nl: int, nr: int) -> np.ndarray:
     """An edge-less instance: zero liveness seed, converges in 0 rounds —
     the matching kind's shard-padding filler."""
@@ -172,76 +161,14 @@ def solve_prepared_matching(
     with span("solve/wait"):
         stats = _stats("matching", prep, res.rounds, res.converged, compact)
     with span("solve/crop"):
-        out = {i: _crop_matching(res, b, nl=prep.shapes[b][0],
-                                 nr=prep.shapes[b][1])
+        crop = matching_ops(**solver_kw).crop
+        out = {i: crop(res, b, prep.shapes[b])
                for b, i in enumerate(prep.idxs)}
     return out, stats
 
 
-@functools.partial(jax.jit, static_argnames=("nl", "nr"))
-def _crop_matching(res: MatchingResult, b, *, nl: int,
-                   nr: int) -> MatchingResult:
-    """Instance ``b`` of a bucket's result, cropped to its (nl, nr), in one
-    dispatch (see ``repro.core.batch._crop_grid``)."""
-    return MatchingResult(
-        match_row=res.match_row[b, :nl],
-        match_col=res.match_col[b, :nr],
-        cardinality=res.cardinality[b],
-        rounds=res.rounds[b],
-        converged=res.converged[b],
-    )
-
-
 def _matching_inert(shape: tuple) -> np.ndarray:
     return inert_matching_problem(*shape)
-
-
-def _matching_loop_spec(*, max_rounds: int = 10_000, backend: str = "xla"):
-    """The matching solver's cached ``LoopSpec`` factory
-    (``match_bipartite`` defaults); see ``repro.core.matching.bfs``."""
-    return _matching_spec(max_rounds, backend)
-
-
-def _matching_refill(*, max_rounds: int = 10_000, greedy_init: bool = True,
-                     backend: str = "xla") -> RefillRuntime:
-    """The ``"matching"`` kind's continuous-batching runtime
-    (``repro.core.refill``): isolated-vertex padding in, match-vector crop
-    out — the same jitted init/finalize as ``_match_batch_compact``, so a
-    refilled instance bit-matches its closed-batch solve."""
-    from repro.core.matching.bfs import (_match_finalize_jit, _match_init_jit,
-                                         _matching_spec)
-    spec = _matching_spec(max_rounds, backend)
-
-    def pad_one(adj, shape):
-        NL, NR = shape
-        return jnp.asarray(pad_matching_problem(adj, NL, NR))[None]
-
-    def init(stacked):
-        return _match_init_jit(jnp.asarray(stacked, jnp.bool_),
-                               greedy_init=greedy_init)
-
-    def finalize(stacked, state, rounds) -> MatchingResult:
-        return _match_finalize_jit(state, rounds)
-
-    def crop(res: MatchingResult, shape, original) -> MatchingResult:
-        nl, nr = shape
-        return MatchingResult(
-            match_row=res.match_row[0, :nl],
-            match_col=res.match_col[0, :nr],
-            cardinality=res.cardinality[0],
-            rounds=res.rounds[0], converged=res.converged[0])
-
-    def shape_of(adj) -> tuple:
-        return tuple(np.asarray(adj).shape)
-
-    return RefillRuntime(spec=spec, pad_one=pad_one, init=init,
-                         finalize=finalize, crop=crop, shape_of=shape_of)
-
-
-def _matching_init_state(**solver_kw):
-    """Cold per-instance init — the refill runtime's init, registered on
-    the warm seam so mixed warm/cold batches share one code path."""
-    return _matching_refill(**solver_kw).init
 
 
 def _matching_warm_state(*, max_rounds: int = 10_000,
@@ -273,9 +200,7 @@ register_kind(SolverKind(
     inert_problem=_matching_inert,
     prepare_buckets=prepare_matching_buckets,
     solve_prepared=solve_prepared_matching,
-    loop_spec=_matching_loop_spec,
-    refill=_matching_refill,
-    init_state=_matching_init_state,
+    ops=matching_ops,
     warm_state=_matching_warm_state,
     solution_of=_matching_solution_of,
 ))

@@ -42,9 +42,9 @@ from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.core.solver_loop import (LoopSpec, masked_events_active,
-                                    run_compacted, run_masked)
+from repro.core.solver_loop import KindOps, LoopSpec, run_masked, solve
 
 INF = jnp.int32(2 ** 30)
 
@@ -276,47 +276,57 @@ def _match_finalize(state: MatchState, rounds) -> MatchingResult:
         rounds=rounds, converged=~state.progress)
 
 
-def _solve_match(adj, *, max_rounds, greedy_init, backend) -> MatchingResult:
-    """Shared masked solver loop, rank-polymorphic over leading batch axes."""
-    state = _match_init(adj, greedy_init=greedy_init)
+def matching_ops(*, max_rounds: int = 10_000, greedy_init: bool = True,
+                 backend: str = "xla") -> KindOps:
+    """The ``"matching"`` kind's instance operations (``KindOps``) for one
+    set of ``match_bipartite`` knobs; the same object for equal knobs."""
+    return _matching_ops(max_rounds, greedy_init, backend)
+
+
+@functools.lru_cache(maxsize=None)
+def _matching_ops(max_rounds: int, greedy_init: bool, backend: str) -> KindOps:
     spec = _matching_spec(max_rounds, backend)
-    state, rounds = run_masked(spec, state, adj.shape[:-2])
-    return _match_finalize(state, rounds)
+
+    def init(adj) -> MatchState:
+        return _match_init(adj, greedy_init=greedy_init)
+
+    def finalize(adj, state: MatchState, rounds) -> MatchingResult:
+        return _match_finalize(state, rounds)
+
+    def solve(adj) -> MatchingResult:
+        state, rounds = run_masked(spec, init(adj), jnp.shape(adj)[:-2])
+        return finalize(adj, state, rounds)
+
+    return KindOps(spec=spec, init=jax.jit(init), finalize=jax.jit(finalize),
+                   solve=jax.jit(solve), pad_one=_pad_one,
+                   shape_of=lambda adj: tuple(np.shape(adj)), crop=_crop)
 
 
-_match_init_jit = jax.jit(_match_init, static_argnames=("greedy_init",))
-_match_finalize_jit = jax.jit(_match_finalize)
+def pad_matching_problem(adj, NL: int, NR: int) -> np.ndarray:
+    """Pad an adjacency with edge-less vertices to (NL, NR) —
+    value-preserving: isolated vertices join no matching."""
+    adj = np.asarray(adj, bool)
+    nl, nr = adj.shape
+    assert NL >= nl and NR >= nr, (NL, NR, nl, nr)
+    return np.pad(adj, ((0, NL - nl), (0, NR - nr)))
 
 
-def _match_batch_compact(adj, *, max_rounds, greedy_init, backend,
-                         lanes=None) -> MatchingResult:
-    """Batched solve with early-exit compaction on the (B,) axis.
-
-    Same driver pattern as the grid/assignment solvers: ``run_compacted``
-    gathers still-live instances into dense pow2-sized sub-batches between
-    jitted cycle segments.  Results bit-match the masked path.
-    """
-    state = _match_init_jit(jnp.asarray(adj, jnp.bool_),
-                            greedy_init=greedy_init)
-    spec = _matching_spec(max_rounds, backend)
-    state, rounds = run_compacted(spec, state, adj.shape[0], lanes=lanes)
-    return _match_finalize_jit(state, rounds)
+def _pad_one(adj, shape):
+    return jnp.asarray(pad_matching_problem(adj, *shape))[None]
 
 
-def _match_batch_stepped(adj, *, max_rounds, greedy_init,
-                         backend) -> MatchingResult:
-    """Eager masked solve for cycle telemetry (public (B, ...) layout).
-
-    Same init/finalize jits as the compacted path around an eager
-    ``run_masked``, which host-steps the jitted phase under the active
-    ``cycle_events(masked=True)`` hook that routed here.  Bit-matches
-    ``_match_batch_impl`` (tests/test_obs.py).
-    """
-    state = _match_init_jit(jnp.asarray(adj, jnp.bool_),
-                            greedy_init=greedy_init)
-    spec = _matching_spec(max_rounds, backend)
-    state, rounds = run_masked(spec, state, adj.shape[:-2])
-    return _match_finalize_jit(state, rounds)
+@functools.partial(jax.jit, static_argnames=("shape",))
+def _crop(res: MatchingResult, b, shape, original=None) -> MatchingResult:
+    """Instance ``b`` of a batched result, cropped to its original
+    (nl, nr), in one dispatch."""
+    nl, nr = shape
+    return MatchingResult(
+        match_row=res.match_row[b, :nl],
+        match_col=res.match_col[b, :nr],
+        cardinality=res.cardinality[b],
+        rounds=res.rounds[b],
+        converged=res.converged[b],
+    )
 
 
 @functools.partial(jax.jit,
@@ -352,17 +362,9 @@ def match_bipartite(
         raise ValueError(
             f"match_bipartite solves ONE instance (adj (nl, nr), got "
             f"{adj.shape}); use match_bipartite_batch for stacked problems")
-    return _solve_match(adj, max_rounds=max_rounds, greedy_init=greedy_init,
-                        backend=backend)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("max_rounds", "greedy_init", "backend"))
-def _match_batch_impl(adj, *, max_rounds, greedy_init,
-                      backend) -> MatchingResult:
-    """Batched solve (shard_map-able body; every leaf leads with batch)."""
-    return _solve_match(adj, max_rounds=max_rounds, greedy_init=greedy_init,
-                        backend=backend)
+    state = _match_init(adj, greedy_init=greedy_init)
+    state, rounds = run_masked(_matching_spec(max_rounds, backend), state, ())
+    return _match_finalize(state, rounds)
 
 
 def match_bipartite_batch(
@@ -403,18 +405,7 @@ def match_bipartite_batch(
         raise ValueError(
             f"match_bipartite_batch expects adj (B, nl, nr), got "
             f"{adj.shape}; use match_bipartite for a single instance")
-    kw = dict(max_rounds=max_rounds, greedy_init=greedy_init,
-              backend=backend)
-    if compact:
-        lanes = None
-        if mesh is not None:
-            from repro.launch.mesh import compact_lanes
-            lanes = compact_lanes(mesh, mesh_axis, adj.shape[0])
-        return _match_batch_compact(adj, lanes=lanes, **kw)
-    if mesh is None:
-        if masked_events_active():
-            return _match_batch_stepped(adj, **kw)
-        return _match_batch_impl(adj, **kw)
-    from repro.launch.mesh import dispatch_sharded
-    return dispatch_sharded(_match_batch_impl, (adj,), adj.shape[0],
-                            mesh, mesh_axis, **kw)
+    ops = matching_ops(max_rounds=max_rounds, greedy_init=greedy_init,
+                       backend=backend)
+    return solve(ops, adj, adj.shape[:1], compact=compact, mesh=mesh,
+                 mesh_axis=mesh_axis)

@@ -41,8 +41,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.solver_loop import (LoopSpec, masked_events_active,
-                                    run_compacted, run_masked)
+from repro.core.solver_loop import KindOps, LoopSpec, run_masked, solve
 
 UP, DOWN, LEFT, RIGHT = 0, 1, 2, 3
 _OPP = (DOWN, UP, RIGHT, LEFT)
@@ -450,40 +449,116 @@ def _grid_finalize(state: GridFlowState, rounds, *,
         )
 
 
-def _solve_grid(cap0, cs0, ct0, *, rounds_per_heuristic, max_rounds,
-                bfs_max_iters, backend,
-                stall_threshold=0.05) -> GridFlowResult:
-    """Shared masked solver loop, rank-polymorphic over leading batch axes.
+def _dir_first(cap: jax.Array) -> jax.Array:
+    """Public batched capacities ``(B, 4, H, W)`` -> the solver's layout
+    ``(4, B, H, W)``: inside the solver the direction axis leads so
+    ``cap[d]`` stays a plain index; in public the batch axis leads."""
+    return jnp.moveaxis(cap, 1, 0)
 
-    ``cs0``/``ct0`` are ``(..., H, W)`` with ``cap0`` ``(4, ..., H, W)``.
-    Orchestration lives in ``repro.core.solver_loop.run_masked``: the loop
-    predicate is a per-instance liveness mask (batch shape ``(...,)``,
-    scalar for a single instance) and converged instances are frozen via
-    selects. With no batch axes the mask is the scalar predicate of the
-    original single-instance loop, so both entry points share one
-    trajectory.
-    """
-    state = _grid_init(cap0, cs0, ct0, bfs_max_iters=bfs_max_iters)
+
+def grid_ops(*, rounds_per_heuristic: int = 32, max_rounds: int = 100_000,
+             bfs_max_iters: int = 0, backend: str = "xla",
+             stall_threshold: float = 0.05) -> KindOps:
+    """The ``"maxflow"`` kind's instance operations (``KindOps``) for one
+    set of ``maxflow_grid`` knobs; the same object for equal knobs."""
+    return _grid_ops(rounds_per_heuristic, max_rounds, bfs_max_iters,
+                     backend, stall_threshold)
+
+
+@functools.lru_cache(maxsize=None)
+def _grid_ops(rounds_per_heuristic: int, max_rounds: int, bfs_max_iters: int,
+              backend: str, stall_threshold: float) -> KindOps:
     spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
                       backend, stall_threshold)
-    state, rounds = run_masked(spec, state, cs0.shape[:-2])
-    return _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters)
+
+    def init(stacked: GridProblem) -> GridFlowState:
+        cap, cs, ct = stacked
+        return _grid_init(_dir_first(cap), cs, ct,
+                          bfs_max_iters=bfs_max_iters)
+
+    def finalize(stacked, state, rounds) -> GridFlowResult:
+        res = _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters)
+        return res._replace(state=res.state._replace(
+            cap=jnp.moveaxis(res.state.cap, 0, 1)))
+
+    def solve(stacked: GridProblem) -> GridFlowResult:
+        # run_masked through this module's global: the name is a seam
+        state, rounds = run_masked(spec, init(stacked),
+                                   jnp.shape(stacked[1])[:-2])
+        return finalize(stacked, state, rounds)
+
+    return KindOps(spec=spec, init=jax.jit(init), finalize=jax.jit(finalize),
+                   solve=jax.jit(solve), pad_one=_pad_one,
+                   shape_of=lambda p: tuple(jnp.shape(p.cap_src)), crop=_crop)
 
 
-_grid_init_jit = jax.jit(_grid_init, static_argnames=("bfs_max_iters",))
-_grid_finalize_jit = jax.jit(_grid_finalize,
-                             static_argnames=("bfs_max_iters",))
+def pad_grid_problem(problem: GridProblem, H: int, W: int) -> GridProblem:
+    """Zero-capacity pad a grid-cut instance to (H, W).
+
+    Padded nodes carry no terminal or neighbour capacity, so they hold no
+    excess and never push or relabel usefully — they are inert, and the
+    max-flow value (and the cut restricted to the original window) of the
+    padded instance equals the original's.
+    """
+    cap, cs, ct = problem
+    h, w = cs.shape[-2:]
+    assert H >= h and W >= w, (H, W, h, w)
+    pad2 = ((0, H - h), (0, W - w))
+    return GridProblem(
+        cap_nbr=jnp.pad(cap, ((0, 0),) + pad2),
+        cap_src=jnp.pad(cs, pad2),
+        cap_sink=jnp.pad(ct, pad2),
+    )
 
 
-def _grid_warm(cap0, cs0, ct0, base_cap, base_ct, prior_cap, prior_ct,
+def stack_grid_problems(problems) -> GridProblem:
+    """Stack same-shape instances into the (B, 4, H, W) batched layout."""
+    return GridProblem(
+        cap_nbr=jnp.stack([jnp.asarray(p.cap_nbr) for p in problems]),
+        cap_src=jnp.stack([jnp.asarray(p.cap_src) for p in problems]),
+        cap_sink=jnp.stack([jnp.asarray(p.cap_sink) for p in problems]),
+    )
+
+
+def _pad_one(problem: GridProblem, shape) -> GridProblem:
+    return stack_grid_problems([pad_grid_problem(problem, *shape)])
+
+
+@functools.partial(jax.jit, static_argnames=("shape",))
+def _crop(res: GridFlowResult, b, shape, original=None) -> GridFlowResult:
+    """Instance ``b`` of a batched result, cropped to its original (h, w),
+    in one dispatch: the crop runs after the solve, on an idle device,
+    where eager slicing would take a dispatch per field."""
+    h, w = shape
+    st = res.state
+    return GridFlowResult(
+        flow=res.flow[b],
+        cut=res.cut[b, :h, :w],
+        state=st._replace(
+            e=st.e[b, :h, :w], h=st.h[b, :h, :w],
+            cap=st.cap[b, :, :h, :w],
+            cap_src=st.cap_src[b, :h, :w],
+            cap_sink=st.cap_sink[b, :h, :w],
+            sink_flow=st.sink_flow[b], src_flow=st.src_flow[b],
+            heur=None if st.heur is None else st.heur[b]),
+        rounds=res.rounds[b],
+        converged=res.converged[b],
+        heuristics=None if res.heuristics is None else res.heuristics[b],
+    )
+
+
+def _grid_warm(problem: GridProblem, base: GridProblem, prior_cap, prior_ct,
                *, bfs_max_iters: int) -> GridFlowState:
     """Warm restart (arXiv 2511.01235 §3): clamp the prior flow to the new
     capacities, repair conservation deficits, re-BFS the heights.
 
-    Internal layout throughout (``cap*`` ``(4, ..., H, W)``, rest
-    ``(..., H, W)``).  ``base_*`` are the capacities the prior solve ran
-    against; the prior NET flow per grid arc is recovered from its residuals
-    as ``base_cap - prior_cap`` and per sink edge as ``base_ct - prior_ct``.
+    Takes the public batched layout (``problem``/``base`` stacked
+    ``GridProblem``s, the prior residuals ``prior_cap`` ``(B, 4, H, W)``
+    and ``prior_ct`` ``(B, H, W)``) and returns a loop state in the
+    solver's layout, as the kind's ``init`` does.  ``base`` holds the
+    capacities the prior solve ran against; the prior NET flow per grid arc
+    is recovered from its residuals as ``base_cap - prior_cap`` and per sink
+    edge as ``base_ct - prior_ct``.
     The restart invariant (Baumstark et al., arXiv 1507.01926) is that the
     height function stays a valid lower bound on residual sink distance —
     guaranteed here by recomputing exact BFS heights against the repaired
@@ -501,6 +576,9 @@ def _grid_warm(cap0, cs0, ct0, base_cap, base_ct, prior_cap, prior_ct,
     cheap to guard) falls back to its cold init, keeping warm-vs-cold
     equivalence unconditional.
     """
+    cap0, cs0, ct0 = problem
+    cap0, base_cap = _dir_first(cap0), _dir_first(base.cap_nbr)
+    prior_cap, base_ct = _dir_first(prior_cap), base.cap_sink
     *b, H, W = cs0.shape
     n_nodes = jnp.int32(H * W + 2)
     bfs_iters = bfs_max_iters or (H * W + 2)
@@ -571,52 +649,6 @@ def _grid_warm(cap0, cs0, ct0, base_cap, base_ct, prior_cap, prior_ct,
 _grid_warm_jit = jax.jit(_grid_warm, static_argnames=("bfs_max_iters",))
 
 
-def _grid_batch_compact(cap0, cs0, ct0, *, rounds_per_heuristic, max_rounds,
-                        bfs_max_iters, backend, stall_threshold=0.05,
-                        lanes=None) -> GridFlowResult:
-    """Batched solve with early-exit compaction (public (B, ...) layout).
-
-    ``run_compacted`` drives the host loop: still-live instances are
-    gathered into dense pow2-sized sub-batches between jitted cycle
-    segments, so converged instances stop consuming FLOPs instead of being
-    select-masked until the whole batch drains. Results bit-match the
-    masked path (tests/test_compact.py).
-    """
-    state = _grid_init_jit(jnp.moveaxis(jnp.asarray(cap0), 1, 0),
-                           jnp.asarray(cs0), jnp.asarray(ct0),
-                           bfs_max_iters=bfs_max_iters)
-    spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
-                      backend, stall_threshold)
-    state, rounds = run_compacted(spec, state, cs0.shape[0], lanes=lanes)
-    res = _grid_finalize_jit(state, rounds, bfs_max_iters=bfs_max_iters)
-    # public layout: batch axis leads everywhere, including state.cap
-    return res._replace(
-        state=res.state._replace(cap=jnp.moveaxis(res.state.cap, 0, 1)))
-
-
-def _grid_batch_stepped(cap0, cs0, ct0, *, rounds_per_heuristic, max_rounds,
-                        bfs_max_iters, backend,
-                        stall_threshold=0.05) -> GridFlowResult:
-    """Eager masked solve for cycle telemetry (public (B, ...) layout).
-
-    Same init/finalize jits as the compacted path around an eager
-    ``run_masked`` call, which — under the active
-    ``cycle_events(masked=True)`` hook that routed here — host-steps the
-    jitted cycle and emits per-cycle events.  Bit-matches
-    ``_grid_batch_impl`` (the per-cycle jit granularity is what the
-    compacted driver already bit-matches at; tests/test_obs.py).
-    """
-    state = _grid_init_jit(jnp.moveaxis(jnp.asarray(cap0), 1, 0),
-                           jnp.asarray(cs0), jnp.asarray(ct0),
-                           bfs_max_iters=bfs_max_iters)
-    spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
-                      backend, stall_threshold)
-    state, rounds = run_masked(spec, state, cs0.shape[:1])
-    res = _grid_finalize_jit(state, rounds, bfs_max_iters=bfs_max_iters)
-    return res._replace(
-        state=res.state._replace(cap=jnp.moveaxis(res.state.cap, 0, 1)))
-
-
 @functools.partial(
     jax.jit,
     static_argnames=("rounds_per_heuristic", "max_rounds", "bfs_max_iters",
@@ -674,28 +706,11 @@ def maxflow_grid(
         raise ValueError(
             f"maxflow_grid solves ONE instance (cap_nbr (4, H, W), got "
             f"{cap0.shape}); use maxflow_grid_batch for stacked problems")
-    return _solve_grid(cap0, cs0, ct0,
-                       rounds_per_heuristic=rounds_per_heuristic,
-                       max_rounds=max_rounds, bfs_max_iters=bfs_max_iters,
-                       backend=backend, stall_threshold=stall_threshold)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("rounds_per_heuristic", "max_rounds", "bfs_max_iters",
-                     "backend", "stall_threshold"),
-)
-def _grid_batch_impl(cap0, cs0, ct0, *, rounds_per_heuristic, max_rounds,
-                     bfs_max_iters, backend,
-                     stall_threshold=0.05) -> GridFlowResult:
-    """Batched solve in the public (B, ...) layout (shard_map-able body)."""
-    res = _solve_grid(jnp.moveaxis(cap0, 1, 0), cs0, ct0,
-                      rounds_per_heuristic=rounds_per_heuristic,
-                      max_rounds=max_rounds, bfs_max_iters=bfs_max_iters,
-                      backend=backend, stall_threshold=stall_threshold)
-    # public layout: batch axis leads everywhere, including state.cap
-    return res._replace(
-        state=res.state._replace(cap=jnp.moveaxis(res.state.cap, 0, 1)))
+    spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
+                      backend, stall_threshold)
+    state = _grid_init(cap0, cs0, ct0, bfs_max_iters=bfs_max_iters)
+    state, rounds = run_masked(spec, state, ())
+    return _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters)
 
 
 def maxflow_grid_batch(
@@ -758,19 +773,8 @@ def maxflow_grid_batch(
         raise ValueError(
             f"maxflow_grid_batch expects cap_nbr (B, 4, H, W), got "
             f"{cap0.shape}; use maxflow_grid for a single instance")
-    kw = dict(rounds_per_heuristic=rounds_per_heuristic,
-              max_rounds=max_rounds, bfs_max_iters=bfs_max_iters,
-              backend=backend, stall_threshold=stall_threshold)
-    if compact:
-        lanes = None
-        if mesh is not None:
-            from repro.launch.mesh import compact_lanes
-            lanes = compact_lanes(mesh, mesh_axis, cs0.shape[0])
-        return _grid_batch_compact(cap0, cs0, ct0, lanes=lanes, **kw)
-    if mesh is None:
-        if masked_events_active():
-            return _grid_batch_stepped(cap0, cs0, ct0, **kw)
-        return _grid_batch_impl(cap0, cs0, ct0, **kw)
-    from repro.launch.mesh import dispatch_sharded
-    return dispatch_sharded(_grid_batch_impl, (cap0, cs0, ct0),
-                            cs0.shape[0], mesh, mesh_axis, **kw)
+    ops = grid_ops(rounds_per_heuristic=rounds_per_heuristic,
+                   max_rounds=max_rounds, bfs_max_iters=bfs_max_iters,
+                   backend=backend, stall_threshold=stall_threshold)
+    return solve(ops, GridProblem(cap0, cs0, ct0), cs0.shape[:1],
+                 compact=compact, mesh=mesh, mesh_axis=mesh_axis)

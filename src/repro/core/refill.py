@@ -10,10 +10,12 @@ streams.
 
 This module turns that low-level hook protocol into a per-kind SESSION:
 
-* ``RefillRuntime`` — what a solver kind registers (the optional
-  ``refill`` factory field of ``repro.core.kinds.SolverKind``): its
-  ``LoopSpec`` plus the pad-one/init/finalize/crop pieces needed to bring
-  a single request into, and out of, an in-flight batched state.
+* ``refill_runtime`` — a kind's instance operations
+  (``repro.core.solver_loop.KindOps``, the ``ops`` factory field of
+  ``repro.core.kinds.SolverKind``): its ``LoopSpec`` plus the
+  pad-one/init/finalize/crop pieces that bring a single request into, and
+  out of, an in-flight batched state — the same pieces the closed-batch
+  path uses.
 * ``RefillSolver`` — one continuous-batching session of one kind on one
   fixed bucket shape: seed it with initial payloads, hand it an ``admit``
   callback that supplies more as slots free up, and receive per-request
@@ -32,60 +34,33 @@ closed-batch path (same padding shape).  The serving layer
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, NamedTuple
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.core.kinds import get_kind
-from repro.core.solver_loop import LoopSpec, run_compacted
+from repro.core.solver_loop import KindOps, drive
 from repro.obs.trace import span, use_tracer
 
-__all__ = ["RefillRuntime", "refill_runtime", "RefillSolver"]
+__all__ = ["refill_runtime", "RefillSolver"]
 
 
-class RefillRuntime(NamedTuple):
-    """A kind's continuous-batching registration (see module docstring).
+def refill_runtime(kind: str, **solver_kw) -> KindOps:
+    """The instance operations (``KindOps``) of ``kind`` with ``solver_kw``
+    knobs, which a refill session drives.
 
-    Build through the kind's cached factory (``get_kind(k).refill(**kw)``)
-    so repeated sessions share one ``LoopSpec`` object and the jitted
-    cycle/init/finalize dispatches cache-hit across sessions.
-
-    All callables follow the kind's PUBLIC batched layout (batch axis
-    leading on every problem leaf); ``init``/``finalize`` own any internal
-    re-layout (e.g. the grid solver's direction-axis moveaxis).
-    """
-
-    spec: LoopSpec          # the kind's solver-loop registration
-    pad_one: Callable       # (payload, bucket_shape) -> batch-1 problem
-    init: Callable          # stacked problem (B leading) -> solver state
-    finalize: Callable      # (batch-1 problem, state1, rounds(1,)) -> result
-    crop: Callable          # (batch-1 result, orig_shape, payload) -> result
-    shape_of: Callable      # validated payload -> its shape tuple
-
-
-def refill_runtime(kind: str, **solver_kw) -> RefillRuntime:
-    """The registered refill runtime of ``kind`` with ``solver_kw`` knobs.
-
-    Raises ``ValueError`` for kinds that registered no refill factory —
+    Raises ``ValueError`` for kinds that registered no ``ops`` factory —
     callers (the async scheduler) treat that as "serve this kind through
     the closed-batch path".
     """
     k = get_kind(kind)
-    if k.refill is None:
+    if k.ops is None:
         raise ValueError(
             f"solver kind {kind!r} has no refill runtime; it serves "
-            f"closed-batch only (register a SolverKind.refill factory to "
+            f"closed-batch only (register a SolverKind.ops factory to "
             f"enable continuous batching)")
-    return k.refill(**solver_kw)
-
-
-def _concat_problems(stacked1: list):
-    """Concatenate batch-1 problems along the leading (public) batch axis."""
-    if len(stacked1) == 1:
-        return stacked1[0]
-    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *stacked1)
+    return k.ops(**solver_kw)
 
 
 class RefillSolver:
@@ -99,9 +74,9 @@ class RefillSolver:
     keyed by request index.
 
     Args:
-      kind: a registered solver kind with a refill runtime
-        (``SolverKind.refill``; ``maxflow`` / ``assignment`` /
-        ``matching`` all register one).
+      kind: a registered solver kind with instance operations
+        (``SolverKind.ops``; ``maxflow`` / ``assignment`` / ``matching``
+        all register them).
       shape: the session bucket shape — every admitted payload must fit
         componentwise (``fits``).
       capacity: number of slots (per-cycle batch width upper bound).
@@ -114,7 +89,7 @@ class RefillSolver:
         tracer through here). ``None`` records nothing; ``device-solve``
         still reaches a running ``jax.profiler`` capture.
       **solver_kw: the kind's static solver knobs (``backend=``,
-        ``max_rounds=``, ...), forwarded to the refill runtime factory.
+        ``max_rounds=``, ...), forwarded to the kind's ``ops`` factory.
     """
 
     def __init__(self, kind: str, *, shape, capacity: int, mesh=None,
@@ -185,7 +160,7 @@ class RefillSolver:
             payloads (positions index ``initial``); warm and cold seeds
             mix in one session via per-slot init.
         """
-        from repro.core.warm import WarmStart, _concat_states
+        from repro.core.warm import WarmStart, _concat_problems, _concat_states
         rt, cap, shape = self.rt, self.capacity, self.shape
         initial = list(initial)
         warm = dict(warm or {})
@@ -313,7 +288,7 @@ class RefillSolver:
                 try:
                     res1 = rt.finalize(problems[idx], st1,
                                        jnp.full((1,), r, jnp.int32))
-                    res = rt.crop(res1, *metas[idx])
+                    res = rt.crop(res1, 0, *metas[idx])
                 except Exception as e:
                     _error(idx, e)
                     return
@@ -324,6 +299,6 @@ class RefillSolver:
         with use_tracer(self.tracer), \
                 span("device-solve", kind=self.kind.name, bucket=list(shape),
                      capacity=cap, driver="refill"):
-            run_compacted(rt.spec, state, cap, lanes=session._lanes,
-                          refill=_Hook())
+            drive(rt.spec, state, (cap,), compact=True, lanes=session._lanes,
+                  refill=_Hook())
         return results

@@ -51,6 +51,13 @@ per-instance pure, a refilled run executes every instance's exact
 solo-solve trajectory: values AND counters bit-match a loop of single
 solves (tests/test_refill.py).  ``repro.core.refill`` wraps the hook
 protocol into a per-kind session object.
+
+Drivers: a kind hands its instance operations (``KindOps``: the spec plus
+jitted init / finalize / fused masked solve, pad-one, shape-of and crop)
+to ``solve``, the one driver ladder — compacted, sharded
+(``repro.launch.mesh.dispatch_sharded``), host-stepped for cycle
+telemetry, or the fused masked program.  ``drive`` runs an already built
+state (warm starts, refill sessions) through the same drivers.
 """
 from __future__ import annotations
 
@@ -153,9 +160,9 @@ def trace_cycles(fn: Callable[[int, int], None]):
 def masked_events_active() -> bool:
     """Is a ``cycle_events(masked=True)`` hook installed on this thread?
 
-    Solver batch wrappers consult this to route an eager masked solve
+    ``solve`` and ``drive`` consult this to route an eager masked solve
     through the host-stepped driver (init/finalize jits + per-cycle jit)
-    instead of the fused jitted entry point, so the hook can observe
+    instead of the fused jitted program, so the hook can observe
     per-cycle liveness. False for plain ``trace_cycles`` hooks.
     """
     hook = _cycle_hook.get()
@@ -179,6 +186,82 @@ class LoopSpec(NamedTuple):
     # int (the balanced backend's ``heuristics``); folded into CycleEvent
     # .heur_total for detail hooks
     heur: Callable | None = None
+
+
+class KindOps(NamedTuple):
+    """A solver kind's instance operations over one set of static knobs.
+
+    Build through the kind's cached factory (one ``functools.lru_cache``
+    over its static knobs, registered as ``SolverKind.ops``) so every
+    batch, refill session and warm start shares one ``LoopSpec`` and one
+    set of jitted programs.  Problems and results use the kind's PUBLIC
+    batched layout (batch axis leading on every leaf); ``init`` and
+    ``finalize`` own any solver-internal re-layout.
+    """
+
+    spec: LoopSpec        # the kind's solver-loop registration
+    init: Callable        # stacked problem -> loop state (jitted)
+    finalize: Callable    # (stacked, state, rounds) -> batched result (jitted)
+    solve: Callable       # stacked -> batched result: init, run_masked and
+    #                       finalize in one jitted program
+    pad_one: Callable     # (payload, bucket_shape) -> batch-1 stacked problem
+    shape_of: Callable    # validated payload -> its shape tuple
+    crop: Callable        # (result, b, shape, original) -> instance b cropped
+    #                       to its original shape, one jitted dispatch
+
+
+def solve(ops: KindOps, stacked, batch_shape: tuple, *, compact: bool = False,
+          mesh=None, mesh_axis: str | None = None, state=None):
+    """The one driver ladder: solve a stacked batch with a kind's ``ops``.
+
+    Chooses, in order:
+
+    * ``compact=True`` — early-exit compaction (``run_compacted``), one
+      lane per shard when ``mesh`` is given (``compact_lanes``);
+    * ``mesh`` — the fused masked program sharded over the batch axis
+      through ``repro.launch.mesh.dispatch_sharded`` (looked up at call
+      time);
+    * a ``cycle_events(masked=True)`` hook on this thread — jitted init,
+      an eager host-stepped ``run_masked``, jitted finalize (the same
+      values as the fused program, with per-cycle events);
+    * otherwise ``ops.solve``, one jitted dispatch.
+
+    ``state`` starts the loop from a state already built (warm starts)
+    instead of ``ops.init(stacked)``; such a solve never takes the fused
+    programs, and on a mesh it runs as per-shard compacted lanes.
+    """
+    lanes = None
+    if mesh is not None:
+        if not compact and state is None:
+            from repro.launch.mesh import dispatch_sharded
+            return dispatch_sharded(ops.solve, (stacked,), batch_shape[0],
+                                    mesh, mesh_axis)
+        from repro.launch.mesh import compact_lanes
+        lanes = compact_lanes(mesh, mesh_axis, batch_shape[0])
+        compact = True
+    if state is None:
+        if not compact and not masked_events_active():
+            return ops.solve(stacked)
+        state = ops.init(stacked)
+    state, rounds = drive(ops.spec, state, batch_shape, compact=compact,
+                          lanes=lanes)
+    return ops.finalize(stacked, state, rounds)
+
+
+def drive(spec: LoopSpec, state, batch_shape: tuple, *, compact: bool = False,
+          lanes=None, refill=None):
+    """Run a built loop state to its end; returns ``(state, rounds)``.
+
+    Compacted (``run_compacted`` on ``lanes``, with an optional refill
+    hook) or masked: host-stepped under a ``cycle_events(masked=True)``
+    hook, else one jitted loop.
+    """
+    if compact:
+        return run_compacted(spec, state, batch_shape[0], lanes=lanes,
+                             refill=refill)
+    if masked_events_active():
+        return run_masked(spec, state, batch_shape)
+    return _run_masked_jit(spec, state, batch_shape)
 
 
 def _lead(spec: LoopSpec, batch_ndim: int):
@@ -228,6 +311,9 @@ def run_masked(spec: LoopSpec, state, batch_shape: tuple):
 
     return jax.lax.while_loop(
         cond, body, (state, jnp.zeros(batch_shape, jnp.int32)))
+
+
+_run_masked_jit = jax.jit(run_masked, static_argnames=("spec", "batch_shape"))
 
 
 @functools.partial(jax.jit, static_argnames=("spec", "batch_ndim"))

@@ -23,25 +23,25 @@ kind-agnostic half of that pipeline:
   ``get`` and are transparently reloaded on hit.
 * ``solve_warm`` — the generic driver: pads warm and cold instances into
   the SAME buckets, builds per-instance states through the kind's
-  ``init_state`` / ``warm_state`` hooks, and drives the UNCHANGED masked /
-  compacted / sharded-lane loop runtimes from that state.  The correctness
+  ``ops.init`` / ``warm_state`` hooks, and drives the UNCHANGED masked /
+  compacted / sharded-lane loop runtimes from that state
+  (``repro.core.solver_loop.solve``).  The correctness
   contract (tests/test_warm.py): a warm-started solve reaches the same
   optimum as a cold solve of the mutated graph, for every kind and driver.
 """
 from __future__ import annotations
 
-import functools
 import hashlib
 import threading
 from collections import OrderedDict
-from typing import Any, Callable, NamedTuple, Sequence
+from typing import Any, NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core.kinds import get_kind
-from repro.core.solver_loop import run_masked
+from repro.core.solver_loop import solve
 
 __all__ = [
     "GraphDelta", "apply_delta", "WarmStart", "SolutionCache",
@@ -276,6 +276,13 @@ def _lead_axis(spec, leaf, batch_ndim: int = 1) -> int:
     return fn(leaf, batch_ndim) if fn is not None else 0
 
 
+def _concat_problems(stacked1: list):
+    """Concatenate batch-1 problems along the leading (public) batch axis."""
+    if len(stacked1) == 1:
+        return stacked1[0]
+    return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=0), *stacked1)
+
+
 def _concat_states(spec, states1: list):
     """Concatenate batch-1 states along each leaf's batch axis."""
     if len(states1) == 1:
@@ -285,11 +292,6 @@ def _concat_states(spec, states1: list):
         return jnp.concatenate(xs, axis=_lead_axis(spec, xs[0]))
 
     return jax.tree.map(cat, *states1)
-
-
-@functools.partial(jax.jit, static_argnames=("spec", "n"))
-def _run_masked_state(spec, state, n: int):
-    return run_masked(spec, state, (n,))
 
 
 def build_warm_state(kind_obj, rt, warm_fn, problem1, payload, ws, bshape):
@@ -314,19 +316,18 @@ def solve_warm(kind: str, payloads: Sequence, warm: dict | None = None, *,
     ordinary cold buckets; returns per-payload results in input order.
 
     ``warm`` maps payload positions to ``WarmStart``s; positions absent
-    from it are cold-initialized through the kind's registered
-    ``init_state`` hook — inside the SAME bucket, so a mixed batch costs
-    one dispatch.  Drivers: the jitted masked loop by default,
-    ``run_compacted`` under ``compact=True``, per-device compacted lanes
-    when ``mesh`` is given.  ``stats_out`` (a list) receives one
-    ``BucketStats`` per dispatched bucket, exactly like
+    from it are cold-initialized through the kind's ``ops.init`` — inside
+    the SAME bucket, so a mixed batch costs one dispatch.  Drivers
+    (``repro.core.solver_loop.solve`` from the built state): the jitted
+    masked loop by default, ``run_compacted`` under ``compact=True``,
+    per-device compacted lanes when ``mesh`` is given.  ``stats_out`` (a
+    list) receives one ``BucketStats`` per dispatched bucket, exactly like
     ``repro.core.batch.solve_batch``.
     """
     from repro.core.batch import BucketStats, _bucket_shape
-    from repro.core.solver_loop import _tree_take, run_compacted
 
     k = get_kind(kind)
-    for hook in ("refill", "init_state", "warm_state"):
+    for hook in ("ops", "warm_state"):
         if getattr(k, hook) is None:
             raise ValueError(
                 f"solver kind {kind!r} registered no {hook!r} hook; it "
@@ -342,8 +343,7 @@ def solve_warm(kind: str, payloads: Sequence, warm: dict | None = None, *,
                 f"warm[{pos}] must be a WarmStart, "
                 f"got {type(warm[pos]).__name__}")
 
-    rt = k.refill(**solver_kw)
-    init_fn = k.init_state(**solver_kw)
+    rt = k.ops(**solver_kw)
     warm_fn = k.warm_state(**solver_kw)
     validated = [k.validate(p) for p in payloads]
     shapes = [rt.shape_of(p) for p in validated]
@@ -359,47 +359,30 @@ def solve_warm(kind: str, payloads: Sequence, warm: dict | None = None, *,
 
     results: dict[int, Any] = {}
     for bshape, idxs in groups.items():
-        problems1 = {i: rt.pad_one(validated[i], bshape) for i in idxs}
-        states1 = []
-        for i in idxs:
-            if i in warm:
-                states1.append(build_warm_state(
-                    k, rt, warm_fn, problems1[i], validated[i], warm[i],
-                    bshape))
-            else:
-                states1.append(init_fn(problems1[i]))
+        problems1 = [rt.pad_one(validated[i], bshape) for i in idxs]
         n = len(idxs)
         if mesh is not None:
             # pad with inert instances so the batch divides the shard
             # count, exactly like solve_batch's mesh path
-            from repro.launch.mesh import compact_lanes, shard_count
+            from repro.launch.mesh import shard_count
             n_pad = -n % shard_count(mesh, mesh_axis)
-            for _ in range(n_pad):
-                states1.append(
-                    init_fn(rt.pad_one(k.inert_problem(bshape), bshape)))
-            state = _concat_states(rt.spec, states1)
-            state, rounds = run_compacted(
-                rt.spec, state, n + n_pad,
-                lanes=compact_lanes(mesh, mesh_axis, n + n_pad))
-        elif compact:
-            state = _concat_states(rt.spec, states1)
-            state, rounds = run_compacted(rt.spec, state, n)
-        else:
-            state = _concat_states(rt.spec, states1)
-            state, rounds = _run_masked_state(rt.spec, state, n)
-
-        rounds = jnp.asarray(rounds)
+            problems1 += [rt.pad_one(k.inert_problem(bshape), bshape)] * n_pad
+        states1 = [build_warm_state(k, rt, warm_fn, p1, validated[i],
+                                    warm[i], bshape)
+                   if i in warm else rt.init(p1)
+                   for i, p1 in zip(idxs, problems1)]
+        states1 += [rt.init(p1) for p1 in problems1[n:]]
+        res = solve(rt, _concat_problems(problems1), (len(problems1),),
+                    compact=compact, mesh=mesh, mesh_axis=mesh_axis,
+                    state=_concat_states(rt.spec, states1))
         for b, i in enumerate(idxs):
-            state1 = _tree_take(rt.spec, state, jnp.asarray([b]))
-            res1 = rt.finalize(problems1[i], state1, rounds[b:b + 1])
-            results[i] = rt.crop(res1, shapes[i], validated[i])
+            results[i] = rt.crop(res, b, shapes[i], validated[i])
         if stats_out is not None:
-            r = np.asarray(rounds)
-            conv = sum(bool(np.asarray(results[i].converged)) for i in idxs
-                       if hasattr(results[i], "converged"))
+            r = np.asarray(res.rounds)[:n]
             stats_out.append(BucketStats(
                 kind=kind, shape=bshape, n_real=n, n_pad=0,
                 compact=bool(compact or mesh is not None),
                 rounds_min=int(r.min()), rounds_max=int(r.max()),
-                rounds_mean=float(r.mean()), n_converged=int(conv)))
+                rounds_mean=float(r.mean()),
+                n_converged=int(np.asarray(res.converged)[:n].sum())))
     return [results[i] for i in range(len(payloads))]

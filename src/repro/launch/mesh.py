@@ -184,21 +184,18 @@ def shard_batched(fn: Callable, mesh, mesh_axis: str | None = None):
                              check_vma=False))
 
 
-@functools.lru_cache(maxsize=None)
-def _cached_shard_batched(impl: Callable, mesh, mesh_axis, kw_items: tuple):
-    return shard_batched(functools.partial(impl, **dict(kw_items)),
-                         mesh, mesh_axis)
+_cached_shard_batched = functools.lru_cache(maxsize=None)(shard_batched)
 
 
 def dispatch_sharded(impl: Callable, args: tuple, batch_size: int, mesh,
-                     mesh_axis: str | None, **static_kw):
-    """Run batched ``impl(*args, **static_kw)`` with the batch axis sharded.
+                     mesh_axis: str | None):
+    """Run batched ``impl(*args)`` with the batch axis sharded.
 
-    The one mesh-dispatch funnel the solvers' ``mesh=`` paths share:
+    The one mesh-dispatch funnel (``repro.core.solver_loop.solve``):
     validates ``batch_size`` divides the shard count, memoizes the
-    jit(shard_map(...)) callable per (impl, mesh, mesh_axis, kwargs), and
-    calls it. ``impl`` must be hashable (a module-level function) and
-    ``static_kw`` values hashable.
+    jit(shard_map(...)) callable per (impl, mesh, mesh_axis), and calls
+    it. ``impl`` must be hashable and stable across calls (a kind's
+    cached ``KindOps.solve``).
     """
     n_shards = shard_count(mesh, mesh_axis)
     if batch_size % n_shards:
@@ -206,6 +203,4 @@ def dispatch_sharded(impl: Callable, args: tuple, batch_size: int, mesh,
             f"batch size {batch_size} not divisible by shard count "
             f"{n_shards}; pad the batch (repro.core.batch does this "
             f"automatically)")
-    fn = _cached_shard_batched(impl, mesh, mesh_axis,
-                               tuple(sorted(static_kw.items())))
-    return fn(*args)
+    return _cached_shard_batched(impl, mesh, mesh_axis)(*args)

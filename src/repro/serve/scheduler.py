@@ -161,7 +161,7 @@ class AsyncSolverEngine:
       spread_threshold / min_compact_batch / ewma_alpha: adaptive-policy
         tuning — see ``choose_driver`` / ``repro.serve.metrics``.
       refill: continuous batching (default off). A flushed batch of a
-        kind with a registered refill runtime (``SolverKind.refill``)
+        kind with registered instance operations (``SolverKind.ops``)
         becomes a ``repro.core.refill.RefillSolver`` session: slots freed
         by converged instances are refilled MID-SOLVE from the kind's
         pending queue (requests must fit the session's bucket shape), and
@@ -234,7 +234,7 @@ class AsyncSolverEngine:
         self._cache = cache if cache is not None else SolutionCache()
         # scheduler ticket -> (kind, cache key) of its cached solution
         self._key_of_ticket: dict[int, tuple[str, str]] = {}
-        # kind -> RefillRuntime | None (None = closed-batch only), lazy
+        # kind -> KindOps | None (None = closed-batch only), lazy
         self._refill_rts: dict[str, Any] = {}
         self._lanes = [
             _Lane(engine=SolverEngine(

@@ -53,7 +53,7 @@ def test_registered_kinds_exported_and_complete():
         kind = core.get_kind(k)
         assert kind.name == k
         assert callable(kind.validate) and callable(kind.prepare_buckets)
-        assert callable(kind.solve_prepared) and callable(kind.loop_spec)
+        assert callable(kind.solve_prepared) and callable(kind.ops)
         assert callable(kind.inert_problem)
 
 

@@ -8,16 +8,26 @@ The contracts under test (repro.core.kinds + the serve layer's shims):
   order-of-import dependent), as do malformed kind names;
 * ``registered_kinds()`` ensures the builtins and preserves registration
   order; ``ensure=False`` peeks without importing solver modules;
+* each builtin kind's ``ops`` factory hands back ONE cached object per
+  set of knobs, and its batched solves go through the named entry points
+  and loop drivers that faults and profiles are planted on;
 * the pre-registry serve spellings — ``SolverEngine(maxflow_kw=,
   assignment_kw=)``, ``submit_maxflow`` / ``submit_assignment`` on both
   engines — still work but emit ``DeprecationWarning`` and delegate to the
   generic ``solver_kw`` / ``submit(kind, ...)`` path.
 """
+import inspect
+
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import repro.core.assignment.cost_scaling as cost_scaling
+import repro.core.batch as batch_mod
 import repro.core.kinds as kinds_mod
+import repro.core.maxflow.grid as grid
+import repro.launch.mesh as mesh_mod
 from repro.core.batch import solve_batch
 from repro.core.kinds import (SolverKind, get_kind, register_kind,
                               registered_kinds)
@@ -33,7 +43,7 @@ def _prob(rng, h=5, w=5):
 def _dummy_kind(name):
     f = lambda *a, **k: None  # noqa: E731
     return SolverKind(name=name, validate=f, inert_problem=f,
-                      prepare_buckets=f, solve_prepared=f, loop_spec=f)
+                      prepare_buckets=f, solve_prepared=f, ops=f)
 
 
 # ------------------------------------------------------------ error paths
@@ -86,6 +96,67 @@ def test_registered_kinds_order_and_peek():
     # peek mode never shrinks the view once the builtins are in
     assert set(registered_kinds(ensure=False)) == set(ks)
     assert get_kind("maxflow").name == "maxflow"
+
+
+# ---------------------------------------------- instance ops and seams
+
+@pytest.mark.parametrize("kind", ["maxflow", "assignment", "matching"])
+def test_ops_factory_is_cached(kind):
+    """Equal knobs, however spelled, give the SAME ops object — so every
+    batch, refill session and warm start shares one set of compiled
+    programs — and other knobs another."""
+    k = get_kind(kind)
+    defaults = {n: p.default
+                for n, p in inspect.signature(k.ops).parameters.items()}
+    assert k.ops(max_rounds=7) is k.ops(max_rounds=7)
+    assert k.ops(**defaults) is k.ops()
+    assert k.ops(max_rounds=7) is not k.ops()
+    assert k.ops(max_rounds=7).spec is not k.ops().spec
+
+
+def _counting(orig, calls):
+    def wrapped(*a, **kw):
+        calls.append(1)
+        return orig(*a, **kw)
+    return wrapped
+
+
+@pytest.mark.parametrize("kind,module,name", [
+    ("maxflow", grid, "run_masked"),
+    ("assignment", cost_scaling, "run_masked"),
+    ("maxflow", batch_mod, "maxflow_grid_batch"),
+    ("assignment", batch_mod, "solve_assignment"),
+    ("maxflow", mesh_mod, "dispatch_sharded"),
+])
+def test_batched_solve_goes_through_named_seams(kind, module, name,
+                                                monkeypatch):
+    """``solve_batch`` reaches the kind's loop and entry point through these
+    module globals, looked up when the program is traced or called: a
+    wrapper planted there by name sees the solve, which still answers
+    right."""
+    rng = np.random.default_rng(3)
+    if kind == "maxflow":
+        payloads = [_prob(rng) for _ in range(2)]
+    else:
+        payloads = [rng.integers(0, 20, (5, 5)) for _ in range(2)]
+    want = solve_batch(kind, payloads)
+    mesh = None
+    if name == "dispatch_sharded":
+        from repro.launch.mesh import make_solver_mesh
+        mesh = make_solver_mesh(1)
+    calls = []
+    jax.clear_caches()               # a planted name must be traced anew
+    try:
+        monkeypatch.setattr(module, name,
+                            _counting(getattr(module, name), calls))
+        got = solve_batch(kind, payloads, mesh=mesh)
+    finally:
+        monkeypatch.undo()
+        jax.clear_caches()
+    assert calls, f"{module.__name__}.{name} was not on the solve's path"
+    for g, w in zip(got, want):
+        for a, b in zip(jax.tree.leaves(g), jax.tree.leaves(w)):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 # ------------------------------------------------------ deprecation shims

@@ -342,6 +342,6 @@ def test_matching_kind_registration_surface():
     assert kind.name == "matching"
     inert = kind.inert_problem((4, 6))
     assert inert.shape == (4, 6) and not inert.any()
-    # the cached LoopSpec factory returns the SAME spec for equal knobs
-    assert kind.loop_spec() is kind.loop_spec()
-    assert kind.loop_spec(max_rounds=7) is not kind.loop_spec()
+    # the cached ops factory returns the SAME ops (and spec) for equal knobs
+    assert kind.ops() is kind.ops()
+    assert kind.ops(max_rounds=7).spec is not kind.ops().spec

@@ -398,7 +398,7 @@ def _gated_refill_factory(real_kind, started, gate):
     requests submitted meanwhile can only resolve via admission (the
     deterministic-admission pattern of tests/test_refill.py)."""
     def factory(**kw):
-        rt = real_kind.refill(**kw)
+        rt = real_kind.ops(**kw)
 
         def finalize(problems, st1, r):
             if not started.is_set():
@@ -419,7 +419,7 @@ def test_refill_admission_spans(monkeypatch):
     real = kinds_mod.get_kind("assignment")
     monkeypatch.setitem(
         kinds_mod._REGISTRY, "assignment",
-        real._replace(refill=_gated_refill_factory(real, started, gate)))
+        real._replace(ops=_gated_refill_factory(real, started, gate)))
     rng = np.random.default_rng(8)
     ws = [rng.integers(0, 50, (5, 5)) for _ in range(4)]
     tr = Tracer()

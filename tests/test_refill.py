@@ -256,7 +256,7 @@ def test_refill_admit_contract():
     assert s.fits(ws[0]) and not s.fits(rng.integers(0, 5, (6, 6)))
     # a kind without a registered runtime is a ValueError naming the gap
     real = kinds_mod.get_kind("maxflow")
-    kinds_mod._REGISTRY["maxflow"] = real._replace(refill=None)
+    kinds_mod._REGISTRY["maxflow"] = real._replace(ops=None)
     try:
         with pytest.raises(ValueError, match="no refill runtime"):
             refill_runtime("maxflow")
@@ -295,7 +295,7 @@ def _gated_refill_factory(real_kind, started, gate, poison=None):
     test can submit requests that can only complete via admission — and,
     optionally, so cropping a ``poison``-marked payload raises."""
     def factory(**kw):
-        rt = real_kind.refill(**kw)
+        rt = real_kind.ops(**kw)
 
         def finalize(problems, st1, r):
             if not started.is_set():
@@ -303,11 +303,11 @@ def _gated_refill_factory(real_kind, started, gate, poison=None):
                 assert gate.wait(timeout=WAIT_S), "test gate never opened"
             return rt.finalize(problems, st1, r)
 
-        def crop(res1, shape, payload):
+        def crop(res1, b, shape, payload):
             if poison is not None \
                     and int(np.asarray(payload).ravel()[0]) == poison:
                 raise RuntimeError("poisoned crop")
-            return rt.crop(res1, shape, payload)
+            return rt.crop(res1, b, shape, payload)
 
         return rt._replace(finalize=finalize, crop=crop)
     return factory
@@ -324,7 +324,7 @@ def test_async_refill_admits_mid_solve_and_resolves_per_instance(monkeypatch):
     real = kinds_mod.get_kind("assignment")
     monkeypatch.setitem(
         kinds_mod._REGISTRY, "assignment",
-        real._replace(refill=_gated_refill_factory(real, started, gate)))
+        real._replace(ops=_gated_refill_factory(real, started, gate)))
 
     rng = np.random.default_rng(8)
     ws = [rng.integers(0, 50, (5, 5)) for _ in range(4)]
@@ -363,7 +363,7 @@ def test_async_refill_poison_admitted_mid_solve_fails_alone(monkeypatch):
     real = kinds_mod.get_kind("assignment")
     monkeypatch.setitem(
         kinds_mod._REGISTRY, "assignment",
-        real._replace(refill=_gated_refill_factory(
+        real._replace(ops=_gated_refill_factory(
             real, started, gate, poison=POISON)))
 
     rng = np.random.default_rng(9)
@@ -396,13 +396,13 @@ def test_async_refill_session_abort_falls_back_to_solo(monkeypatch):
     real = kinds_mod.get_kind("assignment")
 
     def broken_factory(**kw):
-        rt = real.refill(**kw)
+        rt = real.ops(**kw)
         def boom(stacked):
             raise RuntimeError("session init detonated")
         return rt._replace(init=boom)
 
     monkeypatch.setitem(kinds_mod._REGISTRY, "assignment",
-                        real._replace(refill=broken_factory))
+                        real._replace(ops=broken_factory))
     rng = np.random.default_rng(10)
     ws = [rng.integers(0, 50, (5, 5)) for _ in range(3)]
     with AsyncSolverEngine(max_batch=3, max_delay_ms=LONG_DEADLINE_MS,
