@@ -280,6 +280,16 @@ def check_no_violations(state: GridFlowState) -> jax.Array:
 VALID_BACKENDS = ("xla", "multipush", "pallas", "balanced")
 
 
+def _relabel_fn(backend: str):
+    """The global relabel of a backend's init, finalize and (fixed-cadence
+    backends) cycle: ``bfs_heights``' heights, with ``"pallas"``'s sweeps
+    in one Pallas call where the grid's shape allows."""
+    if backend == "pallas":
+        from repro.kernels.bfs_relabel.ops import sink_bfs_heights
+        return sink_bfs_heights
+    return bfs_heights
+
+
 def _round_fn(backend: str):
     """Jacobi-round implementation for a backend flag.
 
@@ -325,6 +335,7 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
     contract survives (tests/test_balanced.py).
     """
     round_fn = _round_fn(backend)
+    global_relabel = _relabel_fn(backend)
     # The fused Pallas round writes every plane of the loop carry while it
     # still reads them, so a loop body of one round copies the whole carry
     # before each call; two rounds an iteration alternate between the carry
@@ -384,7 +395,7 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
         new = jax.lax.fori_loop(0, rounds_per_heuristic, inner, state,
                                 unroll=unroll)
         new = new._replace(
-            h=bfs_heights(new.cap, new.cap_sink, new.h, n_nodes, iters))
+            h=global_relabel(new.cap, new.cap_sink, new.h, n_nodes, iters))
         return _count_heur(new, jnp.ones(state.e.shape[:-2], jnp.bool_))
 
     def live(state: GridFlowState, rounds: jax.Array) -> jax.Array:
@@ -401,11 +412,12 @@ def _grid_spec(rounds_per_heuristic: int, max_rounds: int,
                     heur=lambda s: s.heur)
 
 
-def _grid_init(cap0, cs0, ct0, *, bfs_max_iters: int) -> GridFlowState:
+def _grid_init(cap0, cs0, ct0, *, bfs_max_iters: int,
+               relabel=bfs_heights) -> GridFlowState:
     """Paper Alg. 4.7 init: saturate s->x, heights from a round-0 BFS.
 
     Internal layout — ``cs0``/``ct0`` ``(..., H, W)``, ``cap0``
-    ``(4, ..., H, W)``.
+    ``(4, ..., H, W)``. ``relabel`` is the backend's (``_relabel_fn``).
     """
     *b, H, W = cs0.shape
     bshape = tuple(b)
@@ -423,22 +435,23 @@ def _grid_init(cap0, cs0, ct0, *, bfs_max_iters: int) -> GridFlowState:
             heur=jnp.zeros(bshape, jnp.int32),  # init BFS below not counted
         )
         # Start from BFS-consistent heights (global relabel at round 0).
-        return state._replace(h=bfs_heights(state.cap, state.cap_sink,
-                                            state.h, n_nodes, bfs_iters))
+        return state._replace(h=relabel(state.cap, state.cap_sink,
+                                        state.h, n_nodes, bfs_iters))
 
 
-def _grid_finalize(state: GridFlowState, rounds, *,
-                   bfs_max_iters: int) -> GridFlowResult:
+def _grid_finalize(state: GridFlowState, rounds, *, bfs_max_iters: int,
+                   relabel=bfs_heights) -> GridFlowResult:
     """Min cut + convergence flags from a finished (internal-layout) state.
 
     Sink side of the cut = nodes that still reach t in the residual graph.
+    ``relabel`` is the backend's (``_relabel_fn``).
     """
     H, W = state.e.shape[-2:]
     n_nodes = jnp.int32(H * W + 2)
     bfs_iters = bfs_max_iters or (H * W + 2)
     with jax.named_scope("maxflow/finalize"):
-        h_bfs = bfs_heights(state.cap, state.cap_sink, state.h, n_nodes,
-                            bfs_iters)
+        h_bfs = relabel(state.cap, state.cap_sink, state.h, n_nodes,
+                        bfs_iters)
         return GridFlowResult(
             flow=state.sink_flow,
             cut=h_bfs < n_nodes,
@@ -470,14 +483,16 @@ def _grid_ops(rounds_per_heuristic: int, max_rounds: int, bfs_max_iters: int,
               backend: str, stall_threshold: float) -> KindOps:
     spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
                       backend, stall_threshold)
+    relabel = _relabel_fn(backend)
 
     def init(stacked: GridProblem) -> GridFlowState:
         cap, cs, ct = stacked
         return _grid_init(_dir_first(cap), cs, ct,
-                          bfs_max_iters=bfs_max_iters)
+                          bfs_max_iters=bfs_max_iters, relabel=relabel)
 
     def finalize(stacked, state, rounds) -> GridFlowResult:
-        res = _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters)
+        res = _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters,
+                             relabel=relabel)
         return res._replace(state=res.state._replace(
             cap=jnp.moveaxis(res.state.cap, 0, 1)))
 
@@ -708,9 +723,12 @@ def maxflow_grid(
             f"{cap0.shape}); use maxflow_grid_batch for stacked problems")
     spec = _grid_spec(rounds_per_heuristic, max_rounds, bfs_max_iters,
                       backend, stall_threshold)
-    state = _grid_init(cap0, cs0, ct0, bfs_max_iters=bfs_max_iters)
+    relabel = _relabel_fn(backend)
+    state = _grid_init(cap0, cs0, ct0, bfs_max_iters=bfs_max_iters,
+                       relabel=relabel)
     state, rounds = run_masked(spec, state, ())
-    return _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters)
+    return _grid_finalize(state, rounds, bfs_max_iters=bfs_max_iters,
+                          relabel=relabel)
 
 
 def maxflow_grid_batch(

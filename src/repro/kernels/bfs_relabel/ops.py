@@ -1,5 +1,6 @@
-"""Fixpoint driver for the bfs_relabel kernel: the balanced backend's
-global/gap relabel pass.
+"""Drivers of the bfs_relabel kernels: the balanced backend's
+bidirectional global/gap relabel, and the ``pallas`` backend's sink-only
+one (``sink_bfs_heights``, at the end of this module).
 
 ``bfs_relabel_heights`` has the same call shape as ``repro.core.maxflow.
 grid.bfs_heights`` (shape-polymorphic over leading batch axes, jittable)
@@ -22,11 +23,16 @@ batched == loop-of-singles bit-match contract.
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 
+from repro.core.maxflow.grid import bfs_heights
 from repro.kernels import interpret_mode
-from repro.kernels.bfs_relabel.kernel import SWEEPS, bfs_relabel_sweeps
+from repro.kernels.bfs_relabel.kernel import (LANES, SWEEPS,
+                                              bfs_relabel_sweeps, sink_bfs,
+                                              sink_bfs_fits)
 
 # python int, not jnp.int32: this module is imported lazily, possibly
 # inside a jit trace, where creating a jnp constant would leak a tracer
@@ -76,3 +82,31 @@ def bfs_relabel_heights(cap, cap_src, cap_sink, h_prev, n_nodes,
     ds = ds.reshape(h_prev.shape)
     return jnp.where(dt < INF_H, dt,
                      jnp.maximum(h_prev, jnp.where(ds < INF_H, ds, n_nodes)))
+
+
+def sink_bfs_heights(cap, cap_sink, h_prev, n_nodes, max_iters: int, *,
+                     interpret: bool | None = None):
+    """``grid.bfs_heights`` with its sweeps in one ``sink_bfs`` call.
+
+    Same arguments and the same heights, bit for bit, on every input:
+    the min-plus fixpoint is unique, and each grid stops at its own
+    fixpoint or after ``max_iters`` Jacobi sweeps, as ``bfs_heights``
+    leaves it. A grid that is not whole ``(8, 128)`` tiles is padded with
+    closed nodes, which stay unreached and change no height. Where one
+    grid's planes do not fit the kernel (``kernel.sink_bfs_fits``) it is
+    ``bfs_heights`` itself. Shape-polymorphic over leading batch axes,
+    like ``bfs_heights``.
+    """
+    *batch, H, W = h_prev.shape
+    if not sink_bfs_fits(H, W):
+        return bfs_heights(cap, cap_sink, h_prev, n_nodes, max_iters)
+    if interpret is None:
+        interpret = interpret_mode()
+    B = math.prod(batch)
+    pad = ((0, 0), (0, -H % 8), (0, -W % LANES))
+    with jax.named_scope("maxflow/relabel"):
+        h = sink_bfs(jnp.pad(cap.reshape(4, B, H, W), ((0, 0),) + pad),
+                     jnp.pad(cap_sink.reshape(B, H, W), pad),
+                     jnp.pad(h_prev.reshape(B, H, W), pad), n_nodes,
+                     max_iters=max_iters, interpret=interpret)
+    return h[:, :H, :W].reshape(h_prev.shape)

@@ -1,13 +1,15 @@
-"""Pure-jnp oracle for the bfs_relabel kernel (and its combine step).
+"""Pure-jnp oracles for the bfs_relabel kernels (and their combine steps).
 
 ``bfs_relabel_sweeps_ref`` mirrors one kernel invocation (``SWEEPS`` joint
 relaxation sweeps); ``bfs_relabel_heights_ref`` is the full bidirectional
-fixpoint + combine the ops-level driver must reproduce — both are the
-bit-exact references asserted in tests/test_bfs_relabel.py.
+fixpoint + combine the ops-level driver must reproduce;
+``sink_bfs_heights_ref`` is the sink-only relabel, one grid at a time —
+the bit-exact references asserted in tests/test_bfs_relabel.py.
 """
 from __future__ import annotations
 
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core.maxflow.grid import _nbr_h
 
@@ -58,3 +60,30 @@ def bfs_relabel_heights_ref(cap, cap_src, cap_sink, h_prev, n_nodes):
                      jnp.maximum(h_prev,
                                  jnp.where(ds < INF_H, ds,
                                            jnp.int32(n_nodes))))
+
+
+def sink_bfs_heights_ref(cap, cap_sink, h_prev, n_nodes, max_iters: int):
+    """The sink-only global + gap relabel, one grid at a time.
+
+    Each grid relaxes its sink wavefront (seeded 1 where residual x→t
+    exists) until a sweep changes nothing or ``max_iters`` sweeps have
+    run, then unreached nodes take ``max(h_prev, N)``. Leading batch axes
+    of ``h_prev`` (and of ``cap`` after its direction axis) are grids.
+    """
+    *batch, H, W = h_prev.shape
+    B = int(np.prod(batch, dtype=int))
+    cap = jnp.reshape(cap, (4, B, H, W))
+    cap_sink = jnp.reshape(cap_sink, (B, H, W))
+    h_prev = jnp.reshape(h_prev, (B, H, W))
+    out = []
+    for b in range(B):
+        seed = jnp.where(cap_sink[b] > 0, jnp.int32(1), INF_H)
+        h = seed
+        for _ in range(max_iters):
+            new = _relax(h, cap[:, b], seed)
+            if np.array_equal(np.asarray(new), np.asarray(h)):
+                break
+            h = new
+        out.append(jnp.where(h < INF_H, h,
+                             jnp.maximum(h_prev[b], jnp.int32(n_nodes))))
+    return jnp.stack(out).reshape(tuple(batch) + (H, W))

@@ -1,22 +1,31 @@
-"""bfs_relabel pallas kernel vs pure-jnp oracle (balanced backend relabel).
+"""bfs_relabel pallas kernels vs pure-jnp oracles.
 
-The contract: one ``bfs_relabel_sweeps`` launch == ``SWEEPS`` joint
-min-plus relaxation sweeps of both wavefront planes (``ref.
-bfs_relabel_sweeps_ref``), and the ops-level fixpoint driver reproduces
-the eager bidirectional fixpoint + combine (``ref.
+The balanced backend's contract: one ``bfs_relabel_sweeps`` launch ==
+``SWEEPS`` joint min-plus relaxation sweeps of both wavefront planes
+(``ref.bfs_relabel_sweeps_ref``), and the ops-level fixpoint driver
+reproduces the eager bidirectional fixpoint + combine (``ref.
 bfs_relabel_heights_ref``) bit-exactly — single and batched.
+
+The ``pallas`` backend's: ``sink_bfs_heights`` gives ``grid.bfs_heights``'
+heights bit for bit (and ``ref.sink_bfs_heights_ref``'s), with its sweep
+cap counted per grid, and takes the kernel by the grid's shape alone.
 """
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from repro.core.maxflow.grid import bfs_heights
 from repro.core.maxflow.ref import (checkerboard_problem, long_path_problem,
                                     random_grid_problem)
-from repro.kernels.bfs_relabel.kernel import INF_H, SWEEPS, bfs_relabel_sweeps
-from repro.kernels.bfs_relabel.ops import bfs_relabel_heights
+from repro.kernels.bfs_relabel.kernel import (INF_H, SWEEPS,
+                                              bfs_relabel_sweeps,
+                                              sink_bfs_fits)
+from repro.kernels.bfs_relabel.ops import (bfs_relabel_heights,
+                                           sink_bfs_heights)
 from repro.kernels.bfs_relabel.ref import (bfs_relabel_heights_ref,
-                                           bfs_relabel_sweeps_ref)
+                                           bfs_relabel_sweeps_ref,
+                                           sink_bfs_heights_ref)
 
 pytestmark = pytest.mark.kernels
 
@@ -143,3 +152,142 @@ def test_vmem_limit_admits_paper_grid_and_refuses_oversize():
     # interpret mode has no VMEM: the same shape traces
     jax.eval_shape(lambda *a: bfs_relabel_sweeps(*a, interpret=True),
                    cap, big, big, big, big)
+
+
+# ------------------------------------------------- sink_bfs (pallas backend)
+
+def _sink_case(batch, H, W, seed):
+    """Random grids of shape ``batch + (H, W)`` in the solver's layout,
+    with border edges given capacity too (the kernel's masks must close
+    them), and heights ``h_prev`` drawn on ``[0, 2N)``."""
+    rng = np.random.default_rng(seed)
+    B = int(np.prod(batch, dtype=int))
+    probs = [random_grid_problem(rng, H, W) for _ in range(B)]
+    cap = np.stack([p[0] for p in probs], axis=1)
+    cap[:, :, [0, -1], :] = rng.integers(0, 3, cap[:, :, [0, -1], :].shape)
+    cap[:, :, :, [0, -1]] = rng.integers(0, 3, cap[:, :, :, [0, -1]].shape)
+    ct = np.stack([p[2] for p in probs])
+    n = H * W + 2
+    h_prev = rng.integers(0, 2 * n, (B, H, W))
+    return (jnp.asarray(cap.reshape((4,) + batch + (H, W))),
+            jnp.asarray(ct.reshape(batch + (H, W))),
+            jnp.asarray(h_prev.reshape(batch + (H, W)), jnp.int32), n)
+
+
+def _pallas_names(fn, *args):
+    """The names of the ``pallas_call``s ``fn`` traces to, nested ones too."""
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn.params["name"]
+            for v in eqn.params.values():
+                sub = getattr(v, "jaxpr", v)
+                if hasattr(sub, "eqns"):
+                    yield from walk(sub)
+    return list(walk(jax.make_jaxpr(fn)(*args).jaxpr))
+
+
+@pytest.mark.parametrize("batch,H,W,max_iters,seed", [
+    ((1,), 8, 128, 0, 0),       # one grid of one sublane tile
+    ((3,), 24, 136, 0, 1),      # a lane-ragged width (padded to 256)
+    ((8,), 8, 128, 0, 2),       # the batch cell's batch size
+    ((), 64, 64, 0, 3),         # a single grid, no batch axis
+    ((2,), 12, 12, 0, 9),       # rows padded to a sublane tile
+    ((2,), 16, 512, 0, 10),     # four lane tiles, interleaved
+    ((2,), 8, 384, 0, 14),      # three lane tiles
+    ((3,), 64, 64, 0, 4),
+    ((3,), 24, 136, 1, 5),      # sweep caps that bind: one sweep,
+    ((3,), 24, 136, 5, 6),      # mid-way through a convergence test,
+    ((3,), 64, 64, 9, 7),       # and past one
+    ((), 24, 136, 5, 8),
+])
+def test_sink_bfs_equals_bfs_heights(batch, H, W, max_iters, seed):
+    """The kernel's heights are ``bfs_heights``' and the per-grid oracle's,
+    bit for bit, with the sweep cap counted per grid."""
+    cap, ct, h_prev, n = _sink_case(batch, H, W, seed)
+    iters = max_iters or n
+    assert _pallas_names(lambda *a: sink_bfs_heights(
+        *a, n, iters, interpret=True), cap, ct, h_prev) == ["sink_bfs"]
+    got = sink_bfs_heights(cap, ct, h_prev, jnp.int32(n), iters,
+                           interpret=True)
+    want = bfs_heights(cap, ct, h_prev, jnp.int32(n), iters)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    ref = sink_bfs_heights_ref(cap, ct, h_prev, n, iters)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
+
+
+@pytest.mark.parametrize("sink_open", [False, True])
+def test_sink_bfs_closed_grid_takes_the_gap_value(sink_open):
+    """Every grid edge closed: a node beside the sink is at 1 and every
+    other takes the gap value ``max(h_prev, N)``, also where ``h_prev``
+    already lies above ``N``."""
+    B, H, W = 2, 16, 128
+    n = H * W + 2
+    rng = np.random.default_rng(11)
+    cap = jnp.zeros((4, B, H, W), jnp.float32)
+    ct = np.zeros((B, H, W), np.float32)
+    if sink_open:
+        ct[:, 3, 5] = 2.0
+    h_prev = jnp.asarray(rng.integers(0, 2 * n, (B, H, W)), jnp.int32)
+    got = np.asarray(sink_bfs_heights(cap, jnp.asarray(ct), h_prev,
+                                      jnp.int32(n), n, interpret=True))
+    gap = np.maximum(np.asarray(h_prev), n)
+    want = np.where(ct > 0, 1, gap)
+    np.testing.assert_array_equal(got, want)
+    assert (got[ct == 0] >= n).all()
+
+
+def test_sink_bfs_keeps_heights_above_n():
+    """``h_prev`` wholly above ``N``: the unreached nodes keep it, the
+    reached ones take their distance to the sink, as in ``bfs_heights``."""
+    cap, ct, h_prev, n = _sink_case((3,), 16, 128, 12)
+    h_prev = h_prev + n + 1
+    got = np.asarray(sink_bfs_heights(cap, ct, h_prev, jnp.int32(n), n,
+                                      interpret=True))
+    want = np.asarray(bfs_heights(cap, ct, h_prev, jnp.int32(n), n))
+    np.testing.assert_array_equal(got, want)
+    unreached = got >= n
+    assert unreached.any() and (~unreached).any()
+    np.testing.assert_array_equal(got[unreached],
+                                  np.asarray(h_prev)[unreached])
+
+
+def test_sink_bfs_batched_matches_singles():
+    """A grid's heights do not depend on its batch-mates, even when the
+    sweep cap binds for some grids and not for others."""
+    cap, ct, h_prev, n = _sink_case((4,), 24, 136, 13)
+    batched = sink_bfs_heights(cap, ct, h_prev, jnp.int32(n), 30,
+                               interpret=True)
+    for b in range(4):
+        single = sink_bfs_heights(cap[:, b], ct[b], h_prev[b], jnp.int32(n),
+                                  30, interpret=True)
+        np.testing.assert_array_equal(np.asarray(batched[b]),
+                                      np.asarray(single))
+
+
+@pytest.mark.parametrize("H,W,kernel", [
+    (512, 512, True),       # the benchmark's grids
+    (8, 128, True),
+    (24, 136, True),        # padded to whole (8, 128) tiles
+    (12, 12, True),
+    (516, 512, True),
+    (1024, 1024, True),     # 21 planes of 4 MiB: within the VMEM cap
+    (1536, 1536, False),    # 21 planes of 9 MiB: over it
+    (2048, 1024, False),    # 21 planes of 8 MiB
+])
+def test_sink_bfs_shape_rule(H, W, kernel):
+    """The kernel is taken by the grid's shape alone: one grid's planes,
+    padded to whole ``(8, 128)`` tiles, within ``VMEM_CAP``; otherwise the
+    relabel is ``bfs_heights`` (traced only, nothing compiles)."""
+    from repro.kernels import VMEM_CAP
+    from repro.kernels.bfs_relabel.kernel import (SINK_VMEM_PLANES,
+                                                  vmem_limit_bytes)
+    assert sink_bfs_fits(H, W) is kernel
+    assert (vmem_limit_bytes(H, W, SINK_VMEM_PLANES) <= VMEM_CAP) is kernel
+    S = jax.ShapeDtypeStruct
+    names = _pallas_names(
+        lambda c, t, h: sink_bfs_heights(c, t, h, jnp.int32(H * W + 2),
+                                         H * W + 2, interpret=True),
+        S((4, 2, H, W), jnp.float32), S((2, H, W), jnp.float32),
+        S((2, H, W), jnp.int32))
+    assert names == (["sink_bfs"] if kernel else [])

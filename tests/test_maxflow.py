@@ -51,8 +51,9 @@ def test_maxflow_pallas_backend_matches():
 def test_maxflow_batch_pallas_equals_xla(B, H, W, rounds_per_heuristic,
                                          compact):
     """The whole solve on ``backend="pallas"`` (the fused round where the
-    shape allows) gives ``backend="xla"``'s flow, cut and rounds per
-    instance, through the masked and the compacted drivers."""
+    shape allows, and the ``sink_bfs`` relabel) gives ``backend="xla"``'s
+    flow, cut, rounds, heuristic count and final state per instance,
+    through the masked and the compacted drivers."""
     from repro.core.maxflow.grid import maxflow_grid_batch
     rng = np.random.default_rng(B * H + W)
     probs = [random_grid_problem(rng, H, W) for _ in range(B)]
@@ -62,10 +63,14 @@ def test_maxflow_batch_pallas_equals_xla(B, H, W, rounds_per_heuristic,
         prob, backend=backend, rounds_per_heuristic=rounds_per_heuristic,
         compact=compact) for backend in ("xla", "pallas"))
     assert bool(jnp.all(want.converged))
-    for field in ("flow", "cut", "rounds", "converged"):
+    for field in ("flow", "cut", "rounds", "converged", "heuristics"):
         np.testing.assert_array_equal(np.asarray(getattr(got, field)),
                                       np.asarray(getattr(want, field)),
                                       err_msg=field)
+    for field in want.state._fields:
+        np.testing.assert_array_equal(np.asarray(getattr(got.state, field)),
+                                      np.asarray(getattr(want.state, field)),
+                                      err_msg=f"state.{field}")
 
 
 def test_min_cut_separates():

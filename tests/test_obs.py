@@ -734,7 +734,7 @@ def _bench_match(kernel: str) -> str | None:
 
 
 def _kernel_jaxpr(which):
-    from repro.kernels.bfs_relabel.kernel import bfs_relabel_sweeps
+    from repro.kernels.bfs_relabel.kernel import bfs_relabel_sweeps, sink_bfs
     from repro.kernels.bidding.kernel import bidding
     from repro.kernels.frontier.kernel import frontier
     from repro.kernels.grid_push.kernel import (grid_push_decide,
@@ -760,6 +760,9 @@ def _kernel_jaxpr(which):
     if which == "bfs_relabel_sweeps":
         return jax.make_jaxpr(lambda *a: bfs_relabel_sweeps(
             *a, interpret=True))(planes, ih, ih, ih, ih)
+    if which == "sink_bfs":
+        return jax.make_jaxpr(lambda *a: sink_bfs(
+            *a, max_iters=H * W + 2, interpret=True))(planes, plane, ih, n)
     if which == "frontier":
         return jax.make_jaxpr(lambda *a: frontier(*a, interpret=True))(
             S((8, 128), jnp.bool_), S((8,), i32), S((8,), i32))
@@ -772,6 +775,7 @@ def _kernel_jaxpr(which):
     ("grid_push_decide_sched", "grid_push"),
     ("grid_push_round", "grid_push"),
     ("bfs_relabel_sweeps", None),
+    ("sink_bfs", "sink_bfs"),
     ("frontier", None),
     ("bidding", "bidding"),
 ])
@@ -781,6 +785,24 @@ def test_pallas_calls_carry_their_names(kernel, bench_kernel):
     assert _pallas_names(_kernel_jaxpr(kernel).jaxpr) == [kernel]
     if bench_kernel is not None:
         assert _bench_match(bench_kernel) in kernel
+
+
+_KERNELS = ("grid_push_decide", "grid_push_decide_sched", "grid_push_round",
+            "bfs_relabel_sweeps", "sink_bfs", "frontier", "bidding")
+
+
+@pytest.mark.parametrize("bench_kernel,kernels", [
+    ("grid_push", {"grid_push_decide", "grid_push_decide_sched",
+                   "grid_push_round"}),
+    ("bidding", {"bidding"}),
+    ("sink_bfs", {"sink_bfs"}),
+])
+def test_bench_kernel_matches_only_its_kernels(bench_kernel, kernels):
+    """A benchmark kernel reader's ``MATCH`` finds its own kernels' names
+    and no other ``pallas_call``'s, so no kernel's time is read as
+    another's."""
+    match = _bench_match(bench_kernel)
+    assert {k for k in _KERNELS if match in k} == kernels
 
 
 # ----------------------------------------------------- metrics hygiene

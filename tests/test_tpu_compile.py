@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.bfs_relabel.kernel import bfs_relabel_sweeps
+from repro.kernels.bfs_relabel.kernel import bfs_relabel_sweeps, sink_bfs
 from repro.kernels.bidding.kernel import bidding
 from repro.kernels.frontier.kernel import frontier
 from repro.kernels.grid_push.kernel import (grid_push_decide,
@@ -101,6 +101,15 @@ def test_bfs_relabel_sweeps_compiles(sds):
     text = _compiled_text(
         lambda *a: bfs_relabel_sweeps(*a, interpret=False),
         sds((4, B, H, W), f32), *[sds((B, H, W), i32)] * 4)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("batch", [B, 1])   # the batch cell, one served grid
+def test_sink_bfs_compiles(sds, batch):
+    text = _compiled_text(
+        lambda *a: sink_bfs(*a, max_iters=H * W + 2, interpret=False),
+        sds((4, batch, H, W), f32), sds((batch, H, W), f32),
+        sds((batch, H, W), i32), sds((), i32))
     assert "tpu_custom_call" in text
 
 
